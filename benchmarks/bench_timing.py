@@ -2,8 +2,9 @@
 real chip, with the persistent XLA compilation cache enabled, so round
 5 can budget the driver's bench run (VERDICT r4 weak #1 / next #1).
 
-Run twice: the first sitting is cold (populates .xla_cache/), the
-second shows what the driver's warm sitting would cost.
+Run twice: the first sitting is cold (populates the compilation cache,
+deeplearning4j_tpu/util/compile_cache.py), the second shows what a
+warm sitting would cost.
 
     python benchmarks/bench_timing.py
 """
@@ -18,12 +19,9 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 ".."))
 
-import jax
+from deeplearning4j_tpu.util import compile_cache
 
-CACHE = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
-                     ".xla_cache")
-jax.config.update("jax_compilation_cache_dir", CACHE)
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+compile_cache.enable()
 
 
 def timed(name, fn):
@@ -39,8 +37,8 @@ def timed(name, fn):
 
 def lenet():
     import subprocess
-    env = dict(os.environ, BENCH_FLAGSHIP="0",
-               JAX_COMPILATION_CACHE_DIR=CACHE)
+    env = dict(os.environ, BENCH_FLAGSHIP="0")   # same cache: bench.py
+    #                                              calls the same helper
     t0 = time.perf_counter()
     r = subprocess.run([sys.executable, "bench.py"], env=env,
                        capture_output=True, text=True,

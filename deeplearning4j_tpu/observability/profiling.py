@@ -84,8 +84,6 @@ def cost_from_compiled(compiled) -> dict:
         ca = compiled.cost_analysis()
     except Exception:
         return {}
-    if isinstance(ca, (list, tuple)):      # older jax returns [dict]
-        ca = ca[0] if ca else {}
     if not ca:
         return {}
     out = {}

@@ -87,17 +87,18 @@ def _forward(xw_t, h0, c0, rw, b, pi, pf, po, interpret):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    from deeplearning4j_tpu.ops.pallas_util import (interpret_arg,
+                                                    out_struct)
+
     t, bsz, h4 = xw_t.shape
     hdim = h4 // 4
-    b2 = b.reshape(1, h4)
-    pi2 = pi.reshape(1, hdim)
-    pf2 = pf.reshape(1, hdim)
-    po2 = po.reshape(1, hdim)
+    operands = (xw_t, h0, c0, rw, b.reshape(1, h4), pi.reshape(1, hdim),
+                pf.reshape(1, hdim), po.reshape(1, hdim))
     return pl.pallas_call(
         _lstm_step_kernel,
-        out_shape=[jax.ShapeDtypeStruct((t, bsz, hdim), jnp.float32),
-                   jax.ShapeDtypeStruct((t, bsz, hdim), jnp.float32),
-                   jax.ShapeDtypeStruct((t, bsz, h4), jnp.float32)],
+        out_shape=[out_struct((t, bsz, hdim), jnp.float32, *operands),
+                   out_struct((t, bsz, hdim), jnp.float32, *operands),
+                   out_struct((t, bsz, h4), jnp.float32, *operands)],
         grid=(t,),
         in_specs=[
             pl.BlockSpec((1, bsz, h4), lambda i: (i, 0, 0)),
@@ -114,8 +115,8 @@ def _forward(xw_t, h0, c0, rw, b, pi, pf, po, interpret):
                    pl.BlockSpec((1, bsz, h4), lambda i: (i, 0, 0))],
         scratch_shapes=[pltpu.VMEM((bsz, hdim), jnp.float32),
                         pltpu.VMEM((bsz, hdim), jnp.float32)],
-        interpret=interpret,
-    )(xw_t, h0, c0, rw, b2, pi2, pf2, po2)
+        interpret=interpret_arg(interpret, *operands),
+    )(*operands)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(8,))

@@ -140,8 +140,12 @@ def make_fsdp_train_step(cfg: TransformerConfig, mesh: Mesh, *,
     b1, b2 = betas
 
     def step(params, opt: AdamState, tokens, targets):
-        loss, grads = jax.value_and_grad(
-            lambda p: loss_fn(cfg, p, tokens, targets))(params)
+        # the mesh is named while the loss is traced so that code which
+        # cannot be partitioned automatically (the Pallas attention
+        # kernel) can see it and shard itself over 'data'
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            loss, grads = jax.value_and_grad(
+                lambda p: loss_fn(cfg, p, tokens, targets))(params)
         count = opt.count + 1
         params, m, v = adam_update_tree(
             params, grads, opt.m, opt.v, count.astype(jnp.float32),

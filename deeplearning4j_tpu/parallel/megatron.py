@@ -37,10 +37,6 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-try:
-    from jax import shard_map
-except ImportError:          # jax<0.6: pre-promotion location
-    from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from deeplearning4j_tpu.models.transformer import (
@@ -242,10 +238,9 @@ def _pipeline_apply(blocks_local, h_mb: Array, cfg, mesh) -> Array:
     i = lax.axis_index("pipe")
     m_ = h_mb.shape[0]
     perm_fwd = [(j, j + 1) for j in range(s - 1)]
-    from deeplearning4j_tpu.parallel.mesh import pcast_varying
 
     def vary(x):
-        return pcast_varying(x, ("pipe", "data", "seq"))
+        return lax.pcast(x, ("pipe", "data", "seq"), to="varying")
     recv0 = vary(jnp.zeros_like(h_mb[0]))
     out0 = vary(jnp.zeros_like(h_mb))
 
@@ -543,16 +538,11 @@ def make_parallel_train_step(cfg: TransformerConfig, mesh: Mesh, *,
         return new_p, new_m, new_v, cnt, loss
 
     data_spec = P(("data",), ("seq",))
-    # the replication-check kwarg was renamed check_rep -> check_vma
-    # when the vma type system landed (jax 0.7)
-    import inspect
-    _chk = ("check_vma" if "check_vma"
-            in inspect.signature(shard_map).parameters else "check_rep")
-    smapped = shard_map(
+    smapped = jax.shard_map(
         sharded_step, mesh=mesh,
         in_specs=(specs, specs, specs, P(), data_spec, data_spec),
         out_specs=(specs, specs, specs, P(), P()),
-        **{_chk: False})
+        check_vma=False)
 
     def step(params, opt_state: AdamState, tokens, targets):
         p2, m2, v2, cnt, loss = smapped(params, opt_state.m, opt_state.v,

@@ -25,28 +25,6 @@ from jax.sharding import Mesh
 AXES = ("pipe", "data", "seq", "model", "expert")
 
 
-def pcast_varying(x, axes):
-    """`lax.pcast(x, axes, to="varying")` on jax>=0.7 — marks a constant
-    as device-varying over manual mesh axes so it can seed a scan carry
-    whose steady state IS varying (the vma type system rejects the
-    mismatch otherwise). On older jax the same marking goes through the
-    legacy check_rep machinery: adding a zero derived from
-    `lax.axis_index(axis)` — unreplicated over that axis by its rep
-    rule — drops `axes` from the value's replication set without
-    changing its bytes."""
-    from jax import lax
-    import jax.numpy as jnp
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, axes, to="varying")
-    marker = None
-    for a in axes:
-        t = lax.axis_index(a)
-        marker = t if marker is None else marker + t
-    if marker is None:
-        return x
-    return x + (marker * 0).astype(x.dtype)
-
-
 @dataclass(frozen=True)
 class MeshSpec:
     """Logical mesh shape. Unspecified axes default to 1 (absent)."""

@@ -47,10 +47,8 @@ def ring_attention(q: Array, k: Array, v: Array, axis_name: str, *,
     # pcast: the initial accumulators are constants, but the scan carry is
     # device-varying over the ring axis — the vma type system requires the
     # init to be marked varying too.
-    from deeplearning4j_tpu.parallel.mesh import pcast_varying
-
     def vary(x):
-        return pcast_varying(x, (axis_name,))
+        return lax.pcast(x, (axis_name,), to="varying")
 
     m0 = vary(jnp.full((b, h, tl), NEG_INF, jnp.float32))
     l0 = vary(jnp.zeros((b, h, tl), jnp.float32))

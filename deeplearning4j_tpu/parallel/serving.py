@@ -105,7 +105,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from deeplearning4j_tpu.models.transformer import (TransformerConfig,
                                                    _filter_logits,
@@ -348,9 +347,9 @@ def make_parallel_generate(cfg: TransformerConfig, mesh: Mesh,
         return jnp.concatenate([prompt, jnp.swapaxes(toks, 0, 1)],
                                axis=1)
 
-    sharded = shard_map(run, mesh=mesh,
-                        in_specs=(specs, P("data", None), P()),
-                        out_specs=P("data", None), check_rep=True)
+    sharded = jax.shard_map(run, mesh=mesh,
+                            in_specs=(specs, P("data", None), P()),
+                            out_specs=P("data", None), check_vma=True)
     return jax.jit(sharded)
 
 
@@ -757,8 +756,8 @@ def make_continuous_prefill(cfg: TransformerConfig, mesh: Mesh,
                          _SLOT_VEC_SPEC, _SLOT_VEC_SPEC,
                          _SLOT_VEC_SPEC)
 
-    sharded = shard_map(run, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_rep=True)
+    sharded = jax.shard_map(run, mesh=mesh, in_specs=in_specs,
+                            out_specs=out_specs, check_vma=True)
     return jax.jit(sharded)
 
 
@@ -956,8 +955,8 @@ def make_continuous_decode(cfg: TransformerConfig, mesh: Mesh,
                          _SLOT_VEC_SPEC, _SLOT_VEC_SPEC,
                          P("data", None))
 
-    sharded = shard_map(run, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_rep=True)
+    sharded = jax.shard_map(run, mesh=mesh, in_specs=in_specs,
+                            out_specs=out_specs, check_vma=True)
     return jax.jit(sharded)
 
 
@@ -1226,8 +1225,8 @@ def make_chunked_prefill(cfg: TransformerConfig, mesh: Mesh,
                          _SLOT_VEC_SPEC, _SLOT_VEC_SPEC,
                          _SLOT_VEC_SPEC)
 
-    sharded = shard_map(run, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_rep=True)
+    sharded = jax.shard_map(run, mesh=mesh, in_specs=in_specs,
+                            out_specs=out_specs, check_vma=True)
     return jax.jit(sharded)
 
 
@@ -1745,8 +1744,8 @@ def make_paged_prefill(cfg: TransformerConfig, mesh: Mesh,
     if constrain:
         out_specs = out_specs + (_PAGE_VEC_SPEC,)
 
-    sharded = shard_map(run, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_rep=True)
+    sharded = jax.shard_map(run, mesh=mesh, in_specs=in_specs,
+                            out_specs=out_specs, check_vma=True)
     return jax.jit(sharded)
 
 
@@ -1952,8 +1951,8 @@ def make_paged_decode(cfg: TransformerConfig, mesh: Mesh, chunk: int,
                          _PAGE_SCALE_SPEC, _PAGE_SCALE_SPEC,
                          _PAGE_VEC_SPEC, _PAGE_VEC_SPEC, P(None, None))
 
-    sharded = shard_map(run, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_rep=True)
+    sharded = jax.shard_map(run, mesh=mesh, in_specs=in_specs,
+                            out_specs=out_specs, check_vma=True)
     return jax.jit(sharded)
 
 
@@ -2404,8 +2403,8 @@ def make_speculative_decode(cfg: TransformerConfig, mesh: Mesh,
                          P("data", None), _SLOT_VEC_SPEC,
                          _SLOT_VEC_SPEC, _SLOT_VEC_SPEC)
 
-    sharded = shard_map(run, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_rep=True)
+    sharded = jax.shard_map(run, mesh=mesh, in_specs=in_specs,
+                            out_specs=out_specs, check_vma=True)
     return jax.jit(sharded)
 
 
@@ -2666,8 +2665,8 @@ def make_paged_speculative_decode(cfg: TransformerConfig, mesh: Mesh,
                          _PAGE_VEC_SPEC, _PAGE_VEC_SPEC,
                          _PAGE_VEC_SPEC)
 
-    sharded = shard_map(run, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_rep=True)
+    sharded = jax.shard_map(run, mesh=mesh, in_specs=in_specs,
+                            out_specs=out_specs, check_vma=True)
     return jax.jit(sharded)
 
 
@@ -2686,8 +2685,8 @@ def serving_param_specs(cfg: TransformerConfig):
     # serving meshes are validated pipe=1, so the training layout's
     # leading 'pipe' placement is dropped: naming a size-1 manual axis
     # still marks the params VARYING over it, which poisons the scan
-    # carry's varying-manual-axes set and is what forced
-    # check_rep=False in round 3
+    # carry's varying-manual-axes set (round 3 had to switch the check
+    # off for that reason)
     specs["blocks"] = {
         k: P(*(None if a == "pipe" else a for a in sp))
         for k, sp in specs["blocks"].items()}

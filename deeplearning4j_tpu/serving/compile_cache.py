@@ -170,17 +170,23 @@ class CompileCache:
     # ------------------------------------------------------------------
     # load / store
     # ------------------------------------------------------------------
-    def load(self, key: str) -> Optional[Callable]:
+    def load(self, key: str, devices) -> Optional[Callable]:
         """Deserialize-and-load the entry's executable, or None on any
         miss/corruption (corrupt entries are deleted so the follow-up
         store publishes a clean one)."""
-        fn, _ = self.load_entry(key)
+        fn, _ = self.load_entry(key, devices)
         return fn
 
-    def load_entry(self, key: str
+    def load_entry(self, key: str, devices
                    ) -> "tuple[Optional[Callable], Optional[dict]]":
-        """(executable, meta) for one entry — ``meta`` is the sidecar
-        dict stored beside the executable (ISSUE-15: the program's XLA
+        """(executable, meta) for one entry, loaded onto ``devices`` —
+        the devices of the mesh the program was compiled for, in mesh
+        order. They must be named: left to its default the loader
+        targets EVERY device of the backend, and a one-device
+        executable loaded in a process with more devices (8 virtual
+        CPUs in the tests, 4 chips on a host) then expects one argument
+        shard per device and fails at its first call. ``meta`` is the
+        sidecar dict stored beside the executable (ISSUE-15: the program's XLA
         cost analysis, so a cache-warm restart has a complete cost
         table with ZERO compiles). The frame field is versioned
         in-payload: a pre-meta entry (the 3-tuple frame rounds 17-19
@@ -224,7 +230,9 @@ class CompileCache:
                     meta = None
             else:
                 raise ValueError(f"unknown frame arity {len(frame)}")
-            fn = se.deserialize_and_load(serialized, in_tree, out_tree)
+            fn = se.deserialize_and_load(
+                serialized, in_tree, out_tree,
+                execution_devices=list(devices))
         except Exception as e:
             # corrupt / foreign / version-skewed entry: fail CLOSED to
             # a recompile, and clear the entry so the recompile's

@@ -834,6 +834,20 @@ def set_program_cache_size(n: int) -> int:
     return n
 
 
+def resolved_executables() -> Dict[str, list]:
+    """{factory name: compiled executables} for every program this
+    process has resolved so far — what `chip_smoke.py` reads the
+    compiled HLO of (collectives on a sharded mesh)."""
+    out: Dict[str, list] = {}
+    for c in _ProgramLRU._instances:
+        with c._lock:
+            exes = [side["exec"] for _, side in c._od.values()
+                    if side.get("exec") is not None]
+        if exes:
+            out[c.__name__] = exes
+    return out
+
+
 @_program_cache
 def _compiled_generate(cfg_fields: tuple, mesh, max_new_tokens: int,
                        temperature: float, top_k: int, top_p: float,
@@ -4136,8 +4150,10 @@ class InferenceEngine:
         ``compile_cache_dir`` is set — serialized to disk so the next
         process loads instead of compiling. ``example_args=None``
         (batch-mode generate: shapes vary per call) keeps the lazy jit
-        path. Any AOT-side failure falls back to the lazy jit callable
-        — availability over purity."""
+        path. A program that does not lower or compile RAISES here: the
+        un-compiled callable would fail the same way on every retry,
+        and the caller (warmup, or the tick's guarded call) must see
+        the real error instead of a quarantined request."""
         fn = factory(*fargs, **fkw)
         label = self._program_label(program, fargs)
         if example_args is None:
@@ -4175,7 +4191,8 @@ class InferenceEngine:
             key = self._aot.entry_key(
                 program, self.mesh,
                 (fargs[0], *fargs[2:], tuple(sorted(fkw.items()))))
-            exe, meta = self._aot.load_entry(key)
+            exe, meta = self._aot.load_entry(
+                key, list(self.mesh.devices.flat))
             if exe is not None:
                 self._m_compile_seconds.labels(program).observe(
                     _perf() - t0)
@@ -4190,13 +4207,7 @@ class InferenceEngine:
                 self._profile_program(label, slot, exe, ptokens)
                 slot[("published", str(self._aot.directory))] = True
                 return exe
-        try:
-            exe = fn.lower(*example_args).compile()
-        except Exception as e:
-            log.warning("AOT resolve of %s failed (%s); falling back "
-                        "to lazy jit", program, e)
-            self.profiler.dispatched(label)
-            return fn
+        exe = fn.lower(*example_args).compile()
         self._m_compile_seconds.labels(program).observe(_perf() - t0)
         self._m_compiles.labels(program, "jit").inc()
         slot["cost"] = cost_from_compiled(exe)
@@ -4320,11 +4331,18 @@ class InferenceEngine:
             for k in ks:
                 skw = dict(qkw, draft_quantized=self._draft_qmode,
                            draft_layers=self._draft_layers)
-                self._resolve_program(
-                    "spec_decode", _compiled_spec_decode,
-                    (cfgf, self.mesh, k, ns, *samp), skw,
-                    (params, self._draft_params, *state, active, rem,
-                     poison, key))
+                if self._paged:
+                    self._resolve_program(
+                        "paged_spec_decode", _compiled_paged_spec_decode,
+                        (cfgf, self.mesh, k, ns, *pgeo, *samp), skw,
+                        (params, self._draft_params, *state, bt, active,
+                         rem, poison, key))
+                else:
+                    self._resolve_program(
+                        "spec_decode", _compiled_spec_decode,
+                        (cfgf, self.mesh, k, ns, *samp), skw,
+                        (params, self._draft_params, *state, active,
+                         rem, poison, key))
                 n_programs += 1
         if self._prefill_chunk is not None:
             c = self._prefill_chunk

@@ -85,8 +85,6 @@ def cost_analysis(jitted_fn, *args, **kwargs) -> dict:
         ca = compiled.cost_analysis()
     except Exception:  # some PJRT plugins raise UNIMPLEMENTED here
         return {}
-    if isinstance(ca, (list, tuple)):  # older jax returns [dict]
-        ca = ca[0] if ca else {}
     return dict(ca) if ca else {}
 
 

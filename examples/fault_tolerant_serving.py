@@ -34,18 +34,6 @@ import numpy as np
 
 def main() -> None:
     import jax
-    from jax._src import xla_bridge as _xb
-
-    n_dev = 4
-    # bootstrap BEFORE the first backend touch: on jax<0.6 a live CPU
-    # client cannot be resized (no jax_num_cpu_devices), so querying
-    # jax.devices() first would lock in a 1-device mesh
-    if not _xb.backends_are_initialized():
-        from __graft_entry__ import _force_virtual_cpu_mesh
-        try:
-            _force_virtual_cpu_mesh(n_dev)
-        except Exception:
-            pass              # fall through to whatever mesh exists
 
     from deeplearning4j_tpu import observability as obs
     from deeplearning4j_tpu.models.transformer import (TransformerConfig,
@@ -63,9 +51,9 @@ def main() -> None:
     cfg = TransformerConfig(vocab_size=64, d_model=64, n_heads=4,
                             n_layers=2, max_len=128)
     params = init_params(cfg, jax.random.PRNGKey(0))
-    if len(jax.devices()) >= n_dev:
+    if len(jax.devices()) >= 4:
         mesh = make_mesh(MeshSpec(data=2, model=2))
-    else:                     # unresizable 1-device client (old jax)
+    else:                     # one chip, or a plain CPU host
         mesh = make_mesh(MeshSpec(data=1, model=1))
     prompt = np.arange(16, dtype=np.int32)
 

@@ -105,11 +105,11 @@ def test_store_load_roundtrip_and_atomic_publish(tmp_path):
     fn = jax.jit(lambda x: x * 2 + 1)
     comp = fn.lower(np.ones((4,), np.float32)).compile()
     key = cache.entry_key("toy", None, (("shape", 4),))
-    assert cache.load(key) is None          # miss before store
+    assert cache.load(key, jax.devices()[:1]) is None   # miss: no store
     assert cache.store(key, comp)
     assert not any(_STAGING_SUFFIX in p.name
                    for p in tmp_path.iterdir())
-    loaded = cache.load(key)
+    loaded = cache.load(key, jax.devices()[:1])
     assert loaded is not None
     np.testing.assert_array_equal(
         np.asarray(loaded(np.ones((4,), np.float32))),
@@ -131,17 +131,17 @@ def test_corrupt_entry_fails_closed_and_is_deleted(tmp_path):
 
     blob = p.read_bytes()
     p.write_bytes(blob[: len(blob) // 2])            # truncated
-    assert cache.load(key) is None
+    assert cache.load(key, jax.devices()[:1]) is None
     assert not p.exists()
 
     cache.store(key, comp)
     raw = bytearray(p.read_bytes())
     raw[len(raw) // 2] ^= 0xFF                       # bit flip
     p.write_bytes(bytes(raw))
-    assert cache.load(key) is None
+    assert cache.load(key, jax.devices()[:1]) is None
 
     p.write_bytes(b"not an AOT entry at all")        # foreign file
-    assert cache.load(key) is None
+    assert cache.load(key, jax.devices()[:1]) is None
     assert cache.stats()["corrupt"] == 3
 
 
@@ -328,3 +328,66 @@ def test_compile_metrics_have_samples(params, mesh1):
     assert _compiles(eng, "jit") >= 2           # prefill + decode
     fams = {labels[0] for labels, _ in eng._m_compile_seconds.collect()}
     assert {"prefill", "decode"} <= fams
+
+
+# ---------------------------------------------------------------------------
+# util/compile_cache.enable(): where JAX's own persistent cache lives
+# (ISSUE-21) — placed from outside, one fixed default, nothing else
+# ---------------------------------------------------------------------------
+
+@pytest.fixture()
+def _restore_jax_cache_config():
+    keep = {k: getattr(jax.config, k) for k in (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes")}
+    yield
+    for k, v in keep.items():
+        jax.config.update(k, v)
+
+
+def test_helper_leaves_an_outside_cache_dir_alone(
+        monkeypatch, tmp_path, _restore_jax_cache_config):
+    """With JAX_COMPILATION_CACHE_DIR set, JAX has read it at import;
+    the helper reports it and sets no directory in code."""
+    from deeplearning4j_tpu.util import compile_cache
+
+    monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path))
+    jax.config.update("jax_compilation_cache_dir", "/set/by/jax")
+    assert compile_cache.enable() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == "/set/by/jax"
+    assert not any(tmp_path.iterdir())
+
+
+def test_helper_default_is_fixed_inside_the_checkout(
+        monkeypatch, _restore_jax_cache_config):
+    """Unset, the cache goes to <checkout>/.cache/jax — a fixed path
+    (it is part of every entry's key), never a temp name, pid or time —
+    and the keep-threshold moves only when the caller asks."""
+    from deeplearning4j_tpu.util import compile_cache
+
+    monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+    root = pathlib.Path(__file__).resolve().parents[1]
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    assert compile_cache.enable() == str(root / ".cache" / "jax")
+    assert jax.config.jax_compilation_cache_dir == str(
+        root / ".cache" / "jax")
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 1.0
+    compile_cache.enable(min_compile_time_secs=0.0)
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+
+
+def test_no_other_cache_directory_is_set_in_code():
+    """Outside the tests, the helper is the only place in the repo
+    that touches jax_compilation_cache_dir."""
+    root = pathlib.Path(__file__).resolve().parents[1]
+    helper = pathlib.Path("deeplearning4j_tpu/util/compile_cache.py")
+    offenders = []
+    for path in root.rglob("*.py"):
+        rel = path.relative_to(root)
+        if rel.parts[0].startswith(".") or rel.parts[0] == "tests" \
+                or rel == helper:
+            continue
+        if "jax_compilation_cache_dir" in path.read_text():
+            offenders.append(str(rel))
+    assert offenders == []

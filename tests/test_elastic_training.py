@@ -327,3 +327,38 @@ def test_hang_evicts_and_restores_bit_exactness(tmp_path):
     acts = [e.data.get("action") for e in rec.recent(kind="elastic")]
     assert acts.count("evict") == 1
     assert "replay" in acts
+
+
+def test_bench_error_line_makes_the_exit_code_nonzero(monkeypatch, capsys):
+    """ISSUE-21 satellite: a config that raises prints its `error`
+    line, the remaining configs still run, and `flagship_lines` reports
+    the count that bench.py turns into a non-zero exit."""
+    import importlib.util
+    import json
+    import pathlib
+    import sys
+    import types
+
+    root = pathlib.Path(__file__).resolve().parents[1]
+    spec = importlib.util.spec_from_file_location("_bench_rc",
+                                                  root / "bench.py")
+    bench = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(bench)
+
+    def boom(reps):
+        raise RuntimeError("no such kernel")
+
+    fake = types.ModuleType("flagship")
+    fake.BENCHES = {n: (lambda reps, n=n: {"config": n, "value": 1})
+                    for n in ("transformer", "transformer_1024",
+                              "transformer_32kvocab", "decode_long")}
+    fake.BENCHES["decode"] = boom
+    monkeypatch.setitem(sys.modules, "flagship", fake)
+    assert bench.flagship_lines("transformer") == 1
+    lines = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()]
+    assert [ln["config"] for ln in lines] == [
+        "transformer", "transformer_1024", "transformer_32kvocab",
+        "decode", "decode_long"]
+    assert "error" in lines[3] and "error" not in lines[4]
+    fake.BENCHES["decode"] = fake.BENCHES["transformer"]
+    assert bench.flagship_lines("transformer") == 0

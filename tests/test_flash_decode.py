@@ -94,8 +94,9 @@ def test_kernel_vector_pos_matches_per_row_reference(interpret_mode,
                                                      stacked):
     """PER-ROW positions (the slotted/paged call sites: every slot is
     at its OWN prefix) must equal the per-row scalar reference — on
-    the kernel path, where the second prefetched scalar bounds each
-    batch block's DMA at its furthest row."""
+    the kernel path, where the rows' positions arrive as a VMEM block
+    and their per-batch-block maximum, the prefetched scalar, bounds
+    the block's DMA at its furthest row."""
     b, h, dh, s = 4, 4, 16, 512
     pos = np.array([3, 255, 256, 500], np.int32)
     if stacked:
@@ -310,3 +311,85 @@ def test_window_fallback_when_unavailable(monkeypatch):
     ref = reference_window_attention(q, k, v, jnp.asarray([5, 30]),
                                      n_heads=2)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+
+
+# ---------------------------------------------------------------------------
+# the kernels where the serving programs put them: inside
+# jax.shard_map(check_vma=True) — a pallas_call's out_shape must carry
+# the operands' varying-manual-axes set there, and the interpreter must
+# be one that can run on vma-typed blocks (ops/pallas_util.py)
+# ---------------------------------------------------------------------------
+
+def test_kernels_inside_shard_map_check_vma(interpret_mode, devices8):
+    """Vector-position decode and the K+1 window kernel, traced inside
+    shard_map(check_vma=True) over a (data=2, model=2) mesh with the
+    serving programs' layout (slots over 'data', heads over 'model',
+    positions varying over 'data' only), against the unsharded
+    reference."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from deeplearning4j_tpu.ops.flash_decode import (
+        decode_window_attention, reference_window_attention,
+        window_attention_available)
+    mesh = Mesh(np.array(devices8[:4]).reshape(2, 2), ("data", "model"))
+    L, b, h, dh, s, t = 2, 4, 4, 16, 256, 3
+    ks = jax.random.split(jax.random.PRNGKey(31), 4)
+    q = jax.random.normal(ks[0], (b, h, dh), jnp.float32)
+    qw = jax.random.normal(ks[1], (b, t, h, dh), jnp.float32)
+    ck = jax.random.normal(ks[2], (L, b, s, h * dh), jnp.float32)
+    cv = jax.random.normal(ks[3], (L, b, s, h * dh), jnp.float32)
+    pos = jnp.asarray([0, 77, 128, 250], jnp.int32)
+    h_loc = h // 2
+
+    def body(q, qw, ck, cv, pos):
+        assert jax.typeof(q).vma == {"data", "model"}
+        assert decode_attention_available(q, ck)
+        assert window_attention_available(qw, ck)
+        a = decode_attention(q, ck, cv, pos, h_loc, layer=1)
+        w = decode_window_attention(qw, ck[1], cv[1], pos, h_loc)
+        return a, w
+
+    cache = P(None, "data", None, "model")
+    fn = jax.jit(jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(P("data", "model"), P("data", None, "model"), cache,
+                  cache, P("data")),
+        out_specs=(P("data", "model"), P("data", None, "model")),
+        check_vma=True))
+    a, w = fn(q, qw, ck, cv, pos)
+    np.testing.assert_allclose(
+        np.asarray(a),
+        np.asarray(reference_decode_attention(q, ck[1], cv[1], pos, h)),
+        rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(
+        np.asarray(w),
+        np.asarray(reference_window_attention(qw, ck[1], cv[1], pos, h)),
+        rtol=2e-5, atol=2e-5)
+
+
+def test_window_batch_block_shrinks_with_the_window(monkeypatch):
+    """The window kernel's batch block is the decode kernel's divided
+    by T: Mosaic keeps one f32 product per unrolled pseudo-head, and a
+    T-row window has T times the decode kernel's pseudo-heads (the 2MB
+    block at K+1=5 asked for 28MB of scoped VMEM on the chip)."""
+    import jax.experimental.pallas as pl
+
+    from deeplearning4j_tpu.ops import flash_decode as fd
+    grids = []
+    real = pl.pallas_call
+
+    def spy(kernel, *, grid_spec, **kw):
+        grids.append(grid_spec.grid)
+        return real(kernel, grid_spec=grid_spec, **kw)
+
+    monkeypatch.setattr(pl, "pallas_call", spy)
+    monkeypatch.setenv("DL4JTPU_FLASH", "interpret")
+    monkeypatch.setenv("DL4JTPU_DECODE_BLOCK_BYTES",
+                       str(8 * 128 * 64 * 4))       # 8 rows of [128, 64] f32
+    b, h, dh, s = 8, 4, 16, 256
+    q, k, v = _mk(b, h, dh, s, jnp.float32)
+    pos = jnp.zeros((b,), jnp.int32)
+    fd.decode_attention(q, k, v, pos, h)
+    qw = jnp.zeros((b, 4, h, dh), jnp.float32)
+    fd.decode_window_attention(qw, k, v, pos, h)
+    assert grids == [(1, 2), (4, 2)]      # bb = 8, then 8 // 4 = 2

@@ -66,6 +66,32 @@ def test_fsdp_matches_single_device(devices8):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4)
 
 
+def test_fsdp_shards_the_attention_kernel_over_data(devices8,
+                                                    monkeypatch):
+    """On several chips the FSDP step is one GSPMD jit, and a Mosaic
+    call inside it refuses to lower ("cannot be automatically
+    partitioned"). The step names its mesh while tracing and the flash
+    kernel, seeing it, runs under a shard_map over 'data'. Interpret
+    mode on the CPU: the shard-mapped kernel step equals the
+    single-device kernel step."""
+    import jax.experimental.pallas as pl
+    calls = []
+    real = pl.pallas_call
+
+    def spy(kernel, *a, **kw):
+        calls.append(jax.sharding.get_abstract_mesh().manual_axes)
+        return real(kernel, *a, **kw)
+
+    monkeypatch.setattr(pl, "pallas_call", spy)
+    monkeypatch.setenv("DL4JTPU_FLASH", "interpret")
+    _, _, base_loss = _train(MeshSpec(), steps=2)
+    assert calls and not any(calls)         # one device: no shard_map
+    del calls[:]
+    _, _, got_loss = _train(MeshSpec(data=4), steps=2)
+    assert calls and all("data" in c for c in calls)
+    assert abs(got_loss - base_loss) < 1e-4
+
+
 def test_fsdp_state_is_actually_sharded(devices8):
     mesh = make_mesh(MeshSpec(data=8))
     params = shard_params_fsdp(init_params(CFG, jax.random.PRNGKey(0)),

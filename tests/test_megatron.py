@@ -57,10 +57,6 @@ def test_sequence_parallel_attention_matches_full(devices8, attn_fn):
     resharding) == full single-device causal attention, fwd and grad."""
     from functools import partial
 
-    try:
-        from jax import shard_map
-    except ImportError:      # jax<0.6: pre-promotion location
-        from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     from deeplearning4j_tpu.nn.layers.attention import dot_product_attention
@@ -69,16 +65,9 @@ def test_sequence_parallel_attention_matches_full(devices8, attn_fn):
     q, k, v = (rng.randn(2, 32, 4, 8).astype(np.float32) for _ in range(3))
     ref = dot_product_attention(jnp.asarray(q), jnp.asarray(k),
                                 jnp.asarray(v), causal=True)
-    # jax<0.7's legacy check_rep cannot track the transpose of the ring
-    # scan (its own error message prescribes check_rep=False); the vma
-    # system on newer jax handles it, so keep checking ON there
-    import inspect
-    compat = ({} if "check_vma" in inspect.signature(shard_map).parameters
-              else {"check_rep": False})
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         partial(attn_fn, axis_name="seq", causal=True), mesh=mesh,
-        in_specs=(P(None, "seq"),) * 3, out_specs=P(None, "seq"),
-        **compat))
+        in_specs=(P(None, "seq"),) * 3, out_specs=P(None, "seq")))
     out = fn(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-6)
     # gradients flow through the collective identically

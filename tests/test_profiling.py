@@ -377,7 +377,7 @@ def test_old_format_cache_entry_degrades_to_lazy_recompute(tmp_path):
     key = "decode-oldformat"
     cache.path(key).write_bytes(blob)
 
-    loaded, meta = cache.load_entry(key)
+    loaded, meta = cache.load_entry(key, jax.devices()[:1])
     assert loaded is not None and meta is None
     assert cache.stats()["corrupt"] == 0
     # lazy recompute from the loaded executable: full analysis
@@ -399,7 +399,7 @@ def test_meta_roundtrip_beside_executable(tmp_path):
     exe = fn.lower(x).compile()
     cost = cost_from_compiled(exe)
     assert cache.store("p-meta", exe, meta={"cost": cost})
-    loaded, meta = cache.load_entry("p-meta")
+    loaded, meta = cache.load_entry("p-meta", jax.devices()[:1])
     assert loaded is not None
     assert meta["cost"] == cost
     assert meta["meta_version"] >= 1
