@@ -207,25 +207,27 @@ def block_forward(h: Array, p: Dict[str, Array], cfg: TransformerConfig,
     intermediate is recomputed in backward, attention residuals are
     kept)."""
     d = cfg.d_model
-    x = layer_norm(h, p["ln1g"], p["ln1b"], cfg.eps)
 
     def heads(y):
         return y.reshape(y.shape[0], y.shape[1], cfg.n_heads, cfg.d_head)
 
-    q = heads(jnp.matmul(x, p["Wq"].astype(x.dtype)))
-    k = heads(jnp.matmul(x, p["Wk"].astype(x.dtype)))
-    v = heads(jnp.matmul(x, p["Wv"].astype(x.dtype)))
-    a = dot_product_attention(q, k, v, causal=True, mask=mask)
-    h = h + jnp.matmul(a.reshape(a.shape[0], a.shape[1], d),
-                       p["Wo"].astype(h.dtype))
-    x = layer_norm(h, p["ln2g"], p["ln2b"], cfg.eps)
+    with jax.named_scope("attn"):
+        x = layer_norm(h, p["ln1g"], p["ln1b"], cfg.eps)
+        q = heads(jnp.matmul(x, p["Wq"].astype(x.dtype)))
+        k = heads(jnp.matmul(x, p["Wk"].astype(x.dtype)))
+        v = heads(jnp.matmul(x, p["Wv"].astype(x.dtype)))
+        a = dot_product_attention(q, k, v, causal=True, mask=mask)
+        h = h + jnp.matmul(a.reshape(a.shape[0], a.shape[1], d),
+                           p["Wo"].astype(h.dtype))
     if cfg.n_experts > 0:
         mlp = lambda xx, pp: moe_mlp(xx, pp, cfg)  # noqa: E731
     else:
         mlp = dense_mlp
     if remat_mlp:
         mlp = jax.checkpoint(mlp, prevent_cse=False)
-    h = h + mlp(x, p)
+    with jax.named_scope("mlp"):
+        x = layer_norm(h, p["ln2g"], p["ln2b"], cfg.eps)
+        h = h + mlp(x, p)
     if return_kv:
         return h, (k, v)
     return h
@@ -239,8 +241,9 @@ def forward_hidden(cfg: TransformerConfig, params: Dict[str, Any],
     vocab-panel scan)."""
     dt = cfg.activation_dtype()
     t = tokens.shape[1]
-    h = (params["embed"].astype(dt)[tokens]
-         + params["pos"].astype(dt)[:t][None])
+    with jax.named_scope("embed"):
+        h = (params["embed"].astype(dt)[tokens]
+             + params["pos"].astype(dt)[:t][None])
 
     if cfg.remat and cfg.remat_policy not in ("full", "dots", "mlp"):
         raise ValueError(f"unknown remat_policy {cfg.remat_policy!r}: "
@@ -602,15 +605,16 @@ def chunked_cross_entropy(h: Array, wout: Array, targets: Array,
 
 def loss_fn(cfg: TransformerConfig, params: Dict[str, Any], tokens: Array,
             targets: Array) -> Array:
-    if cfg.xent_chunk > 0 and cfg.vocab_size > cfg.xent_chunk:
-        h = forward_hidden(cfg, params, tokens)
-        return chunked_cross_entropy(h, params["Wout"], targets,
-                                     cfg.xent_chunk)
-    logits = forward(cfg, params, tokens).astype(jnp.float32)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, targets[..., None].astype(jnp.int32),
-                               axis=-1)[..., 0]
-    return jnp.mean(nll)
+    h = forward_hidden(cfg, params, tokens)
+    with jax.named_scope("head_loss"):
+        if cfg.xent_chunk > 0 and cfg.vocab_size > cfg.xent_chunk:
+            return chunked_cross_entropy(h, params["Wout"], targets,
+                                         cfg.xent_chunk)
+        logits = jnp.matmul(h, params["Wout"].astype(h.dtype))
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        nll = -jnp.take_along_axis(
+            logp, targets[..., None].astype(jnp.int32), axis=-1)[..., 0]
+        return jnp.mean(nll)
 
 
 class TransformerLM:

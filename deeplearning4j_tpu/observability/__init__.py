@@ -10,9 +10,12 @@ one substrate they all publish into:
   `Counter`/`Gauge`/`Histogram` (fixed buckets, per-cell locks,
   monotonic `perf_counter` timers); a process default registry plus
   injectable instances; `NULL_REGISTRY` to disable by injection.
-- `tracing` — nestable `span(name)` context managers recording
-  wall-time histograms and forwarding to
-  `jax.profiler.TraceAnnotation` so spans land in XLA profiles.
+- `tracing` — nestable `span(name, **args)` context managers that
+  append a record (id, parent, name, start, end, tick, rid, args) to
+  a bounded process-wide ring (`default_spans()`; `NULL_SPANS`
+  disables by injection), hold a `jax.profiler.TraceAnnotation` so
+  spans land in XLA profiles, and `snapshot()` with a clock anchor
+  that puts them on the profiler's clock.
 - `export` — Prometheus text exposition + JSON snapshot, served by the
   stdlib `MetricsServer` (`/metrics`, `/healthz`, `/readyz` with
   pluggable health callables, plus `/debugz`, `/slo`,
@@ -55,7 +58,8 @@ from deeplearning4j_tpu.observability.metrics import (  # noqa: F401
     DECODE_LATENCY_BUCKETS, DEFAULT_BUCKETS, Counter, Gauge, Histogram,
     MetricsRegistry, NULL_REGISTRY, NullRegistry, default_registry)
 from deeplearning4j_tpu.observability.tracing import (  # noqa: F401
-    current_span, span, traced)
+    NULL_SPANS, Span, SpanRing, annotate, current_span, default_spans,
+    mark, span, traced)
 from deeplearning4j_tpu.observability.export import (  # noqa: F401
     CONTENT_TYPE_LATEST, MetricsServer, json_snapshot, probe_response,
     prometheus_text, snapshot_prometheus_text)

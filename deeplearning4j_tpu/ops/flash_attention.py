@@ -406,6 +406,7 @@ def _flash_forward_impl(q3, k3, v3, scale: float, causal: bool,
             in_specs=[qspec, kvspec, kvspec],
             out_specs=[qspec, stat_spec, stat_spec],
             interpret=interpret_arg(interpret, qc, kc, vc),
+            name="flash_fwd",
         )(qc, kc, vc)
 
     chunks = _bh_chunks(bh, tq // qsb, _MAX_2D_GRID_FWD)
@@ -463,6 +464,7 @@ def _flash_backward(q3, k3, v3, o3, m, logl, g, scale, causal, q_offset,
             out_specs=[full, kspec, kspec],
             scratch_shapes=[pltpu.VMEM((tq, d), jnp.float32)],
             interpret=interpret_arg(interpret, *args),
+            name="flash_bwd",
         )(*args)
 
     operands = (q3, k3, v3, g, m, logl, delta)

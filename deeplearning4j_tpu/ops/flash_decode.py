@@ -398,6 +398,7 @@ def _split_k_call(kernel, q3: Array, k_cache: Array, v_cache: Array, pos,
         interpret=interpret_arg(
             os.environ.get("DL4JTPU_FLASH") == "interpret",
             q3, k_cache, v_cache),
+        name="flash_decode" if window == 1 else "flash_decode_window",
     )(reach_blk, pos_rows.reshape(b, 1, 1), q3, k_cache, v_cache)
 
 

@@ -147,9 +147,10 @@ def make_fsdp_train_step(cfg: TransformerConfig, mesh: Mesh, *,
             loss, grads = jax.value_and_grad(
                 lambda p: loss_fn(cfg, p, tokens, targets))(params)
         count = opt.count + 1
-        params, m, v = adam_update_tree(
-            params, grads, opt.m, opt.v, count.astype(jnp.float32),
-            learning_rate=learning_rate, b1=b1, b2=b2, eps=eps)
+        with jax.named_scope("optimizer"):
+            params, m, v = adam_update_tree(
+                params, grads, opt.m, opt.v, count.astype(jnp.float32),
+                learning_rate=learning_rate, b1=b1, b2=b2, eps=eps)
         return params, AdamState(m, v, count), loss
 
     return jax.jit(step,

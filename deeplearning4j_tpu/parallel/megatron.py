@@ -137,40 +137,41 @@ def _block_fwd_sharded(h: Array, p: Dict[str, Array],
     f_model = _f_sync("model")
     g_model = _g_sync("model")
 
-    x = layer_norm(h, p["ln1g"], p["ln1b"], cfg.eps)
-    x = f_model(x)
-
     def heads(y):
         return y.reshape(y.shape[0], y.shape[1], h_loc, cfg.d_head)
 
-    q = heads(jnp.matmul(x, p["Wq"].astype(x.dtype)))
-    k = heads(jnp.matmul(x, p["Wk"].astype(x.dtype)))
-    v = heads(jnp.matmul(x, p["Wv"].astype(x.dtype)))
-    if sp > 1:
-        # seq_impl validated upfront by make_parallel_train_step
-        if cfg.seq_impl == "ulysses":
-            a = ulysses_attention(q, k, v, "seq", causal=True)
-        else:
-            a = ring_attention(q, k, v, "seq", causal=True)
-    else:
-        from deeplearning4j_tpu.nn.layers.attention import \
-            dot_product_attention
-        a = dot_product_attention(q, k, v, causal=True)
-    a = a.reshape(a.shape[0], a.shape[1], h_loc * cfg.d_head)
-    attn_out = jnp.matmul(a, p["Wo"].astype(a.dtype))
-    attn_out = g_model(attn_out)
-    h = h + attn_out
-
-    x = layer_norm(h, p["ln2g"], p["ln2b"], cfg.eps)
-    if cfg.n_experts > 0:
-        h = h + _moe_sharded(x, p, cfg, dp)
-    else:
+    with jax.named_scope("attn"):
+        x = layer_norm(h, p["ln1g"], p["ln1b"], cfg.eps)
         x = f_model(x)
-        z = jax.nn.gelu(jnp.matmul(x, p["W1"].astype(x.dtype))
-                        + p["b1"].astype(x.dtype))
-        m = jnp.matmul(z, p["W2"].astype(z.dtype))
-        m = g_model(m)
-        h = h + m + p["b2"].astype(h.dtype)
+        q = heads(jnp.matmul(x, p["Wq"].astype(x.dtype)))
+        k = heads(jnp.matmul(x, p["Wk"].astype(x.dtype)))
+        v = heads(jnp.matmul(x, p["Wv"].astype(x.dtype)))
+        if sp > 1:
+            # seq_impl validated upfront by make_parallel_train_step
+            if cfg.seq_impl == "ulysses":
+                a = ulysses_attention(q, k, v, "seq", causal=True)
+            else:
+                a = ring_attention(q, k, v, "seq", causal=True)
+        else:
+            from deeplearning4j_tpu.nn.layers.attention import \
+                dot_product_attention
+            a = dot_product_attention(q, k, v, causal=True)
+        a = a.reshape(a.shape[0], a.shape[1], h_loc * cfg.d_head)
+        attn_out = jnp.matmul(a, p["Wo"].astype(a.dtype))
+        attn_out = g_model(attn_out)
+        h = h + attn_out
+
+    with jax.named_scope("mlp"):
+        x = layer_norm(h, p["ln2g"], p["ln2b"], cfg.eps)
+        if cfg.n_experts > 0:
+            h = h + _moe_sharded(x, p, cfg, dp)
+        else:
+            x = f_model(x)
+            z = jax.nn.gelu(jnp.matmul(x, p["W1"].astype(x.dtype))
+                            + p["b1"].astype(x.dtype))
+            m = jnp.matmul(z, p["W2"].astype(z.dtype))
+            m = g_model(m)
+            h = h + m + p["b2"].astype(h.dtype)
     return h
 
 
@@ -478,10 +479,12 @@ def make_parallel_train_step(cfg: TransformerConfig, mesh: Mesh, *,
         dt = cfg.activation_dtype()
         b_loc, tl = tokens_loc.shape
         seq_idx = lax.axis_index("seq").astype(jnp.int32)
-        pos = lax.dynamic_slice(params["pos"],
-                                (seq_idx * tl, jnp.int32(0)),
-                                (tl, cfg.d_model))
-        h = params["embed"].astype(dt)[tokens_loc] + pos.astype(dt)[None]
+        with jax.named_scope("embed"):
+            pos = lax.dynamic_slice(params["pos"],
+                                    (seq_idx * tl, jnp.int32(0)),
+                                    (tl, cfg.d_model))
+            h = (params["embed"].astype(dt)[tokens_loc]
+                 + pos.astype(dt)[None])
         # microbatch split for the pipeline
         if b_loc % m_:
             raise ValueError(f"local batch {b_loc} not divisible by "
@@ -490,23 +493,24 @@ def make_parallel_train_step(cfg: TransformerConfig, mesh: Mesh, *,
         h_mb = h.reshape(m_, mb, tl, cfg.d_model)
         out = _pipeline_apply(params["blocks"], h_mb, cfg, mesh)
         hf = out.reshape(b_loc, tl, cfg.d_model)
-        hf = layer_norm(hf, params["lnfg"], params["lnfb"], cfg.eps)
-        if cfg.xent_chunk > 0 and cfg.vocab_size > cfg.xent_chunk:
-            # streaming vocab-panel loss on the LOCAL tokens (Wout is
-            # replicated; each shard scans its own panels) — the same
-            # real-vocab memory wall the single-chip loss_fn dodges,
-            # models/transformer.chunked_cross_entropy
-            local_sum = chunked_cross_entropy(
-                hf, params["Wout"], targets_loc,
-                cfg.xent_chunk) * (b_loc * tl)
-        else:
-            logits = jnp.matmul(hf, params["Wout"].astype(hf.dtype))
-            logits = logits.astype(jnp.float32)
-            logp = jax.nn.log_softmax(logits, axis=-1)
-            nll = -jnp.take_along_axis(
-                logp, targets_loc[..., None].astype(jnp.int32),
-                axis=-1)[..., 0]
-            local_sum = jnp.sum(nll)
+        with jax.named_scope("head_loss"):
+            hf = layer_norm(hf, params["lnfg"], params["lnfb"], cfg.eps)
+            if cfg.xent_chunk > 0 and cfg.vocab_size > cfg.xent_chunk:
+                # streaming vocab-panel loss on the LOCAL tokens (Wout
+                # is replicated; each shard scans its own panels) — the
+                # same real-vocab memory wall the single-chip loss_fn
+                # dodges, models/transformer.chunked_cross_entropy
+                local_sum = chunked_cross_entropy(
+                    hf, params["Wout"], targets_loc,
+                    cfg.xent_chunk) * (b_loc * tl)
+            else:
+                logits = jnp.matmul(hf, params["Wout"].astype(hf.dtype))
+                logits = logits.astype(jnp.float32)
+                logp = jax.nn.log_softmax(logits, axis=-1)
+                nll = -jnp.take_along_axis(
+                    logp, targets_loc[..., None].astype(jnp.int32),
+                    axis=-1)[..., 0]
+                local_sum = jnp.sum(nll)
         if s > 1:
             is_last = (lax.axis_index("pipe") == s - 1)
             local_sum = jnp.where(is_last, local_sum, 0.0)
@@ -532,9 +536,10 @@ def make_parallel_train_step(cfg: TransformerConfig, mesh: Mesh, *,
             grads, specs)
         # adam on local shards (identical math on every replica)
         cnt = count + 1
-        new_p, new_m, new_v = adam_update_tree(
-            params, grads, opt_m, opt_v, cnt.astype(jnp.float32),
-            learning_rate=learning_rate, b1=b1, b2=b2, eps=eps)
+        with jax.named_scope("optimizer"):
+            new_p, new_m, new_v = adam_update_tree(
+                params, grads, opt_m, opt_v, cnt.astype(jnp.float32),
+                learning_rate=learning_rate, b1=b1, b2=b2, eps=eps)
         return new_p, new_m, new_v, cnt, loss
 
     data_spec = P(("data",), ("seq",))

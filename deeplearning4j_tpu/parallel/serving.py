@@ -257,6 +257,17 @@ def _local_block_decode(h, p, ck_all, cv_all, layer: int, pos,
     return h, ck_all, cv_all
 
 
+def _jit_program(run, maker: str, mesh: Mesh, in_specs, out_specs):
+    """`jax.jit(shard_map(run))` under a module name of its own,
+    `jit_run_<maker>`: every maker's body is a local `def run`, so
+    without this all serving programs are `jit_run` in a profiler trace
+    and can be told apart only by what they hold. int8 and constrained
+    bodies share their maker's name."""
+    run.__name__ = run.__qualname__ = f"run_{maker}"
+    return jax.jit(jax.shard_map(run, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=True))
+
+
 def make_parallel_generate(cfg: TransformerConfig, mesh: Mesh,
                            max_new_tokens: int,
                            temperature: float = 0.0,
@@ -347,10 +358,9 @@ def make_parallel_generate(cfg: TransformerConfig, mesh: Mesh,
         return jnp.concatenate([prompt, jnp.swapaxes(toks, 0, 1)],
                                axis=1)
 
-    sharded = jax.shard_map(run, mesh=mesh,
-                            in_specs=(specs, P("data", None), P()),
-                            out_specs=P("data", None), check_vma=True)
-    return jax.jit(sharded)
+    return _jit_program(run, "parallel_generate", mesh,
+                        (specs, P("data", None), P()),
+                        P("data", None))
 
 
 def _check_serving_mesh(cfg: TransformerConfig, mesh: Mesh,
@@ -420,10 +430,11 @@ def _sample_slots(logits, posidx, key, dp: int, temperature: float,
     the same continuation (models/transformer.sample_at_positions owns
     the core; this wrapper adds the data-rank key fold). Greedy
     (temperature<=0) ignores the key entirely."""
-    if temperature > 0 and dp > 1:
-        key = jax.random.fold_in(key, lax.axis_index("data"))
-    return sample_at_positions(logits, posidx, key, temperature,
-                               top_k, top_p)
+    with jax.named_scope("sample"):
+        if temperature > 0 and dp > 1:
+            key = jax.random.fold_in(key, lax.axis_index("data"))
+        return sample_at_positions(logits, posidx, key, temperature,
+                                   top_k, top_p)
 
 
 # constrained-decoding runtime operands (ISSUE-20): every masked
@@ -756,9 +767,8 @@ def make_continuous_prefill(cfg: TransformerConfig, mesh: Mesh,
                          _SLOT_VEC_SPEC, _SLOT_VEC_SPEC,
                          _SLOT_VEC_SPEC)
 
-    sharded = jax.shard_map(run, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_vma=True)
-    return jax.jit(sharded)
+    return _jit_program(run, "continuous_prefill", mesh, in_specs,
+                        out_specs)
 
 
 def make_continuous_decode(cfg: TransformerConfig, mesh: Mesh,
@@ -955,9 +965,8 @@ def make_continuous_decode(cfg: TransformerConfig, mesh: Mesh,
                          _SLOT_VEC_SPEC, _SLOT_VEC_SPEC,
                          P("data", None))
 
-    sharded = jax.shard_map(run, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_vma=True)
-    return jax.jit(sharded)
+    return _jit_program(run, "continuous_decode", mesh, in_specs,
+                        out_specs)
 
 
 def make_chunked_prefill(cfg: TransformerConfig, mesh: Mesh,
@@ -1225,9 +1234,8 @@ def make_chunked_prefill(cfg: TransformerConfig, mesh: Mesh,
                          _SLOT_VEC_SPEC, _SLOT_VEC_SPEC,
                          _SLOT_VEC_SPEC)
 
-    sharded = jax.shard_map(run, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_vma=True)
-    return jax.jit(sharded)
+    return _jit_program(run, "chunked_prefill", mesh, in_specs,
+                        out_specs)
 
 
 # ---------------------------------------------------------------------------
@@ -1335,8 +1343,9 @@ def _gather_pages(plane, bt, ns: int, s_view: int):
     """[NP, ps, D_loc] plane -> the block-table-ordered logical view
     [Ns, s_view, D_loc]: unallocated table entries read the scratch
     page; the caller's position mask keeps them out of attention."""
-    g = plane[bt]                       # [Ns, mp, ps, D_loc]
-    return g.reshape(ns, s_view, g.shape[-1])
+    with jax.named_scope("gather_pages"):
+        g = plane[bt]                   # [Ns, mp, ps, D_loc]
+        return g.reshape(ns, s_view, g.shape[-1])
 
 
 def _local_block_decode_paged(h, p, kp, vp, bt, layer: int, pos, act,
@@ -1744,9 +1753,9 @@ def make_paged_prefill(cfg: TransformerConfig, mesh: Mesh,
     if constrain:
         out_specs = out_specs + (_PAGE_VEC_SPEC,)
 
-    sharded = jax.shard_map(run, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_vma=True)
-    return jax.jit(sharded)
+    return _jit_program(run, "paged_chunked_prefill" if chunked
+                        else "paged_prefill", mesh, in_specs,
+                        out_specs)
 
 
 def make_paged_chunked_prefill(cfg: TransformerConfig, mesh: Mesh,
@@ -1951,9 +1960,8 @@ def make_paged_decode(cfg: TransformerConfig, mesh: Mesh, chunk: int,
                          _PAGE_SCALE_SPEC, _PAGE_SCALE_SPEC,
                          _PAGE_VEC_SPEC, _PAGE_VEC_SPEC, P(None, None))
 
-    sharded = jax.shard_map(run, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_vma=True)
-    return jax.jit(sharded)
+    return _jit_program(run, "paged_decode", mesh, in_specs,
+                        out_specs)
 
 
 # ---------------------------------------------------------------------------
@@ -2403,9 +2411,8 @@ def make_speculative_decode(cfg: TransformerConfig, mesh: Mesh,
                          P("data", None), _SLOT_VEC_SPEC,
                          _SLOT_VEC_SPEC, _SLOT_VEC_SPEC)
 
-    sharded = jax.shard_map(run, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_vma=True)
-    return jax.jit(sharded)
+    return _jit_program(run, "speculative_decode", mesh, in_specs,
+                        out_specs)
 
 
 def make_paged_speculative_decode(cfg: TransformerConfig, mesh: Mesh,
@@ -2665,9 +2672,8 @@ def make_paged_speculative_decode(cfg: TransformerConfig, mesh: Mesh,
                          _PAGE_VEC_SPEC, _PAGE_VEC_SPEC,
                          _PAGE_VEC_SPEC)
 
-    sharded = jax.shard_map(run, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_vma=True)
-    return jax.jit(sharded)
+    return _jit_program(run, "paged_speculative_decode", mesh, in_specs,
+                        out_specs)
 
 
 def serving_param_specs(cfg: TransformerConfig):

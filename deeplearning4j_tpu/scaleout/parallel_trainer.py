@@ -14,6 +14,7 @@ from deeplearning4j_tpu.earlystopping.config import (
     EarlyStoppingConfiguration, EarlyStoppingResult)
 from deeplearning4j_tpu.earlystopping.trainer import BaseEarlyStoppingTrainer
 from deeplearning4j_tpu.nn.multilayer import _unpack_batch
+from deeplearning4j_tpu.observability.metrics import default_registry
 from deeplearning4j_tpu.observability.tracing import span
 from deeplearning4j_tpu.parallel.wrapper import ParallelWrapper
 
@@ -31,7 +32,7 @@ class EarlyStoppingParallelTrainer(BaseEarlyStoppingTrainer):
         # span: per-batch fit wall time lands in the
         # trace_span_seconds{span="scaleout/parallel_fit"} histogram
         # AND in XLA profiles (TraceAnnotation) when one is recording
-        with span("scaleout/parallel_fit"):
+        with span("scaleout/parallel_fit", registry=default_registry()):
             self.wrapper.fit(feats, labs,
                              lmask if lmask is not None else fmask)
 
@@ -57,7 +58,7 @@ class SparkEarlyStoppingTrainer(BaseEarlyStoppingTrainer):
     def _fit_batch(self, batch) -> None:
         feats, labs, fmask, lmask = _unpack_batch(batch)
         mask = lmask if lmask is not None else fmask
-        with span("scaleout/spark_fit"):
+        with span("scaleout/spark_fit", registry=default_registry()):
             if mask is not None:
                 # the TrainingMaster facade fits plain arrays; masked
                 # (padded-sequence) batches go through the underlying

@@ -250,6 +250,9 @@ from deeplearning4j_tpu.observability.metrics import (
 from deeplearning4j_tpu.observability.profiling import (
     EngineProfiler, NULL_PROFILER, ProfileCapture, cost_from_compiled)
 from deeplearning4j_tpu.observability.slo import NULL_SLO, SLOTracker
+from deeplearning4j_tpu.observability.tracing import (annotate,
+                                                      default_spans, mark,
+                                                      span)
 from deeplearning4j_tpu.parallel.serving import (
     init_paged_state, init_slot_state, make_chunked_prefill,
     make_continuous_decode, make_continuous_prefill,
@@ -639,6 +642,10 @@ class RequestHandle:
         # tick pipeline (ISSUE-12): the scheduler's one-tick-ahead
         # view; always 0 on synchronous engines
         self._pending_n = 0
+        # leading positions whose K/V this engine has computed before
+        # (prefill or decode): what a later prefill covers again is
+        # counted as `reprefill_tokens`
+        self._kv_seen = 0
         # flight recorder (ISSUE-6): the engine swaps in a live
         # RequestTrace at submit; NULL_TRACE keeps direct
         # constructions (and disabled recording) zero-cost
@@ -716,6 +723,8 @@ class _PendingTick:
     # per-slot seeds) captured BEFORE dispatch — restoring both is
     # what makes a failed pipelined tick invisible to the DFA walk
     c_in_state: Optional[tuple] = None
+    # index of the round that dispatched it (`engine.tick`'s `tick`)
+    tick: int = 0
 
 
 # ---------------------------------------------------------------------------
@@ -1270,7 +1279,7 @@ class InferenceEngine:
                  registry=None,
                  quantize: Optional[str] = None,
                  kv_quantize: Optional[str] = None,
-                 recorder=None, slo=None, profiler=None):
+                 recorder=None, slo=None, profiler=None, spans=None):
         self.cfg = cfg
         self.mesh = mesh
         self.config = config or EngineConfig()
@@ -1572,6 +1581,12 @@ class InferenceEngine:
                             self.registry,
                             tenant_top_n=self.config.tenant_top_n))
         self.profiler = profiler
+        # the tick's spans (observability/tracing.py): the process-wide
+        # ring unless one is injected; spans=NULL_SPANS makes no record
+        # and opens no profiler annotation
+        self.spans = default_spans() if spans is None else spans
+        self._tick_no = 0
+        self._tokens_committed = 0
         self._decode_bill_label: Optional[str] = None
         self._capture = ProfileCapture(self.config.profile_dir)
         # cold-start warm-up (ISSUE-12): resolve the whole closed
@@ -1624,6 +1639,21 @@ class InferenceEngine:
             "serving_requests_preempted",
             "In-flight requests evicted from their slot (isolation or "
             "weight reload) and re-run from their committed prefix")
+        self._m_ticks_recovered = r.counter(
+            "serving_ticks_recovered",
+            "Pipelined ticks whose outputs failed at the sync and were "
+            "recovered from the last committed state")
+        blocked = r.counter(
+            "serving_admission_blocked",
+            "Scheduling rounds that left a queued request unseated, by "
+            "what was short (pages: the KV page pool; slots: every "
+            "slot taken)", labelnames=("reason",))
+        self._m_blocked = {reason: blocked.labels(reason)
+                           for reason in ("pages", "slots")}
+        self._m_reprefill_tokens = r.counter(
+            "serving_reprefill_tokens",
+            "Prompt or generated tokens prefilled again for a request "
+            "that lost its slot (preemption, isolation, reload)")
         r.gauge("serving_slot_occupancy",
                 "Occupied continuous-batching slots").set_function(
             lambda: float(sum(s is not None for s in self._slots)))
@@ -2266,9 +2296,12 @@ class InferenceEngine:
         engine_continuous benchmark's arrival-replay loop) can
         interleave submissions with decode progress."""
         if self._continuous:
-            if self._pipe:
-                return self._tick_pipelined()
-            return self._tick_continuous()
+            self._tick_no += 1
+            with span("engine.tick", spans=self.spans,
+                      tick=self._tick_no, queue=len(self._queue)):
+                if self._pipe:
+                    return self._tick_pipelined()
+                return self._tick_continuous()
         batch = self._form_batch()
         if not batch:
             return False
@@ -2538,6 +2571,9 @@ class InferenceEngine:
             # reseat/failover replay
             toks, hit_terminal = self._c_advance_commit(r, toks)
         r._generated.append(toks)
+        self._tokens_committed += int(toks.shape[0])
+        r._kv_seen = max(r._kv_seen, r.prompt.shape[0]
+                         + sum(t.shape[0] for t in r._generated) - 1)
         ev = r.trace.add(kind, tokens=int(toks.shape[0]), **data)
         if first:
             self.slo.first_token(r.trace, ev.ts)
@@ -2627,13 +2663,15 @@ class InferenceEngine:
         idx = int(self._m_batches.value)
         latency = self._clock() - t_start
         self._m_batch_seconds.observe(latency)
-        for l in self._listeners:
-            if hasattr(l, "record_batch"):
-                l.record_batch(n_active)
-            try:
-                l.iteration_done(self, idx, latency)
-            except Exception:     # listeners must not kill serving
-                log.exception("engine listener failed")
+        with span("engine.tick.listeners", spans=self.spans,
+                  listeners=len(self._listeners)):
+            for l in self._listeners:
+                if hasattr(l, "record_batch"):
+                    l.record_batch(n_active)
+                try:
+                    l.iteration_done(self, idx, latency)
+                except Exception:     # listeners must not kill serving
+                    log.exception("engine listener failed")
 
     # ------------------------------------------------------------------
     # chunked prefill: the token-budget scheduler (ISSUE-10)
@@ -2883,9 +2921,11 @@ class InferenceEngine:
                 cjar.out, o = o[-1], o[:-1]
             return tuple(o[:n_state]), self._out_sync(o[n_state])
 
-        state, first = self._guarded(call, [r for _, r in entries],
-                                     self._m_prefill_seconds,
-                                     prefill=True, chunked=True)
+        state, first = self._guarded(
+            call, [r for _, r in entries], self._m_prefill_seconds,
+            self._prefill_work(name, [(r, r._prefill_pos, n)
+                                      for _, r, n in plan]),
+            prefill=True, chunked=True)
         if cjar is not None:
             self._cmask_commit(cjar)
         self._slot_state = state
@@ -2986,17 +3026,19 @@ class InferenceEngine:
         self._pipe_items = []
         self._pipe_defer = True
         try:
-            if self._prefill_chunk is not None:
-                n_active = self._dispatch_budgeted(admitted, params)
-            else:
-                n_active = self._dispatch_oneshot(admitted, params)
+            with span("engine.tick.dispatch", spans=self.spans):
+                if self._prefill_chunk is not None:
+                    n_active = self._dispatch_budgeted(admitted, params)
+                else:
+                    n_active = self._dispatch_oneshot(admitted, params)
         finally:
             self._pipe_defer = False
             items, self._pipe_items = self._pipe_items, None
         if not items:
             return None
         return _PendingTick(items=items, in_state=self._pipe_in_state,
-                            n_active=n_active, c_in_state=c_in)
+                            n_active=n_active, c_in_state=c_in,
+                            tick=self._tick_no)
 
     def _sched_decoding(self) -> List[tuple]:
         """Slots eligible for this tick's decode dispatch under the
@@ -3112,6 +3154,13 @@ class InferenceEngine:
         commit them in dispatch order: prefill first tokens, then
         decode chunks — exactly what the synchronous tick would have
         committed, one tick later."""
+        with span("engine.tick.commit", spans=self.spans,
+                  commits_tick=prev.tick):
+            n0 = self._tokens_committed
+            self._commit_tick_items(prev)
+            annotate(tokens=self._tokens_committed - n0)
+
+    def _commit_tick_items(self, prev: "_PendingTick") -> None:
         # a speculative item's deferred outputs are a TUPLE (toks,
         # ncommit, drafted, accepted); flatten across items so the
         # whole tick still drains through ONE blocking sync
@@ -3121,7 +3170,8 @@ class InferenceEngine:
             spans.append(len(out))
             flat.extend(out)
         try:
-            drained = self._block_on_many(flat)
+            with span("engine.tick.commit.sync", spans=self.spans):
+                drained = self._block_on_many(flat)
         except RuntimeError as e:
             self._recover_failed_tick(prev, e)
             return
@@ -3242,6 +3292,12 @@ class InferenceEngine:
                     if id(r) not in seen:
                         seen.add(id(r))
                         reqs.append(r)
+        self._m_ticks_recovered.inc()
+        with span("engine.tick.recover", spans=self.spans,
+                  requests=len(reqs), error=type(err).__name__):
+            self._restore_committed(prev, reqs, err)
+
+    def _restore_committed(self, prev: "_PendingTick", reqs, err) -> None:
         self._slot_state = prev.in_state
         if prev.c_in_state is not None:
             # roll the device DFA back with the KV: restore the
@@ -3295,7 +3351,7 @@ class InferenceEngine:
         the queue is served highest class first. preemption_budget=0
         keeps FIFO seating bit-identically."""
         admitted = []
-        with self._lock:
+        with span("engine.tick.admit", spans=self.spans), self._lock:
             if self._preempt_budget > 0:
                 self._preempt_for_priority_locked()
             # deque cursor, not list.pop(0) (ISSUE-10 satellite): the
@@ -3303,6 +3359,7 @@ class InferenceEngine:
             # order; the popleft cursor is order-stable by construction
             free = deque(i for i in range(self._num_slots)
                          if self._slots[i] is None)
+            n_free = len(free)
             seated_order: List[RequestHandle] = []
             while free and self._queue:
                 r = self._pop_request_locked()
@@ -3334,6 +3391,7 @@ class InferenceEngine:
                         # unless _seat_adopted already shed it
                         if not r.done():
                             self._queue.appendleft(r)
+                            self._admission_blocked("pages", r)
                         break
                     if r.done():
                         continue     # shed typed "handoff" at seating
@@ -3346,6 +3404,7 @@ class InferenceEngine:
                         # that could never fit
                         if not r.done():
                             self._queue.appendleft(r)
+                            self._admission_blocked("pages", r)
                         break
                     hit = seated
                 free.popleft()
@@ -3406,7 +3465,18 @@ class InferenceEngine:
             # prefill scheduler builds on
             assert [r for _, r in admitted] == seated_order, \
                 "admission order diverged from queue order"
+            if not free and self._queue:
+                self._admission_blocked("slots", self._queue[0])
+            annotate(seated=n_free - len(free), prefix_tokens=sum(
+                r._prefill_pos for _, r in admitted))
         return admitted
+
+    def _admission_blocked(self, reason: str, r: RequestHandle) -> None:
+        """The queue's head stays unseated this round: `pages` (the
+        pool, after eviction, cannot cover it) or `slots` (none free)."""
+        self._m_blocked[reason].inc()
+        mark("engine.admit.blocked", spans=self.spans, reason=reason,
+             rid=r.rid)
 
     def _pop_request_locked(self) -> RequestHandle:
         """Next request to seat. FIFO unless priority preemption is on
@@ -4469,8 +4539,11 @@ class InferenceEngine:
                 cjar.out, o = o[-1], o[:-1]
             return tuple(o[:n_state]), self._out_sync(o[n_state])
 
-        out = self._guarded(call, [r for _, r in entries],
-                            self._m_prefill_seconds, prefill=True)
+        out = self._guarded(
+            call, [r for _, r in entries], self._m_prefill_seconds,
+            self._prefill_work(f"prefill_b{int(tb)}",
+                               [(r, 0, int(plen[i]))
+                                for i, r in entries]), prefill=True)
         if cjar is not None:
             self._cmask_commit(cjar)
         # per-tenant prefill billing (ISSUE-15): every prompt token
@@ -4522,7 +4595,8 @@ class InferenceEngine:
             return tuple(o[:n_state]), self._out_sync(o[n_state])
 
         out = self._guarded(call, [r for _, r in entries],
-                            self._m_step_seconds)
+                            self._m_step_seconds,
+                            self._decode_work(entries, rem, self._chunk))
         if cjar is not None:
             self._cmask_commit(cjar)
         return out
@@ -4582,8 +4656,11 @@ class InferenceEngine:
                 cjar.out, o = o[-1], o[:-1]
             return tuple(o[:n_state]), self._out_sync(o[n_state])
 
-        out = self._guarded(call, [r for _, r in entries],
-                            self._m_prefill_seconds, prefill=True)
+        out = self._guarded(
+            call, [r for _, r in entries], self._m_prefill_seconds,
+            self._prefill_work(f"paged_prefill_b{int(tb)}",
+                               [(r, int(start[i]), int(slen[i]))
+                                for i, r in entries]), prefill=True)
         if cjar is not None:
             self._cmask_commit(cjar)
         # per-tenant prefill billing (ISSUE-15): the SUFFIX lengths —
@@ -4637,7 +4714,8 @@ class InferenceEngine:
             return tuple(o[:n_state]), self._out_sync(o[n_state])
 
         out = self._guarded(call, [r for _, r in entries],
-                            self._m_step_seconds)
+                            self._m_step_seconds,
+                            self._decode_work(entries, rem, self._chunk))
         if cjar is not None:
             self._cmask_commit(cjar)
         return out
@@ -4851,7 +4929,8 @@ class InferenceEngine:
                     *self._out_sync_many(o[n_state:n_state + 4]))
 
         state, toks, nc, drafted, accepted = self._guarded(
-            call, [r for _, r in entries], self._m_step_seconds)
+            call, [r for _, r in entries], self._m_step_seconds,
+            self._decode_work(entries, rem, self._spec_cur_k + 1))
         if cjar is not None:
             self._cmask_commit(cjar)
         return state, toks, nc, drafted, accepted, poison
@@ -4913,7 +4992,8 @@ class InferenceEngine:
                     *self._out_sync_many(o[n_state:n_state + 4]))
 
         state, toks, nc, drafted, accepted = self._guarded(
-            call, [r for _, r in entries], self._m_step_seconds)
+            call, [r for _, r in entries], self._m_step_seconds,
+            self._decode_work(entries, rem, self._spec_cur_k + 1))
         if cjar is not None:
             self._cmask_commit(cjar)
         return state, toks, nc, drafted, accepted, poison
@@ -4964,18 +5044,24 @@ class InferenceEngine:
     def _reap(self, shed: bool = False) -> None:
         """Free slots whose request reached a terminal state; with
         ``shed``, first run the deadline check over occupied slots."""
-        if shed:
-            self._shed_expired([r for _, r in self._occupied()])
-        with self._lock:
-            for i, r in enumerate(self._slots):
-                if r is not None and r.done():
-                    if r._hold_kv:
-                        # held for KV export (ISSUE-11): the slot (and
-                        # its pages) stays seated until release_held /
-                        # export_slot_kv frees it
-                        continue
-                    self._free_slot(i)
-                    self._leave_flight(r)
+        with span("engine.tick.reap", spans=self.spans):
+            n_shed = n_freed = 0
+            if shed:
+                live = [r for _, r in self._occupied() if not r.done()]
+                self._shed_expired(live)
+                n_shed = sum(r.done() for r in live)
+            with self._lock:
+                for i, r in enumerate(self._slots):
+                    if r is not None and r.done():
+                        if r._hold_kv:
+                            # held for KV export (ISSUE-11): the slot
+                            # (and its pages) stays seated until
+                            # release_held / export_slot_kv frees it
+                            continue
+                        self._free_slot(i)
+                        self._leave_flight(r)
+                        n_freed += 1
+            annotate(finished=n_freed, shed=n_shed)
 
     def _leave_flight(self, r: RequestHandle) -> None:
         if r._in_flight:
@@ -5107,8 +5193,46 @@ class InferenceEngine:
     # ------------------------------------------------------------------
     # the guarded decode step
     # ------------------------------------------------------------------
+    def _prefill_work(self, program: str, rows) -> dict:
+        """Args of an `engine.dispatch.prefill` span for ``rows``
+        [(handle, start, n)], each advancing n tokens from position
+        start: the live tokens, their causal (query, key) pairs, and
+        the tokens among them whose K/V this engine had computed
+        before (a request that lost its slot is prefilled again from
+        its committed prefix). Counted as
+        `perfbench/harness/stats.decode_and_prefill_work` counts them,
+        so the program's count and a client's can be held against
+        each other."""
+        tokens = pairs = again = 0
+        for r, start, n in rows:
+            tokens += n
+            pairs += n * start + n * (n + 1) // 2
+            again += max(0, min(start + n, r._kv_seen) - start)
+            r._kv_seen = max(r._kv_seen, start + n)
+        self._m_reprefill_tokens.inc(again)
+        return {"program": program, "rows": len(rows),
+                "prefill_tokens": tokens, "prefill_pairs": pairs,
+                "reprefill_tokens": again}
+
+    def _decode_work(self, entries, rem, steps: int) -> dict:
+        """Args of an `engine.dispatch.decode` span: the tokens this
+        call is scheduled to produce (min(steps, remaining budget) a
+        row; a speculative round's are its worst case) and the live
+        cache rows they attend, the row just written included."""
+        tokens = rows = 0
+        for i, r in entries:
+            n = max(0, min(steps, int(rem[i])))
+            done = r.max_new_tokens - int(rem[i])
+            tokens += n
+            rows += (n * (r.prompt.shape[0] + done - 1)
+                     + n * (n + 1) // 2)
+        return {"program": self._decode_bill_label,
+                "rows": len(entries), "steps": steps,
+                "decode_tokens": tokens, "decode_rows": rows}
+
     def _guarded(self, call, reqs: List[RequestHandle], hist,
-                 prefill: bool = False, chunked: bool = False):
+                 work: dict, prefill: bool = False,
+                 chunked: bool = False):
         """One compiled-call guard shared by every decode path:
         fault-injection hook (the injector sees the request ids of ALL
         co-resident work), latency histogram, retry with exponential
@@ -5116,46 +5240,55 @@ class InferenceEngine:
         breaker accounting. The step counter indexes COMPLETED calls —
         prefills and chunks share it — so a failed attempt retries the
         same index (ServingFaultInjector contract). Raises
-        _BatchDecodeFailed after max_retries."""
+        _BatchDecodeFailed after max_retries.
+
+        The whole guard, retries included, is one
+        `engine.dispatch.prefill` / `engine.dispatch.decode` span
+        whose args are ``work`` (`_prefill_work` / `_decode_work`). A
+        pipelined dispatch returns before the device has finished; a
+        synchronous one blocks inside the span."""
         rids = [r.rid for r in reqs]
         attempt = 0
-        while True:
-            try:
-                if self._injector is not None:
-                    hook = self._injector.on_decode_step
-                    if (prefill and chunked
-                            and hasattr(self._injector,
-                                        "on_prefill_chunk")):
-                        hook = self._injector.on_prefill_chunk
-                    elif prefill and hasattr(self._injector,
-                                             "on_prefill"):
-                        hook = self._injector.on_prefill
-                    hook(self._step_counter, rids)
-                t_step = _perf()
-                self._busy_mark()
-                out = call()
-                hist.observe(_perf() - t_step)
-                self._record_success()
-                self._step_counter += 1
-                return out
-            except RuntimeError as e:       # XlaRuntimeError, injected
-                self._record_failure(e)
-                attempt += 1
-                if attempt > self.config.max_retries:
-                    raise _BatchDecodeFailed(str(e)) from e
-                self._m_retries.inc()
-                for r in reqs:
-                    r.trace.add("retry", step=self._step_counter,
-                                attempt=attempt, prefill=prefill)
-                delay = min(self.config.backoff_base_s
-                            * (2 ** (attempt - 1)),
-                            self.config.backoff_max_s)
-                log.warning(
-                    "decode step %d failed (%s); retry %d/%d in %.3fs",
-                    self._step_counter, e, attempt,
-                    self.config.max_retries, delay)
-                if delay > 0:
-                    time.sleep(delay)
+        with span("engine.dispatch.prefill" if prefill
+                  else "engine.dispatch.decode", spans=self.spans,
+                  **work):
+            while True:
+                try:
+                    if self._injector is not None:
+                        hook = self._injector.on_decode_step
+                        if (prefill and chunked
+                                and hasattr(self._injector,
+                                            "on_prefill_chunk")):
+                            hook = self._injector.on_prefill_chunk
+                        elif prefill and hasattr(self._injector,
+                                                 "on_prefill"):
+                            hook = self._injector.on_prefill
+                        hook(self._step_counter, rids)
+                    t_step = _perf()
+                    self._busy_mark()
+                    out = call()
+                    hist.observe(_perf() - t_step)
+                    self._record_success()
+                    self._step_counter += 1
+                    return out
+                except RuntimeError as e:       # XlaRuntimeError, injected
+                    self._record_failure(e)
+                    attempt += 1
+                    if attempt > self.config.max_retries:
+                        raise _BatchDecodeFailed(str(e)) from e
+                    self._m_retries.inc()
+                    for r in reqs:
+                        r.trace.add("retry", step=self._step_counter,
+                                    attempt=attempt, prefill=prefill)
+                    delay = min(self.config.backoff_base_s
+                                * (2 ** (attempt - 1)),
+                                self.config.backoff_max_s)
+                    log.warning(
+                        "decode step %d failed (%s); retry %d/%d in %.3fs",
+                        self._step_counter, e, attempt,
+                        self.config.max_retries, delay)
+                    if delay > 0:
+                        time.sleep(delay)
 
     def _invoke(self, params, prompts: np.ndarray, n: int,
                 reqs: List[RequestHandle]) -> np.ndarray:
@@ -5191,7 +5324,9 @@ class InferenceEngine:
         def call():
             return self._block_on(fn(params, jnp.asarray(prompts), key))
 
-        out = self._guarded(call, reqs, self._m_step_seconds)
+        out = self._guarded(call, reqs, self._m_step_seconds,
+                            {"program": "generate", "rows": b,
+                             "steps": int(n)})
         return out[:b, prompts.shape[1]:]
 
     def _isolate(self, active: List[RequestHandle], params,
