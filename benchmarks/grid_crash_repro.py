@@ -60,8 +60,8 @@ def main() -> int:
     k = jax.random.normal(ks[1], (bh, t, d), jnp.bfloat16)
     v = jax.random.normal(ks[2], (bh, t, d), jnp.bfloat16)
     try:
-        out, _, _ = jax.jit(lambda a, b, c: _flash_forward(
-            a, b, c, 0.125, True, 0, 0, False))(q, k, v)
+        out, _ = jax.jit(lambda a, b, c: _flash_forward(
+            a, b, c, d, 0.125, True, 0, 0, False))(q, k, v)
         float(jnp.sum(out.astype(jnp.float32)))
     except Exception as e:
         msg = f"{type(e).__name__}: {e}"

@@ -12,8 +12,15 @@ import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.nn.layers.attention import dot_product_attention
-from deeplearning4j_tpu.ops.flash_attention import (flash_attention,
+from deeplearning4j_tpu.ops.flash_attention import (_lane_dense_width,
+                                                    flash_attention,
                                                     flash_attention_available)
+
+# (heads, head_dim) that take the block's own [B, T, H*Dh] layout, two
+# heads a program at 64 and one at 128; the (2, 16) and (2, 32) beside
+# them in the lists below keep one head an entry of [B*H, T, Dh]
+# (H*Dh < 128), and (4, 32) fills one 128-lane group with four heads
+LANE_DENSE = [(2, 64), (4, 64), (2, 128)]
 
 
 @pytest.fixture(autouse=True)
@@ -27,9 +34,11 @@ def _rand(shape, seed):
         np.random.default_rng(seed).normal(size=shape).astype(np.float32))
 
 
+@pytest.mark.parametrize("h,d", [(4, 32), (2, 32)] + LANE_DENSE)
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_matches_reference(causal):
-    b, t, h, d = 2, 128, 4, 32
+def test_flash_matches_reference(causal, h, d):
+    b, t = 2, 128
+    assert bool(_lane_dense_width(h, d, t, t)) == (h * d % 128 == 0)
     q, k, v = (_rand((b, t, h, d), s) for s in (0, 1, 2))
     got = flash_attention(q, k, v, causal=causal)
     os.environ["DL4JTPU_FLASH"] = "0"
@@ -39,10 +48,12 @@ def test_flash_matches_reference(causal):
                                rtol=2e-4, atol=2e-5)
 
 
-def test_flash_offsets_match_reference():
+@pytest.mark.parametrize("h,d", [(2, 16)] + LANE_DENSE)
+def test_flash_offsets_match_reference(h, d):
     """Blockwise callers pass global position offsets; causal masking must
-    line up with the monolithic computation."""
-    b, t, h, d = 1, 128, 2, 16
+    line up with the monolithic computation (a suffix block of a longer
+    sequence: sk != tq)."""
+    b, t = 1, 128
     q, k, v = (_rand((b, 2 * t, h, d), s) for s in (3, 4, 5))
     os.environ["DL4JTPU_FLASH"] = "0"
     full = dot_product_attention(q, k, v, causal=True)
@@ -54,16 +65,24 @@ def test_flash_offsets_match_reference():
                                rtol=2e-4, atol=2e-5)
 
 
-def test_flash_gradients_match_reference():
-    b, t, h, d = 1, 64, 2, 16
-    q, k, v = (_rand((b, t, h, d), s) for s in (6, 7, 8))
+@pytest.mark.parametrize("h,d,tq,sk,causal", [
+    (2, 16, 64, 64, True),
+    # two q tiles by two k tiles, so the diagonal splits the loops
+    (2, 64, 1024, 1024, True), (4, 64, 128, 128, True),
+    (2, 128, 128, 128, True),
+    # cross-attention lengths: sk != tq, every tile unmasked
+    (2, 64, 128, 384, False), (2, 128, 128, 256, False)])
+def test_flash_gradients_match_reference(h, d, tq, sk, causal):
+    b = 1
+    q = _rand((b, tq, h, d), 6)
+    k, v = (_rand((b, sk, h, d), s) for s in (7, 8))
 
     def loss_flash(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, causal=True) ** 2)
+        return jnp.sum(flash_attention(q, k, v, causal=causal) ** 2)
 
     def loss_ref(q, k, v):
         os.environ["DL4JTPU_FLASH"] = "0"
-        out = dot_product_attention(q, k, v, causal=True)
+        out = dot_product_attention(q, k, v, causal=causal)
         os.environ["DL4JTPU_FLASH"] = "interpret"
         return jnp.sum(out ** 2)
 
@@ -105,13 +124,15 @@ def test_eligibility_rules():
     assert not flash_attention_available(q_ok, k_odd, None)
 
 
-def test_gradients_with_fully_masked_rows():
+@pytest.mark.parametrize("h,d", [(2, 16)] + LANE_DENSE)
+def test_gradients_with_fully_masked_rows(h, d):
     """kv_offset > q_offset creates causal rows with zero valid keys;
     the forward degenerates to a uniform average and the Pallas
     backward must reproduce the reference VJP exactly (regression:
     a single pre-summed logsumexp lost log(l) to f32 rounding on
-    those rows, inflating p from 1/S to 1)."""
-    b, t, h, d = 1, 128, 2, 16
+    those rows, inflating p from 1/S to 1). Either layout keeps m and
+    log(l) apart there, as two lane-major rows a head."""
+    b, t = 1, 128
     q, k, v = (_rand((b, t, h, d), s) for s in (7, 8, 9))
 
     def loss_flash(q, k, v):
@@ -131,6 +152,87 @@ def test_gradients_with_fully_masked_rows():
     for g1, g2 in zip(got, want):
         np.testing.assert_allclose(np.asarray(g1), np.asarray(g2),
                                    rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("h,d", LANE_DENSE)
+def test_suffix_block_gradients_match_reference(h, d):
+    """What ring attention asks of the entry: the gradients of a suffix
+    block of queries over a longer key sequence, by integer offsets."""
+    b, t = 1, 128
+    q = _rand((b, t, h, d), 12)
+    k, v = (_rand((b, 2 * t, h, d), s) for s in (13, 14))
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(
+            fn(q, k, v, causal=True, q_offset=t, kv_offset=0) ** 2)
+
+    got = jax.grad(loss(flash_attention), argnums=(0, 1, 2))(q, k, v)
+    os.environ["DL4JTPU_FLASH"] = "0"
+    want = jax.grad(loss(dot_product_attention), argnums=(0, 1, 2))(q, k, v)
+    os.environ["DL4JTPU_FLASH"] = "interpret"
+    for a, b_ in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   rtol=1e-3, atol=1e-4)
+
+
+def test_layout_gate_mark_and_counter():
+    """Shapes alone choose the layout, and a trace-time mark and a
+    counter say which was taken."""
+    from deeplearning4j_tpu.observability.metrics import default_registry
+    from deeplearning4j_tpu.observability.tracing import default_spans
+
+    assert _lane_dense_width(16, 64, 1024, 1024) == 128
+    assert _lane_dense_width(16, 128, 2048, 2048) == 128
+    assert _lane_dense_width(4, 256, 1024, 1024) == 256
+    assert _lane_dense_width(2, 16, 128, 128) == 0      # H*Dh = 32
+    assert _lane_dense_width(8, 96, 1024, 1024) == 0    # Dh = 96
+    assert _lane_dense_width(3, 64, 1024, 1024) == 0    # half a group
+    # past one superblock: the host tilings keep the per-head form
+    assert _lane_dense_width(16, 128, 4096, 4096) == 0
+    assert _lane_dense_width(16, 128, 1024, 8192) == 0
+    # a kv length the backward cannot tile (its jnp fallback)
+    assert _lane_dense_width(16, 64, 128, 200) == 0
+
+    def seen(layout):
+        fam = default_registry().get("flash_attention_calls")
+        return fam.labels(layout).value if fam else 0.0
+
+    for (h, d), layout, hpb in (((2, 16), "per_head", 1),
+                                ((2, 64), "lane_dense", 2),
+                                ((2, 128), "lane_dense", 1)):
+        before = seen(layout)
+        x = _rand((1, 128, h, d), 0)
+        flash_attention(x, x, x, causal=True)
+        assert seen(layout) == before + 1
+        last = [sp for sp in default_spans().snapshot().spans
+                if sp.name == "flash_attention.layout"][-1]
+        assert last.args == {"layout": layout, "heads_per_block": hpb}
+
+
+def test_lane_dense_lowering_has_no_transpose(monkeypatch):
+    """Lowered for the TPU (no chip needed to lower), the gradient of
+    an eligible shape is the two Mosaic calls and reshapes: no
+    transpose of q, k, v, o or their cotangents. The per-head shape
+    beside it shows what the search would find."""
+    import re
+
+    monkeypatch.setenv("DL4JTPU_FLASH", "auto")   # the Mosaic call itself
+
+    def lowered(shape):
+        x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+        grad = jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=True).astype(jnp.float32) ** 2), (0, 1, 2))
+        # Mosaic has no 64-bit index arithmetic; the tests' x64 mode is
+        # not how the chip runs
+        with jax.enable_x64(False):
+            return jax.jit(grad).trace(x, x, x).lower(
+                lowering_platforms=("tpu",)).as_text()
+
+    dense = lowered((2, 256, 16, 64))
+    assert dense.count("tpu_custom_call") == 2
+    assert not re.findall(r"stablehlo\.transpose", dense)
+    per_head = lowered((2, 256, 2, 16))
+    assert len(re.findall(r"stablehlo\.transpose", per_head)) >= 6
 
 
 def test_multi_superblock_and_chunked_backward_paths():
