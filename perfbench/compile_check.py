@@ -54,15 +54,14 @@ def serving(cell, tr: dict, args) -> int:
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from perfbench.harness import serve
-
     s = cell.sizes()
     ref = cell.reference()
     devices = described_devices(cell.chips)
+    from deeplearning4j_tpu.models.transformer import page_pool_shape
     from deeplearning4j_tpu.parallel.mesh import MeshSpec, make_mesh
     from deeplearning4j_tpu.parallel.serving import (
         make_paged_chunked_prefill, make_paged_decode, serving_param_specs)
-    cfg = serve.model_config(cell.config, s)
+    cfg = cell.program_config(s)
     mesh = make_mesh(MeshSpec(), devices=devices)
     e = dict(tr["engine"])
     if args.kv_pages:
@@ -78,7 +77,7 @@ def serving(cell, tr: dict, args) -> int:
         lambda sh, sp: sds(sh, np.float32, NamedSharding(mesh, sp)),
         ref.leaf_shapes(s), serving_param_specs(cfg),
         is_leaf=lambda x: isinstance(x, tuple))
-    pool = sds((cfg.n_layers, npages, ps, cfg.d_model), jnp.bfloat16)
+    pool = sds(page_pool_shape(cfg, npages, ps), jnp.bfloat16)
     vec = sds((ns,), np.int32)
     state = (pool, pool, vec, vec)
     bt = sds((ns, mp), np.int32)
@@ -130,7 +129,7 @@ def main(argv=None) -> int:
     from deeplearning4j_tpu.parallel.mesh import MeshSpec, make_mesh
     from deeplearning4j_tpu.parallel.optim import AdamState
     from perfbench.harness import train
-    cfg = train.train_config(cell.config, tr, s)
+    cfg = train.train_config(cell, tr, s)
     shapes = ref.leaf_shapes(s)
     if tr["entry"] == "megatron":
         from deeplearning4j_tpu.parallel.megatron import (

@@ -1,19 +1,16 @@
-"""The yardstick's arithmetic: chip peaks, and the operations and bytes
-that the algorithm needs, whatever implements it.
+"""The yardstick's arithmetic that belongs to no family of model: chip peaks,
+what a roofline is, and the live pairs of causal attention.
 
-Counts are of required work only. Causal attention counts the live half of
-the score matrix; decode attention reads each live K and V row once;
-recomputed (rematerialised) work, gathers, casts and copies count nothing.
-So a share of a peak stays a bound after a later PR swaps a kernel or takes
-a gather out. Sizes come from a configuration's file (`Sizes.from_file`).
+What a family's block needs of operations and bytes is counted in its own
+module, `perfbench/archs/<arch>.py`, which a configuration's file names
+(`harness/cells.py`); those counts are of required work only, so a share of
+a peak stays a bound after a later PR swaps a kernel.
 
 Copied in spirit from `deeplearning4j_tpu/util/flops.py` (peaks) and
 `observability/profiling.roofline`; the originals are listed in PERF.md for
 a later PR to delete.
 """
 from __future__ import annotations
-
-import dataclasses
 
 # Published per-chip peaks. Source: Google Cloud documentation, "TPU v5e"
 # (197 TFLOP/s bf16, 819 GB/s HBM, 16 GB). Keyed by jax's `device_kind`,
@@ -33,52 +30,11 @@ def peaks(device_kind: str) -> dict:
         ) from None
 
 
-@dataclasses.dataclass(frozen=True)
-class Sizes:
-    """What the arithmetic and the reference need of a configuration."""
-    n_layer: int
-    n_embd: int
-    n_head: int
-    n_inner: int
-    vocab_size: int
-    n_positions: int
-    eps: float = 1e-5
-
-    @classmethod
-    def from_file(cls, cfg: dict) -> "Sizes":
-        return cls(n_layer=int(cfg["n_layer"]), n_embd=int(cfg["n_embd"]),
-                   n_head=int(cfg["n_head"]), n_inner=int(cfg["n_inner"]),
-                   vocab_size=int(cfg["vocab_size"]),
-                   n_positions=int(cfg["n_positions"]),
-                   eps=float(cfg["layer_norm_epsilon"]))
-
-    @property
-    def d_head(self) -> int:
-        return self.n_embd // self.n_head
-
-
-def matmul_params(s: Sizes) -> int:
-    """Parameters that a token is multiplied by: q, k, v, o, the two MLP
-    matrices of every layer, and the output head. Embedding and position
-    rows are looked up, not multiplied; biases and norms are not counted."""
-    per_layer = 4 * s.n_embd * s.n_embd + 2 * s.n_embd * s.n_inner
-    return s.n_layer * per_layer + s.n_embd * s.vocab_size
-
-
-def held_params(s: Sizes) -> int:
-    """Parameters in the program's tree (separate head, MLP biases only)."""
-    d, f = s.n_embd, s.n_inner
-    per_layer = 4 * d * d + 2 * d * f + f + d + 4 * d
-    return (s.n_layer * per_layer + s.vocab_size * d + s.n_positions * d
-            + 2 * d + d * s.vocab_size)
-
-
-def forward_flops(s: Sizes, n_tokens: int, attended: int) -> float:
-    """Forward pass over `n_tokens` tokens that between them attend
-    `attended` (query, key) pairs per layer per head-dim: 2 FLOPs a weight a
-    token, plus QK^T and PV at 2 * 2 * d_model a live pair a layer."""
-    return (2.0 * matmul_params(s) * n_tokens
-            + 4.0 * s.n_embd * s.n_layer * attended)
+def roofline_s(flops: float, nbytes: float, device_kind: str) -> float:
+    """Least seconds the chip needs for that much required work: the larger
+    of FLOPs over peak and bytes over peak."""
+    pk = peaks(device_kind)
+    return max(flops / pk["flops_per_s"], nbytes / pk["bytes_per_s"])
 
 
 def causal_pairs(t: int) -> int:
@@ -90,35 +46,3 @@ def span_pairs(start: int, n: int) -> int:
     """Live pairs of n consecutive positions that follow `start` cached
     ones: position start + i attends start + i + 1 keys."""
     return n * start + n * (n + 1) // 2
-
-
-def train_flops_per_step(s: Sizes, rows: int, t: int) -> float:
-    """Forward plus backward of one step, no recomputation: three times the
-    forward's matrix products (the backward makes two for each), the
-    attention's included."""
-    return 3.0 * forward_flops(s, rows * t, rows * causal_pairs(t))
-
-
-def flash_train_roofline_s(s: Sizes, rows: int, t: int, device_kind: str,
-                           act_bytes: int = 2) -> float:
-    """Least seconds the chip needs for the attention of one step, forward
-    and backward, all layers: the larger of FLOPs over peak and bytes over
-    peak. Forward 2 products, backward 4 (dV, dP, dQ, dK) over the live
-    half; bytes are q, k, v, o read or written once forward, and q, k, v,
-    o, do read and dq, dk, dv written once backward."""
-    pk = peaks(device_kind)
-    pairs = rows * causal_pairs(t) * s.n_layer
-    flops = 6 * 2.0 * s.n_embd * pairs
-    nbytes = (4 + 8) * rows * t * s.n_embd * act_bytes * s.n_layer
-    return max(flops / pk["flops_per_s"], nbytes / pk["bytes_per_s"])
-
-
-def decode_attn_roofline_s(s: Sizes, live_rows: int, device_kind: str,
-                           cache_bytes: int = 2) -> float:
-    """Least seconds for decode attention that reads `live_rows` cached
-    positions in all (summed over slots and steps), every layer: each live K
-    and V row once (bytes), and 2 products over it (FLOPs)."""
-    pk = peaks(device_kind)
-    nbytes = 2.0 * live_rows * s.n_embd * cache_bytes * s.n_layer
-    flops = 4.0 * live_rows * s.n_embd * s.n_layer
-    return max(flops / pk["flops_per_s"], nbytes / pk["bytes_per_s"])

@@ -1,24 +1,41 @@
 """Finds a cell's files by the names in BENCHMARK.json.
 
-Nothing here knows a cell, a configuration, a traffic mix or a metric by
-name. A later PR adds `configs/<config>.json`, `traffic/<traffic>.json`,
-`metrics/<metric>.json` (naming a reader under `readers/`) and one entry
-each in BENCHMARK.json, and edits no file that is there.
+Nothing here knows a cell, a configuration, a traffic mix, a metric or a
+size by name. A later PR adds files and entries and edits no file that is
+there:
+
+- `configs/<config>.json`: any keys; `arch` and `reference` name the two
+  modules below, `reduced`, `assumed` and the deployment the cut stands for
+  say where the sizes came from;
+- `archs/<arch>.py`, where the benchmark binds to the program, one module a
+  family of block. It owes the harness `sizes(cfg)` (a frozen, hashable
+  object, a static argument of jitted reference steps, with at least
+  `vocab_size`: the ids the traffic draws from and the loss is over),
+  `rehearsal(cfg)` (the keys `--rehearse` overlays on the file, CPU-sized)
+  and `program_config(cfg, sizes, **training)` (the program's config; every
+  size in it comes from `sizes`, `cfg` gives what is not one, so that a
+  rehearsal reaches the program whole); and each metric's reader the count
+  of required work it asks for by name through `Cell.count`
+  (`train_flops_per_step`, `forward_flops`, `flash_train_roofline_s`,
+  `decode_attn_roofline_s`, ...): no recomputation, gathers, casts or copies;
+- `references/<reference>.py`, the plain reference, which imports nothing
+  of the program and takes the same `sizes`;
+- `traffic/<traffic>.json` with its own `check` limits,
+  `metrics/<metric>.json` naming a reader under `readers/` that is there or
+  new, and entries in BENCHMARK.json (`configs`, `workloads`, `per_layer`,
+  and the cell's name in its end-to-end metric's `workloads`).
 """
 from __future__ import annotations
 
 import importlib.util
 import json
+import sys
 from pathlib import Path
 from typing import Any, Dict, List
 
 ROOT = Path(__file__).resolve().parents[1]          # perfbench/
 REPO = ROOT.parent
 BENCHMARK = REPO / "BENCHMARK.json"
-
-# the --rehearse dry run's model: CPU-sized, never a measurement
-REHEARSAL_SIZES = dict(n_layer=2, n_embd=128, n_head=4, n_inner=512,
-                       vocab_size=512, n_positions=256)
 
 
 def enable_compile_cache() -> None:
@@ -40,6 +57,7 @@ def load_json(path: Path) -> Any:
 def load_module(path: Path, name: str):
     spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod       # a dataclass looks its module up there
     spec.loader.exec_module(mod)
     return mod
 
@@ -64,11 +82,34 @@ class Cell:
         self.traffic = load_json(
             root / "traffic" / f"{self.entry['traffic']}.json")
         self.root = root
+        self._arch = None
+
+    def arch(self):
+        """The family's module, loaded once a cell: its `Sizes` is one
+        class for everything that this cell builds."""
+        if self._arch is None:
+            name = self.config["arch"]
+            self._arch = load_module(self.root / "archs" / f"{name}.py",
+                                     f"perfbench_arch_{name}")
+        return self._arch
 
     def sizes(self, rehearse: bool = False):
-        from perfbench.harness.arith import Sizes
-        return Sizes.from_file(dict(
-            self.config, **(REHEARSAL_SIZES if rehearse else {})))
+        arch = self.arch()
+        return arch.sizes(dict(
+            self.config, **(arch.rehearsal(self.config) if rehearse else {})))
+
+    def program_config(self, sizes, **training):
+        return self.arch().program_config(self.config, sizes, **training)
+
+    def count(self, name: str):
+        """The arch's function of that name, a count of required work that
+        a metric's reader asks for; an arch without it is an error."""
+        fn = getattr(self.arch(), name, None)
+        if fn is None:
+            raise SystemExit(
+                f"perfbench: archs/{self.config['arch']}.py has no "
+                f"{name}(), which a metric of {self.name} asks of it")
+        return fn
 
     def _lists(self, metric: dict) -> bool:
         return self.name in metric.get("workloads", [self.name])

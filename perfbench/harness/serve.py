@@ -22,7 +22,6 @@ from typing import Dict, List
 import numpy as np
 
 from perfbench.harness import stats, traffic as traffic_mod
-from perfbench.harness.arith import Sizes
 
 now = time.perf_counter
 
@@ -76,16 +75,6 @@ def _engine_config(traffic: dict, rehearse: bool):
     return EngineConfig(**kw)
 
 
-def model_config(cfg: dict, s: Sizes, **training):
-    """The program's TransformerConfig for a configuration's file."""
-    from deeplearning4j_tpu.models.transformer import TransformerConfig
-    return TransformerConfig(
-        vocab_size=s.vocab_size, d_model=s.n_embd, n_heads=s.n_head,
-        n_layers=s.n_layer, max_len=s.n_positions,
-        mlp_ratio=s.n_inner // s.n_embd, eps=s.eps,
-        dtype=cfg["activation_dtype"], **training)
-
-
 def shrink_traffic(t: dict) -> dict:
     """The rehearsal's traffic: an eighth of every length."""
     t = dict(t)
@@ -130,7 +119,7 @@ class Tracer(threading.Thread):
             self.error = e
 
 
-def build(cell, s: Sizes, tr: dict, seed: int, rehearse: bool, split: dict):
+def build(cell, s, tr: dict, seed: int, rehearse: bool, split: dict):
     """The engine, warmed, its weights made on the device from the seed in
     the serving layout."""
     import jax
@@ -142,7 +131,7 @@ def build(cell, s: Sizes, tr: dict, seed: int, rehearse: bool, split: dict):
 
     ref = cell.reference()
     t = now()
-    cfg = model_config(cell.config, s)
+    cfg = cell.program_config(s)
     mesh = make_mesh(MeshSpec(), devices=jax.devices()[:cell.chips])
     shardings = jax.tree_util.tree_map(
         lambda sp: NamedSharding(mesh, sp), serving_param_specs(cfg),
@@ -329,7 +318,7 @@ def run(cell, args, ctx) -> dict:
             "traffic": tr}
 
 
-def check_served(ref, s: Sizes, sample, check: dict, seed: int,
+def check_served(ref, s, sample, check: dict, seed: int,
                  precision: str = "f32") -> dict:
     """The plain reference once over each sampled prompt with its served
     tokens: the widest gap by which a served token's logit lies below the

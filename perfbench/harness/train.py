@@ -16,8 +16,7 @@ from typing import List
 import numpy as np
 
 from perfbench.harness import traffic as traffic_mod
-from perfbench.harness.arith import Sizes
-from perfbench.harness.serve import Tracer, model_config
+from perfbench.harness.serve import Tracer
 
 now = time.perf_counter
 ADAM_B1 = 0.9
@@ -32,13 +31,13 @@ def shrink_traffic(t: dict) -> dict:
     return t
 
 
-def train_config(cfg: dict, tr: dict, s: Sizes):
-    return model_config(cfg, s, remat=bool(tr["remat"]),
-                        remat_policy=tr.get("remat_policy", "full"),
-                        xent_chunk=int(tr["xent_chunk"]))
+def train_config(cell, tr: dict, s):
+    return cell.program_config(s, remat=bool(tr["remat"]),
+                               remat_policy=tr.get("remat_policy", "full"),
+                               xent_chunk=int(tr["xent_chunk"]))
 
 
-def _build(cell, tr: dict, s: Sizes, seed: int, ref):
+def _build(cell, tr: dict, s, seed: int, ref):
     """The program's step and its state, weights made on the device from
     the seed in the step's own shardings."""
     import jax
@@ -47,7 +46,7 @@ def _build(cell, tr: dict, s: Sizes, seed: int, ref):
     from deeplearning4j_tpu.parallel.mesh import MeshSpec, make_mesh
     from deeplearning4j_tpu.parallel.optim import AdamState, init_adam_state
 
-    cfg = train_config(cell.config, tr, s)
+    cfg = train_config(cell, tr, s)
     lr = float(tr["learning_rate"])
     devices = jax.devices()[:cell.chips]
     if tr["entry"] == "megatron":
@@ -210,7 +209,7 @@ def first_steps(ref, step, params, opt, batches, init, seed: int,
             "change": change, "state": (params, opt)}
 
 
-def _ref_shardings(ref, s: Sizes, mesh):
+def _ref_shardings(ref, s, mesh):
     """Where the reference's state does not fit one chip it is spread over
     the cell's chips: each leaf split along its last axis that divides."""
     import jax
@@ -230,7 +229,7 @@ def _ref_shardings(ref, s: Sizes, mesh):
     return tree, NamedSharding(mesh, P())
 
 
-def reference_readings(ref, s: Sizes, batches, tr: dict, seed: int, mesh,
+def reference_readings(ref, s, batches, tr: dict, seed: int, mesh,
                        precision: str = "f32", halve_batch: bool = False):
     """Losses of the first three steps, leaf norms of the first gradient and
     of the parameters' change after the three, by the plain reference (or,

@@ -1,8 +1,8 @@
 """The decode-attention kernel's share of its roofline: the least time the
 chip needs to read each live K and V row once for the decode tokens that the
-traced window committed (harness/arith.py), over the kernel's device time
+traced window committed (the cell's arch), over the kernel's device time
 there. Tokens are stamped one scheduling round after their step ran."""
-from perfbench.harness import arith, stats
+from perfbench.harness import stats
 
 
 def read(run, args):
@@ -13,6 +13,6 @@ def read(run, args):
     work = stats.decode_and_prefill_work(run["log"], tracer.t_a, tracer.t_b)
     if not seconds or not work["decode_rows"]:
         return None
-    least = arith.decode_attn_roofline_s(run["sizes"], work["decode_rows"],
-                                         run["device_kind"])
+    least = run["cell"].count("decode_attn_roofline_s")(
+        run["sizes"], work["decode_rows"], run["device_kind"])
     return 100.0 * least / seconds

@@ -10,6 +10,7 @@ def read(run, args):
     if len(runs) < 2:
         return None
     period = (runs[-1][0] - runs[0][0]) / (len(runs) - 1)
-    flops = arith.train_flops_per_step(run["sizes"], run["rows"], run["seq"])
+    flops = run["cell"].count("train_flops_per_step")(
+        run["sizes"], run["rows"], run["seq"])
     peak = arith.peaks(run["device_kind"])["flops_per_s"]
     return 100.0 * flops / (period * peak * run["chips"])

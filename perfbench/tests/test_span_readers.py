@@ -12,7 +12,7 @@ import pytest
 
 import perfbench.run as runner
 from perfbench.harness import cells, xplane
-from perfbench.harness.arith import PEAKS, Sizes, forward_flops
+from perfbench.harness.arith import PEAKS
 
 from deeplearning4j_tpu.observability.tracing import Snapshot, Span
 
@@ -193,12 +193,11 @@ def test_sums_self_times_and_shares():
 
 
 def test_mfu_pairs_device_time_with_the_tokens_dispatched_there():
-    s = Sizes(n_layer=2, n_embd=128, n_head=4, n_inner=512, vocab_size=512,
-              n_positions=256)
+    cell = cells.Cell("cgpt13-flood", benchmark=ROOT / "waiting.json")
+    s, forward_flops = cell.sizes(rehearse=True), cell.count("forward_flops")
     red = reduced([], [("jit_run_paged_decode(7)", 0.0, 0.25),
                        ("jit_run_paged_chunked_prefill(8)", 0.25, 0.5)], 2.0)
-    run = serving_run(trace=red, sizes=s, device_kind=KIND,
-                      cell=types.SimpleNamespace(chips=1))
+    run = serving_run(trace=red, sizes=s, device_kind=KIND, cell=cell)
     mfu = reader("mfu_by_tick")
     peak = PEAKS[KIND]["flops_per_s"]
     # the traced window holds the second tick alone
