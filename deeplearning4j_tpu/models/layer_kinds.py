@@ -1,0 +1,306 @@
+"""Layer kinds for a `TransformerConfig` with `layer_types`: a period of
+unlike layers where the GPT-2 family has one block.
+
+A layer is ``h += mixer(norm(h)); h += moe(norm(h))`` with
+``norm(x) = x / sqrt(mean(x^2) + eps) * (1 + w)`` in float32. Mixers:
+
+- ``"full"``: gated grouped-query attention. ``Wq`` gives a head its query
+  and its output gate side by side; q and k are RMS-normed over the head,
+  rotary positions (rotate-half) turn the first `rotary_fraction` of the
+  head, a KV head serves ``n_heads / n_kv_heads`` query heads, and the
+  output is ``Wo(attn * sigmoid(gate))``.
+- ``"deltanet"``: Gated DeltaNet. ``Wqkvz`` (laid out by key head: q, k,
+  v of its value heads, z of its value heads) and ``Wba``; q, k, v through
+  a causal depthwise convolution and SiLU; the gated delta rule
+  (ops/gated_delta.py) over L2-normalised q and k;
+  ``Wo(norm(o) * gnorm * silu(z))``.
+
+The MLP of every layer is a top-k mixture of experts with a gated shared
+expert (`moe_topk`). The layer is told which experts it holds
+(`cfg.experts_held`, ids from `first`): it routes over all
+`cfg.n_experts`, normalises over all `moe_top_k` chosen and adds only what
+the held experts give, without drops: every routed row is computed
+(ops/grouped_matmul.py). On one chip that is an expert-parallel rank
+without its exchange; the absent experts' part is left out.
+
+Parameter leaves are stacked over PERIODS: ``blocks["l<i>"][name]`` is
+``[P, ...]`` for position ``i`` of the period, and the step scans periods.
+These kinds run on the training path (models/transformer.py's forward and
+parallel/megatron.py's step on data / pipe axes); serving refuses them.
+"""
+from __future__ import annotations
+
+from typing import Any, Dict
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+Array = jax.Array
+F32 = jnp.float32
+KINDS = ("deltanet", "full")
+_HI = lax.Precision.HIGHEST
+
+
+# ---------------------------------------------------------------------------
+# parameters
+# ---------------------------------------------------------------------------
+
+def layer_shapes(cfg, kind: str) -> Dict[str, tuple]:
+    """Leaf shapes of one layer of `kind`, without the period axis."""
+    d, f, fs = cfg.d_model, cfg.moe_d_ff, cfg.shared_d_ff
+    out = {"ln1": (d,), "ln2": (d,), "router": (d, cfg.n_experts),
+           "We_gu": (cfg.experts_held, d, 2 * f),
+           "We_down": (cfg.experts_held, f, d),
+           "Ws_gu": (d, 2 * fs), "Ws_down": (fs, d), "Ws_gate": (d, 1)}
+    if kind == "deltanet":
+        kd = cfg.gdn_key_heads * cfg.gdn_key_dim
+        vd = cfg.gdn_value_heads * cfg.gdn_value_dim
+        out.update(Wqkvz=(d, 2 * kd + 2 * vd),
+                   Wba=(d, 2 * cfg.gdn_value_heads),
+                   conv=(cfg.gdn_conv_width, 2 * kd + vd),
+                   A_log=(cfg.gdn_value_heads,),
+                   dt_bias=(cfg.gdn_value_heads,),
+                   gnorm=(cfg.gdn_value_dim,), Wo=(vd, d))
+    elif kind == "full":
+        h, hk, dh = cfg.n_heads, cfg.kv_heads, cfg.d_head
+        out.update(Wq=(d, 2 * h * dh), Wk=(d, hk * dh), Wv=(d, hk * dh),
+                   qnorm=(dh,), knorm=(dh,), Wo=(h * dh, d))
+    else:
+        raise ValueError(f"unknown layer kind {kind!r}: expected one of "
+                         f"{KINDS}")
+    return out
+
+
+def n_periods(cfg) -> int:
+    if cfg.n_layers % len(cfg.layer_types):
+        raise ValueError(f"n_layers {cfg.n_layers} is not whole periods of "
+                         f"layer_types {cfg.layer_types}")
+    return cfg.n_layers // len(cfg.layer_types)
+
+
+def init_params(cfg, key: Array) -> Dict[str, Any]:
+    """embed, lnfg, Wout and blocks.l<i> stacked over periods: matrices
+    N(0, 1/fan_in), norms' w nought, plain gains 1, A_log log U(1, 16),
+    dt_bias the inverse softplus of a step log-uniform in [0.001, 0.1]
+    (the family's initialiser: a position forgets 0.1% to 80% of the
+    state; with dt_bias 1 it forgets nearly all of it, the output is one
+    position's term, and its norm turns on the sign of k . q)."""
+    p = n_periods(cfg)
+    blocks = {}
+    for i, kind in enumerate(cfg.layer_types):
+        leaves = {}
+        for j, (name, shape) in enumerate(sorted(
+                layer_shapes(cfg, kind).items())):
+            k = jax.random.fold_in(jax.random.fold_in(key, i), j)
+            full = (p,) + shape
+            if name.startswith("W") or name in ("router", "conv"):
+                leaves[name] = (jax.random.normal(k, full, F32)
+                                / jnp.sqrt(F32(shape[-2])))
+            elif name == "A_log":
+                leaves[name] = jnp.log(jax.random.uniform(
+                    k, full, F32, 1.0, 16.0))
+            elif name == "dt_bias":     # softplus^-1 of log U(.001, .1)
+                dt = jnp.exp(jax.random.uniform(
+                    k, full, F32, jnp.log(0.001), jnp.log(0.1)))
+                leaves[name] = dt + jnp.log(-jnp.expm1(-dt))
+            elif name == "gnorm":
+                leaves[name] = jnp.ones(full, F32)
+            else:
+                leaves[name] = jnp.zeros(full, F32)
+        blocks[f"l{i}"] = leaves
+    ke, ko = jax.random.split(jax.random.fold_in(key, 1 << 20))
+    d, v = cfg.d_model, cfg.vocab_size
+    return {"embed": jax.random.normal(ke, (v, d), F32) * 0.02,
+            "blocks": blocks, "lnfg": jnp.zeros((d,), F32),
+            "Wout": jax.random.normal(ko, (d, v), F32) / jnp.sqrt(F32(d))}
+
+
+# ---------------------------------------------------------------------------
+# pieces
+# ---------------------------------------------------------------------------
+
+def rms_norm(x: Array, w: Array, eps: float) -> Array:
+    """x / sqrt(mean(x^2) + eps) * (1 + w), computed in float32."""
+    xf = x.astype(F32)
+    y = xf * lax.rsqrt(jnp.mean(jnp.square(xf), -1, keepdims=True) + eps)
+    return (y * (1.0 + w.astype(F32))).astype(x.dtype)
+
+
+def rotary(x: Array, theta: float, rot: int) -> Array:
+    """Rotate-half positions 0 .. T-1 on the first `rot` of a head's
+    dimensions, in float32; x [B, T, H, dh]."""
+    t = x.shape[1]
+    inv = 1.0 / (theta ** (jnp.arange(0, rot, 2, dtype=F32) / rot))
+    ang = jnp.arange(t, dtype=F32)[:, None] * inv[None, :]
+    cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)[None, :, None, :]
+    sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)[None, :, None, :]
+    xr = x[..., :rot].astype(F32)
+    half = jnp.concatenate([-xr[..., rot // 2:], xr[..., :rot // 2]], -1)
+    return jnp.concatenate([(xr * cos + half * sin).astype(x.dtype),
+                            x[..., rot:]], -1)
+
+
+def _mm(x: Array, w: Array) -> Array:
+    return jnp.matmul(x, w.astype(x.dtype))
+
+
+def gated_attention(x: Array, p: Dict[str, Array], cfg) -> Array:
+    from deeplearning4j_tpu.nn.layers.attention import dot_product_attention
+    b, t, _ = x.shape
+    h, hk, dh = cfg.n_heads, cfg.kv_heads, cfg.d_head
+    qg = _mm(x, p["Wq"]).reshape(b, t, h, 2 * dh)
+    q, gate = qg[..., :dh], qg[..., dh:]
+    k = _mm(x, p["Wk"]).reshape(b, t, hk, dh)
+    v = _mm(x, p["Wv"]).reshape(b, t, hk, dh)
+    rot = int(dh * cfg.rotary_fraction)
+    q = rms_norm(q, p["qnorm"], cfg.eps)
+    k = rms_norm(k, p["knorm"], cfg.eps)
+    if rot:
+        q, k = (rotary(q, cfg.rope_theta, rot),
+                rotary(k, cfg.rope_theta, rot))
+    a = dot_product_attention(q, k, v, causal=True)         # [B, T, H, dh]
+    a = a * jax.nn.sigmoid(gate.astype(F32)).astype(a.dtype)
+    return _mm(a.reshape(b, t, h * dh), p["Wo"])
+
+
+def causal_conv(x: Array, w: Array) -> Array:
+    """Depthwise causal convolution, no bias: x [B, T, C], w [W, C]. The
+    taps are summed in float32 (a tap's gradient is a sum over every
+    position of the batch), the result is in x's dtype."""
+    width, t = w.shape[0], x.shape[1]
+    xp = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0))).astype(F32)
+    return sum(xp[:, i:i + t] * w[i] for i in range(width)).astype(x.dtype)
+
+
+def _l2(x: Array) -> Array:
+    xf = x.astype(F32)
+    return xf * lax.rsqrt(jnp.sum(jnp.square(xf), -1, keepdims=True) + 1e-6)
+
+
+def gated_deltanet(x: Array, p: Dict[str, Array], cfg) -> Array:
+    from deeplearning4j_tpu.ops.gated_delta import gated_delta_rule
+    b, t, _ = x.shape
+    hk, hv = cfg.gdn_key_heads, cfg.gdn_value_heads
+    dk, dv = cfg.gdn_key_dim, cfg.gdn_value_dim
+    r = hv // hk
+    qkvz = _mm(x, p["Wqkvz"]).reshape(b, t, hk, 2 * dk + 2 * r * dv)
+    # the gates' two thin columns in float32: they feed exp and sigmoid
+    ba = jnp.matmul(x.astype(F32), p["Wba"],
+                    precision=_HI).reshape(b, t, hk, 2 * r)
+    z = qkvz[..., 2 * dk + r * dv:].reshape(b, t, hv, dv)
+    mixed = jnp.concatenate(
+        [qkvz[..., :dk].reshape(b, t, hk * dk),
+         qkvz[..., dk:2 * dk].reshape(b, t, hk * dk),
+         qkvz[..., 2 * dk:2 * dk + r * dv].reshape(b, t, hv * dv)], -1)
+    mixed = jax.nn.silu(causal_conv(mixed, p["conv"]))
+    q = mixed[..., :hk * dk].reshape(b, t, hk, dk)
+    k = mixed[..., hk * dk:2 * hk * dk].reshape(b, t, hk, dk)
+    v = mixed[..., 2 * hk * dk:].reshape(b, t, hv, dv)
+    beta = jax.nn.sigmoid(ba[..., :r].reshape(b, t, hv))
+    g = -jnp.exp(p["A_log"]) * jax.nn.softplus(
+        ba[..., r:].reshape(b, t, hv) + p["dt_bias"])
+    q = (_l2(q) * dk ** -0.5).astype(x.dtype)
+    k = _l2(k).astype(x.dtype)
+    o = gated_delta_rule(q, k, v, g, beta)
+    of = o.astype(F32)
+    of = of * lax.rsqrt(jnp.mean(jnp.square(of), -1, keepdims=True)
+                        + cfg.eps) * p["gnorm"]
+    o = (of * jax.nn.silu(z.astype(F32))).astype(x.dtype)
+    return _mm(o.reshape(b, t, hv * dv), p["Wo"])
+
+
+def _swiglu(x: Array, w_gu: Array, w_down: Array) -> Array:
+    gu = _mm(x, w_gu)
+    f = gu.shape[-1] // 2
+    return _mm(jax.nn.silu(gu[..., :f]) * gu[..., f:], w_down)
+
+
+def route(x: Array, router: Array, top_k: int):
+    """Softmax over all experts in float32, the top_k, their weights
+    divided by their sum: (experts [N, k] int32, weights [N, k] f32)."""
+    prob = jax.nn.softmax(jnp.matmul(x.astype(F32), router, precision=_HI),
+                          axis=-1)
+    w, idx = lax.top_k(prob, top_k)
+    return idx.astype(jnp.int32), w / jnp.sum(w, -1, keepdims=True)
+
+
+def moe_topk(x: Array, p: Dict[str, Array], cfg, first: int = 0) -> Array:
+    """x [B, T, D]: the held experts' part of the routed sum, dropless,
+    plus the gated shared expert."""
+    from deeplearning4j_tpu.observability.metrics import default_registry
+    from deeplearning4j_tpu.observability.tracing import mark
+    from deeplearning4j_tpu.ops import grouped_matmul as gm
+
+    b, t, d = x.shape
+    n, k, held = b * t, cfg.moe_top_k, cfg.experts_held
+    xf = x.reshape(n, d)
+    mark("moe.share", held=held, of=cfg.n_experts, top_k=k, tokens=n,
+         buffer_rows=gm.buffer_rows(n * k, held))
+    default_registry().counter(
+        "moe_calls", "top-k mixture-of-experts layers traced").inc()
+    with jax.named_scope("moe.route"):
+        router = p["router"]
+        if not cfg.train_router:        # frozen: the leaf's gradient only
+            router = lax.stop_gradient(router)
+        idx, w = route(xf, router, k)
+        plan = gm.plan_groups(idx, first, held)
+    with jax.named_scope("moe.dispatch"):
+        buf = gm.dispatch(xf, plan)
+        w_row = jnp.where(plan.valid, w.reshape(-1)[plan.pair_of], 0.0)
+    with jax.named_scope("moe.experts"):
+        gu = gm.grouped_matmul(buf, p["We_gu"], plan.tile_expert,
+                               plan.n_live)
+        f = gu.shape[-1] // 2
+        mid = jax.nn.silu(gu[:, :f]) * gu[:, f:]
+        out = gm.grouped_matmul(mid, p["We_down"], plan.tile_expert,
+                                plan.n_live)
+    with jax.named_scope("moe.combine"):
+        # rows of tiles that hold nothing were never written: mask before
+        # anything is multiplied into them
+        out = jnp.where(plan.valid[:, None], out, 0)
+        y = gm.combine(out * w_row[:, None].astype(out.dtype), plan)
+    with jax.named_scope("moe.shared"):
+        gate = jax.nn.sigmoid(jnp.matmul(xf.astype(F32), p["Ws_gate"],
+                                         precision=_HI))
+        y = y + gate.astype(x.dtype) * _swiglu(xf, p["Ws_gu"], p["Ws_down"])
+    return y.reshape(b, t, d)
+
+
+MIXERS = {"deltanet": ("deltanet", gated_deltanet),
+          "full": ("attn", gated_attention)}
+
+
+def layer_forward(h: Array, p: Dict[str, Array], cfg, kind: str) -> Array:
+    scope, mixer = MIXERS[kind]
+    with jax.named_scope(scope):
+        h = h + mixer(rms_norm(h, p["ln1"], cfg.eps), p, cfg)
+    with jax.named_scope("mlp"):
+        return h + moe_topk(rms_norm(h, p["ln2"], cfg.eps), p, cfg)
+
+
+def periods_forward(h: Array, blocks: Dict[str, Dict[str, Array]],
+                    cfg) -> Array:
+    """Every period held, scanned: `blocks["l<i>"][name]` is `[P, ...]`.
+    The single-device forward and the parallel step's stage both are
+    this."""
+    def period(h, p):
+        return period_forward(h, p, cfg), None
+    return lax.scan(period, h, blocks)[0]
+
+
+def period_forward(h: Array, blocks: Dict[str, Dict[str, Array]],
+                   cfg) -> Array:
+    """One period's layers in turn; `blocks["l<i>"]` without the period
+    axis. With `cfg.remat` each layer keeps only its input."""
+    for i, kind in enumerate(cfg.layer_types):
+        fn = lambda h_, p_, kind=kind: layer_forward(  # noqa: E731
+            h_, p_, cfg, kind)
+        if cfg.remat:
+            # prevent_cse stays on: a period's layers share one scan body
+            # (one iteration where one period is held), and without the
+            # barrier XLA merges a layer's recomputation with its forward
+            # and keeps every layer's activations
+            fn = jax.checkpoint(fn)
+        h = fn(h, blocks[f"l{i}"])
+    return h

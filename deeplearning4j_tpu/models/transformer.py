@@ -92,10 +92,41 @@ class TransformerConfig:
     # + per-row scales. Cache writes cast on store; attention reads
     # promote back through the usual matmul dtype rules.
     cache_dtype: Optional[str] = None
+    # --- a period of unlike layers (models/layer_kinds.py) -------------
+    # the kinds of one period's layers, in order ("deltanet", "full");
+    # () is the one GPT-2 block above. With layer_types the model has
+    # RMSNorm (1 + w), no learned positions, and a top-k mixture of
+    # experts with a gated shared expert in every layer. Training path
+    # only: the serving engine refuses a config that sets them.
+    layer_types: Tuple[str, ...] = ()
+    n_kv_heads: int = 0             # 0: as many as n_heads
+    head_dim: int = 0               # 0: d_model // n_heads
+    rotary_fraction: float = 0.0    # part of a head that rotary turns
+    rope_theta: float = 10000.0
+    gdn_key_heads: int = 0          # Gated DeltaNet: key / value heads,
+    gdn_value_heads: int = 0        # their sizes and the causal
+    gdn_key_dim: int = 0            # convolution's width
+    gdn_value_dim: int = 0
+    gdn_conv_width: int = 4
+    # top-k MoE of the typed layers: n_experts is the router's width,
+    # experts_held how many of them ([0, experts_held)) live here
+    experts_held: int = 0
+    moe_top_k: int = 1
+    moe_d_ff: int = 0
+    shared_d_ff: int = 0
+    # False: the router's matrix is frozen (it gets no gradient, so Adam
+    # leaves it where it is), as a fine-tune that keeps the routing does.
+    # The hidden state still gets its gradient through the routing
+    # weights: the mathematics of every other leaf is whole
+    train_router: bool = True
 
     @property
     def d_head(self) -> int:
-        return self.d_model // self.n_heads
+        return self.head_dim or self.d_model // self.n_heads
+
+    @property
+    def kv_heads(self) -> int:
+        return self.n_kv_heads or self.n_heads
 
     @property
     def d_ff(self) -> int:
@@ -120,6 +151,9 @@ def _winit(key, shape, fan_in):
 
 
 def init_params(cfg: TransformerConfig, key: Array) -> Dict[str, Any]:
+    if cfg.layer_types:
+        from deeplearning4j_tpu.models import layer_kinds
+        return layer_kinds.init_params(cfg, key)
     d, f, L, v = cfg.d_model, cfg.d_ff, cfg.n_layers, cfg.vocab_size
     ks = jax.random.split(key, 12)
 
@@ -241,6 +275,8 @@ def forward_hidden(cfg: TransformerConfig, params: Dict[str, Any],
     vocab-panel scan)."""
     dt = cfg.activation_dtype()
     t = tokens.shape[1]
+    if cfg.layer_types:
+        return _forward_hidden_typed(cfg, params, tokens)
     with jax.named_scope("embed"):
         h = (params["embed"].astype(dt)[tokens]
              + params["pos"].astype(dt)[:t][None])
@@ -261,6 +297,21 @@ def forward_hidden(cfg: TransformerConfig, params: Dict[str, Any],
         body = jax.checkpoint(body, prevent_cse=False, policy=pol)
     h, _ = lax.scan(body, h, params["blocks"])
     return layer_norm(h, params["lnfg"], params["lnfb"], cfg.eps)
+
+
+def _forward_hidden_typed(cfg: TransformerConfig, params: Dict[str, Any],
+                          tokens: Array) -> Array:
+    """forward_hidden for a config with `layer_types`: no positions are
+    added (the full layers rotate, the DeltaNet layers recur), the scan is
+    over periods, remat keeps a layer's input (the one policy)."""
+    from deeplearning4j_tpu.models import layer_kinds
+    if cfg.remat and cfg.remat_policy != "full":
+        raise ValueError(f"remat_policy {cfg.remat_policy!r} with "
+                         "layer_types: only 'full' is there")
+    with jax.named_scope("embed"):
+        h = params["embed"].astype(cfg.activation_dtype())[tokens]
+    h = layer_kinds.periods_forward(h, params["blocks"], cfg)
+    return layer_kinds.rms_norm(h, params["lnfg"], cfg.eps)
 
 
 def forward(cfg: TransformerConfig, params: Dict[str, Any],

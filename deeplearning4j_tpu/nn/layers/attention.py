@@ -40,7 +40,9 @@ def dot_product_attention(q: Array, k: Array, v: Array, *,
                           scale: Optional[float] = None) -> Array:
     """Scaled dot-product attention.
 
-    q: [B, T, H, Dh]; k, v: [B, S, H, Dh] -> [B, T, H, Dh].
+    q: [B, T, H, Dh]; k, v: [B, S, H, Dh] -> [B, T, H, Dh]. k and v may
+    hold fewer heads (grouped-query attention): KV head j serves query
+    heads [j * H / Hkv, (j + 1) * H / Hkv).
     ``mask``: optional [B, S] {0,1} key-validity mask.
     ``q_offset``/``kv_offset``: global positions of q[0] / k[0] — causal
     masking compares global positions, enabling blockwise/ring callers.
@@ -66,6 +68,9 @@ def dot_product_attention(q: Array, k: Array, v: Array, *,
     # [B, H, T, S] — accumulate in >=f32 (f64 inputs keep f64: the
     # gradient-check suites run the whole net in float64)
     acc = jnp.promote_types(q.dtype, jnp.float32)
+    if k.shape[2] != q.shape[2]:
+        k = jnp.repeat(k, q.shape[2] // k.shape[2], axis=2)
+        v = jnp.repeat(v, q.shape[2] // v.shape[2], axis=2)
     scores = jnp.einsum("bthd,bshd->bhts", q, k,
                         preferred_element_type=acc) * scale
     if causal:

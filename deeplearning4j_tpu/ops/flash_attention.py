@@ -468,12 +468,13 @@ def _log_caps_once():
         _MAX_2D_GRID_FWD, _MAX_2D_GRID_BWD)
 
 
-def _bh_chunks(bh: int, nsb: int, cap: int):
+def _bh_chunks(bh: int, nsb: int, cap: int, group: int = 1):
     """Slice extents over the batch-head axis keeping the grid's
-    chunk x nsb within ``cap`` programs."""
+    chunk x nsb within ``cap`` programs; whole groups of ``group``
+    query heads (those that share a K/V head) stay in one chunk."""
     if nsb <= 1:
         return [(0, bh)]
-    step = max(1, cap // nsb)
+    step = max(group, cap // nsb // group * group)
     return [(lo, min(step, bh - lo)) for lo in range(0, bh, step)]
 
 
@@ -506,24 +507,28 @@ def _k_superblock(sk: int, bk: int) -> int:
 
 
 def _flash_forward(q3, k3, v3, dh: int, scale: float, causal: bool,
-                   q_offset: int, kv_offset: int, interpret: bool):
+                   q_offset: int, kv_offset: int, interpret: bool,
+                   group: int = 1):
     """(out [N, T, L], stats [N, L // W, rows, T]) of operands
     [N, T, L] holding L // dh heads of dh lanes side by side: the
     block's own layout (N = B, L = H*Dh) or one head an entry
     (N = B*H, L = Dh). rows: a log-sum-exp a head of a block, or m and
-    log(l) apart (`_one_lse`)."""
+    log(l) apart (`_one_lse`). With ``group`` > 1 (grouped-query
+    attention, one head an entry) k3 and v3 hold N // group entries and
+    entry n of q3 reads entry n // group of them: the K/V block is
+    shared by its group through the block index, never repeated."""
     tq = q3.shape[1]
     if tq > _FWD_Q_CHUNK:
         chunk = _chunk_of(tq, _FWD_Q_CHUNK)
         if chunk and chunk < tq:
             outs = [_flash_forward_impl(
                 q3[:, lo:lo + chunk], k3, v3, dh, scale, causal,
-                q_offset + lo, kv_offset, interpret)
+                q_offset + lo, kv_offset, interpret, group)
                 for lo in range(0, tq, chunk)]
             return (jnp.concatenate([o for o, _ in outs], axis=1),
                     jnp.concatenate([st for _, st in outs], axis=-1))
     return _flash_forward_impl(q3, k3, v3, dh, scale, causal, q_offset,
-                               kv_offset, interpret)
+                               kv_offset, interpret, group)
 
 
 def _block_width(lanes: int, dh: int) -> int:
@@ -532,8 +537,21 @@ def _block_width(lanes: int, dh: int) -> int:
     return dh if lanes == dh else max(128, dh)
 
 
+def _vmem_params(nbytes: int):
+    """Compiler parameters for a call whose resident blocks (double
+    buffered) pass the 16 MB default of scoped VMEM: a K/V pair of
+    8192 rows at head_dim 256 is 8 MB before its second buffer. Shapes
+    under the default get none, and compile as they always have."""
+    if 2 * nbytes <= 12 * 2 ** 20:
+        return None
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=min(100 * 2 ** 20, 2 * nbytes + 24 * 2 ** 20))
+
+
 def _flash_forward_impl(q3, k3, v3, dh: int, scale: float, causal: bool,
-                        q_offset: int, kv_offset: int, interpret: bool):
+                        q_offset: int, kv_offset: int, interpret: bool,
+                        group: int = 1):
     import jax.experimental.pallas as pl
 
     from deeplearning4j_tpu.ops.pallas_util import (interpret_arg,
@@ -552,9 +570,10 @@ def _flash_forward_impl(q3, k3, v3, dh: int, scale: float, causal: bool,
         _flash_fwd_kernel, scale=scale, causal=causal,
         qo=int(q_offset), ko=int(kv_offset), bq=bq, bk=bk, dh=dh)
     qspec = pl.BlockSpec((1, qsb, w), lambda b, g, i: (b, i, g))
-    kvspec = pl.BlockSpec((1, sk, w), lambda b, g, i: (b, 0, g))
+    kvspec = pl.BlockSpec((1, sk, w), lambda b, g, i: (b // group, 0, g))
     stat_spec = pl.BlockSpec((1, 1, nrows, qsb),
                              lambda b, g, i: (b, g, 0, i))
+    resident = (2 * sk + 2 * qsb) * w * q3.dtype.itemsize
 
     def call(qc, kc, vc):
         c = qc.shape[0]
@@ -567,22 +586,27 @@ def _flash_forward_impl(q3, k3, v3, dh: int, scale: float, causal: bool,
             in_specs=[qspec, kvspec, kvspec],
             out_specs=[qspec, stat_spec],
             interpret=interpret_arg(interpret, qc, kc, vc),
+            compiler_params=_vmem_params(resident),
             name="flash_fwd",
         )(qc, kc, vc)
 
-    chunks = _bh_chunks(n, tq // qsb, _MAX_2D_GRID_FWD)
+    chunks = _bh_chunks(n, tq // qsb, _MAX_2D_GRID_FWD, group)
     if len(chunks) == 1:
         return call(q3, k3, v3)
-    outs = [call(q3[lo:lo + c], k3[lo:lo + c], v3[lo:lo + c])
+    outs = [call(q3[lo:lo + c], k3[lo // group:(lo + c) // group],
+                 v3[lo // group:(lo + c) // group])
             for lo, c in chunks]
     return tuple(jnp.concatenate([o[i] for o in outs], axis=0)
                  for i in range(2))
 
 
 def _flash_backward(q3, k3, v3, o3, stats, g, dh, scale, causal,
-                    q_offset, kv_offset, interpret):
+                    q_offset, kv_offset, interpret, group: int = 1):
     """Pallas backward: ONE program per (batch row, lane group)
-    producing dQ, dK and dV together (shared probability panels)."""
+    producing dQ, dK and dV together (shared probability panels).
+    With ``group`` > 1 a program reads its group's shared K/V entry and
+    writes its own query head's dK and dV, which are then summed over
+    the group in float32."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -602,8 +626,10 @@ def _flash_backward(q3, k3, v3, o3, stats, g, dh, scale, causal,
                    ko=int(kv_offset), bq=bq, bk=bk, dh=dh)
     full = pl.BlockSpec((1, tq, w), lambda b, g, j: (b, 0, g))
     kspec = pl.BlockSpec((1, ksb, w), lambda b, g, j: (b, j, g))
+    kin = pl.BlockSpec((1, ksb, w), lambda b, g, j: (b // group, j, g))
     stat_spec = pl.BlockSpec((1, 1, stats.shape[2], tq),
                              lambda b, g, j: (b, g, 0, 0))
+    resident = ((3 * tq + 4 * ksb) * q3.dtype.itemsize + 2 * tq) * w
 
     def call(args):
         c = args[0].shape[0]
@@ -613,34 +639,45 @@ def _flash_backward(q3, k3, v3, o3, stats, g, dh, scale, causal,
                        out_struct((c, sk, lanes), k3.dtype, *args),
                        out_struct((c, sk, lanes), v3.dtype, *args)],
             grid=(c, groups, sk // ksb),
-            in_specs=[full, kspec, kspec, full, full, stat_spec],
+            in_specs=[full, kin, kin, full, full, stat_spec],
             out_specs=[full, kspec, kspec],
             scratch_shapes=[pltpu.VMEM((tq, w), jnp.float32),
                             pltpu.VMEM((w // dh, tq), jnp.float32)],
             interpret=interpret_arg(interpret, *args),
+            compiler_params=_vmem_params(resident),
             name="flash_bwd",
         )(*args)
 
+    def over_group(x):          # a K/V head's query heads add up
+        if group == 1:
+            return x
+        return jnp.sum(x.astype(jnp.float32).reshape(
+            (n // group, group) + x.shape[1:]), axis=1).astype(x.dtype)
+
     operands = (q3, k3, v3, o3, g, stats)
-    chunks = _bh_chunks(n, sk // ksb, _MAX_2D_GRID_BWD)
+    chunks = _bh_chunks(n, sk // ksb, _MAX_2D_GRID_BWD, group)
     if len(chunks) == 1:
-        return call(operands)
-    outs = [call(tuple(a[lo:lo + c] for a in operands))
-            for lo, c in chunks]
-    return tuple(jnp.concatenate([o[i] for o in outs], axis=0)
-                 for i in range(3))
+        dq, dk, dv = call(operands)
+        return dq, over_group(dk), over_group(dv)
+    outs = [call(tuple(
+        a[lo // group:(lo + c) // group] if a is k3 or a is v3
+        else a[lo:lo + c] for a in operands)) for lo, c in chunks]
+    dq, dk, dv = (jnp.concatenate([o[i] for o in outs], axis=0)
+                  for i in range(3))
+    return dq, over_group(dk), over_group(dv)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def _flash_attention3(q3, k3, v3, dh, scale, causal, q_offset, kv_offset,
-                      interpret):
+                      interpret, group=1):
     return _flash_forward(q3, k3, v3, dh, scale, causal, q_offset,
-                          kv_offset, interpret)[0]
+                          kv_offset, interpret, group)[0]
 
 
-def _fwd(q3, k3, v3, dh, scale, causal, q_offset, kv_offset, interpret):
+def _fwd(q3, k3, v3, dh, scale, causal, q_offset, kv_offset, interpret,
+         group=1):
     out, stats = _flash_forward(q3, k3, v3, dh, scale, causal, q_offset,
-                                kv_offset, interpret)
+                                kv_offset, interpret, group)
     return out, (q3, k3, v3, out, stats)
 
 
@@ -674,7 +711,7 @@ def _chunk_of(n: int, cap: int) -> int:
     return 0
 
 
-def _bwd(dh, scale, causal, q_offset, kv_offset, interpret, res, g):
+def _bwd(dh, scale, causal, q_offset, kv_offset, interpret, group, res, g):
     """Long-sequence backward = 2-D host tiling over the fused kernel
     (r5). Sequences past ~4k failed to compile even with q chunked —
     and two (3072, 3072) kernel calls that each compile ALONE failed
@@ -712,7 +749,7 @@ def _bwd(dh, scale, causal, q_offset, kv_offset, interpret, res, g):
                         q3[:, qsl], k3[:, ksl], v3[:, ksl], o3[:, qsl],
                         stats[..., qsl], g[:, qsl], dh, scale,
                         causal, q_offset + qlo, kv_offset + klo,
-                        interpret)
+                        interpret, group)
                     dq = (dq_c.astype(jnp.float32) if dq is None
                           else dq + dq_c.astype(jnp.float32))
                     dk32 = dk_c.astype(jnp.float32)
@@ -730,11 +767,11 @@ def _bwd(dh, scale, causal, q_offset, kv_offset, interpret, res, g):
                         [zk if d is None else d for d in dvs],
                         axis=1).astype(v3.dtype))
     return _bwd_qchunks(dh, scale, causal, q_offset, kv_offset, interpret,
-                        res, g)
+                        group, res, g)
 
 
-def _bwd_qchunks(dh, scale, causal, q_offset, kv_offset, interpret, res,
-                 g):
+def _bwd_qchunks(dh, scale, causal, q_offset, kv_offset, interpret, group,
+                 res, g):
     q3, k3, v3, o3, stats = res
     sk = k3.shape[1]
     tq = q3.shape[1]
@@ -758,7 +795,7 @@ def _bwd_qchunks(dh, scale, causal, q_offset, kv_offset, interpret, res,
                 dq_c, dk_c, dv_c = _flash_backward(
                     q3[:, sl], k3, v3, o3[:, sl], stats[..., sl],
                     g[:, sl], dh, scale, causal, q_offset + lo,
-                    kv_offset, interpret)
+                    kv_offset, interpret, group)
                 dqs.append(dq_c)
                 dk = dk_c.astype(jnp.float32) if dk is None \
                     else dk + dk_c.astype(jnp.float32)
@@ -767,12 +804,14 @@ def _bwd_qchunks(dh, scale, causal, q_offset, kv_offset, interpret, res,
             return (jnp.concatenate(dqs, axis=1),
                     dk.astype(k3.dtype), dv.astype(v3.dtype))
         return _flash_backward(q3, k3, v3, o3, stats, g, dh, scale,
-                               causal, q_offset, kv_offset, interpret)
+                               causal, q_offset, kv_offset, interpret,
+                               group)
     # kv length doesn't tile: jnp-recompute fallback (a head an entry:
     # `_lane_dense_width` keeps such lengths in the per-head form)
     _, vjp = jax.vjp(
-        lambda q, k, v: _reference_attention(q, k, v, scale, causal,
-                                             q_offset, kv_offset),
+        lambda q, k, v: _reference_attention(
+            q, jnp.repeat(k, group, axis=0), jnp.repeat(v, group, axis=0),
+            scale, causal, q_offset, kv_offset),
         q3, k3, v3)
     return vjp(g)
 
@@ -834,7 +873,10 @@ def flash_attention(q: Array, k: Array, v: Array, *, causal: bool = False,
                     q_offset=0, kv_offset=0,
                     scale: Optional[float] = None) -> Array:
     """[B, T, H, D] attention via the Pallas kernel. Same contract as
-    attention.dot_product_attention (which dispatches here)."""
+    attention.dot_product_attention (which dispatches here). k and v
+    may hold fewer heads than q (grouped-query attention): KV head j
+    serves query heads [j * H / Hkv, (j + 1) * H / Hkv), one head an
+    entry, the K/V block shared through its block index."""
     from deeplearning4j_tpu.observability.metrics import default_registry
     from deeplearning4j_tpu.observability.tracing import mark
 
@@ -842,7 +884,12 @@ def flash_attention(q: Array, k: Array, v: Array, *, causal: bool = False,
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     interpret = os.environ.get("DL4JTPU_FLASH") == "interpret"
-    w = _lane_dense_width(h, d, tq, k.shape[1])
+    hkv = k.shape[2]
+    if h % hkv:
+        raise ValueError(f"query heads {h} not a multiple of KV heads "
+                         f"{hkv}")
+    group = h // hkv
+    w = _lane_dense_width(h, d, tq, k.shape[1]) if group == 1 else 0
     layout = "lane_dense" if w else "per_head"
     # trace time: once a compiled program, not once a step
     mark("flash_attention.layout", layout=layout,
@@ -859,11 +906,12 @@ def flash_attention(q: Array, k: Array, v: Array, *, causal: bool = False,
     else:       # [B, T, H, D] -> [B*H, T, D]: a transpose
         def to3(x):
             return jnp.transpose(x, (0, 2, 1, 3)).reshape(
-                b * h, x.shape[1], d)
+                b * x.shape[2], x.shape[1], d)
 
     def call(q3, k3, v3):
         return _flash_attention3(q3, k3, v3, d, float(scale), bool(causal),
-                                 int(q_offset), int(kv_offset), interpret)
+                                 int(q_offset), int(kv_offset), interpret,
+                                 group)
 
     # Traced under a jit that spans several devices (GSPMD: the FSDP
     # step says so by naming its mesh), a Mosaic call cannot be
@@ -876,7 +924,7 @@ def flash_attention(q: Array, k: Array, v: Array, *, causal: bool = False,
     mesh = jax.sharding.get_abstract_mesh()
     if (not mesh.empty and not mesh.manual_axes
             and mesh.shape.get("data", 1) > 1
-            and (b if w else b * h) % mesh.shape["data"] == 0):
+            and (b if w else b * hkv) % mesh.shape["data"] == 0):
         from jax.sharding import PartitionSpec as P
         call = jax.shard_map(call, mesh=mesh, in_specs=P("data"),
                              out_specs=P("data"), check_vma=False)

@@ -16,6 +16,14 @@ def out_struct(shape, dtype, *operands) -> jax.ShapeDtypeStruct:
     return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
+def off_chip() -> bool:
+    """True where the backend is not a TPU: kernels that have no jnp twin
+    (ops/gated_delta.py, ops/grouped_matmul.py) then run through the
+    Pallas interpreter, which is how the CPU tests and rehearsals reach
+    them."""
+    return jax.default_backend() != "tpu"
+
+
 def interpret_arg(interpret: bool, *operands):
     """`pallas_call(interpret=...)` value for the CPU test mode
     (DL4JTPU_FLASH / DL4JTPU_FUSED_LSTM = interpret).

@@ -97,6 +97,16 @@ def _g_sync(axis_name: str):
 
 def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
     """PartitionSpec pytree matching models/transformer.init_params."""
+    if cfg.layer_types:
+        # a period of unlike layers: every leaf stacked over periods, the
+        # axis 'pipe' shards; nothing else of them is divided (the held
+        # experts are this rank's share already)
+        from deeplearning4j_tpu.models import layer_kinds
+        return {"embed": P(), "lnfg": P(), "Wout": P(), "blocks": {
+            f"l{i}": {name: P("pipe", *([None] * len(shape)))
+                      for name, shape in
+                      layer_kinds.layer_shapes(cfg, kind).items()}
+            for i, kind in enumerate(cfg.layer_types)}}
     blocks: Dict[str, P] = {
         "Wq": P("pipe", None, "model"), "Wk": P("pipe", None, "model"),
         "Wv": P("pipe", None, "model"), "Wo": P("pipe", "model", None),
@@ -216,6 +226,10 @@ def _moe_sharded(x: Array, p: Dict[str, Array], cfg: TransformerConfig,
 # ---------------------------------------------------------------------------
 
 def _stage_fn(x: Array, blocks_local, cfg, mesh) -> Array:
+    if cfg.layer_types:
+        from deeplearning4j_tpu.models import layer_kinds
+        return layer_kinds.periods_forward(x, blocks_local, cfg)
+
     def body(h, p):
         return _block_fwd_sharded(h, p, cfg, mesh), None
 
@@ -451,12 +465,27 @@ def make_parallel_train_step(cfg: TransformerConfig, mesh: Mesh, *,
     if mesh.shape.get("expert", 1) != 1:
         raise ValueError("expert parallelism rides the 'data' axis; use "
                          "expert=1 in the mesh (Switch-style EP)")
-    if cfg.n_layers % s:
-        raise ValueError("n_layers must divide by pipe size")
-    if cfg.n_heads % tp or cfg.d_ff % tp:
-        raise ValueError("n_heads and d_ff must divide by model size")
-    if cfg.n_experts and cfg.n_experts % dp:
-        raise ValueError("n_experts must divide by data size")
+    if cfg.layer_types:
+        from deeplearning4j_tpu.models import layer_kinds
+        if tp > 1 or sp > 1:
+            raise ValueError(
+                "TransformerConfig.layer_types: the typed layers (Gated "
+                "DeltaNet, gated grouped-query attention, top-k MoE) are "
+                "not divided over the 'model' or 'seq' axes; use data "
+                "and pipe")
+        if pipeline_schedule == "1f1b" and s > 1:
+            raise ValueError("TransformerConfig.layer_types: the 1f1b "
+                             "schedule is not there for typed layers")
+        if layer_kinds.n_periods(cfg) % s:
+            raise ValueError("whole periods of layer_types must divide "
+                             "by pipe size")
+    else:
+        if cfg.n_layers % s:
+            raise ValueError("n_layers must divide by pipe size")
+        if cfg.n_heads % tp or cfg.d_ff % tp:
+            raise ValueError("n_heads and d_ff must divide by model size")
+        if cfg.n_experts and cfg.n_experts % dp:
+            raise ValueError("n_experts must divide by data size")
     if cfg.seq_impl not in ("ring", "ulysses"):
         raise ValueError(f"unknown seq_impl {cfg.seq_impl!r}: expected "
                          "'ring' or 'ulysses'")
@@ -480,11 +509,12 @@ def make_parallel_train_step(cfg: TransformerConfig, mesh: Mesh, *,
         b_loc, tl = tokens_loc.shape
         seq_idx = lax.axis_index("seq").astype(jnp.int32)
         with jax.named_scope("embed"):
-            pos = lax.dynamic_slice(params["pos"],
-                                    (seq_idx * tl, jnp.int32(0)),
-                                    (tl, cfg.d_model))
-            h = (params["embed"].astype(dt)[tokens_loc]
-                 + pos.astype(dt)[None])
+            h = params["embed"].astype(dt)[tokens_loc]
+            if not cfg.layer_types:     # typed layers add no positions
+                pos = lax.dynamic_slice(params["pos"],
+                                        (seq_idx * tl, jnp.int32(0)),
+                                        (tl, cfg.d_model))
+                h = h + pos.astype(dt)[None]
         # microbatch split for the pipeline
         if b_loc % m_:
             raise ValueError(f"local batch {b_loc} not divisible by "
@@ -494,7 +524,11 @@ def make_parallel_train_step(cfg: TransformerConfig, mesh: Mesh, *,
         out = _pipeline_apply(params["blocks"], h_mb, cfg, mesh)
         hf = out.reshape(b_loc, tl, cfg.d_model)
         with jax.named_scope("head_loss"):
-            hf = layer_norm(hf, params["lnfg"], params["lnfb"], cfg.eps)
+            if cfg.layer_types:
+                from deeplearning4j_tpu.models.layer_kinds import rms_norm
+                hf = rms_norm(hf, params["lnfg"], cfg.eps)
+            else:
+                hf = layer_norm(hf, params["lnfg"], params["lnfb"], cfg.eps)
             if cfg.xent_chunk > 0 and cfg.vocab_size > cfg.xent_chunk:
                 # streaming vocab-panel loss on the LOCAL tokens (Wout
                 # is replicated; each shard scans its own panels) — the

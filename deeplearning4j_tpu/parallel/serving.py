@@ -410,10 +410,29 @@ def _resolve_quant(quantized, kv_mode):
     return resolve_mode(quantized), resolve_mode(kv_mode)
 
 
+def _refuse_unserved(cfg: TransformerConfig) -> None:
+    """The serving programs hold one kind of state, a K/V cache of
+    `n_heads` heads a layer. A config with layer kinds (recurrent state
+    beside the cache) or fewer KV heads than query heads trains
+    (models/layer_kinds.py) and is not served: refused here, where every
+    `make_*` and `_check_spec` pass, by the field's name."""
+    if cfg.layer_types:
+        raise ValueError(
+            f"TransformerConfig.layer_types={cfg.layer_types}: the serving "
+            "engine has no recurrent state beside its paged K/V cache and "
+            "cannot serve typed layers; they run on the training path only")
+    if cfg.n_kv_heads and cfg.n_kv_heads != cfg.n_heads:
+        raise ValueError(
+            f"TransformerConfig.n_kv_heads={cfg.n_kv_heads} with n_heads="
+            f"{cfg.n_heads}: the serving K/V cache holds n_heads heads a "
+            "layer; grouped-query attention is not served")
+
+
 def _serving_specs(cfg: TransformerConfig, quantized):
     """Param in_specs/placement tree: the serving layout, run through
     `quant.model.quantize_specs` when the tree is quantized (values
     keep the float spec, scales drop sharding on their size-1 axis)."""
+    _refuse_unserved(cfg)
     specs = serving_param_specs(cfg)
     if quantized:
         from deeplearning4j_tpu.quant.model import quantize_specs
@@ -2037,6 +2056,7 @@ def _embed_pending(params, cfg: TransformerConfig, pos, tok):
 
 
 def _check_spec(cfg: TransformerConfig, spec_k: int, draft_layers: int):
+    _refuse_unserved(cfg)
     if spec_k < 1:
         raise ValueError(f"spec_k must be >= 1, got {spec_k}")
     if cfg.n_experts > 0:

@@ -115,3 +115,88 @@ def test_per_head_kernels_compile_for_v5e(one_chip, shape):
     assert not fa._lane_dense_width(shape[2], shape[3], shape[1], shape[1])
     text = _compiled_grad(one_chip, shape, causal=True)
     assert text.count('custom_call_target="tpu_custom_call"') >= 2
+
+
+def _kernel_names(text):
+    """Which of the program's kernel names the compiled Mosaic calls
+    carry (an instruction is named for its kernel, under whatever JAX
+    transformation wrapped the call)."""
+    calls = [line.split("=")[0] for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    return {name for name in ("flash_fwd", "flash_bwd", "gdn_fwd", "gdn_bwd",
+                              "moe_gmm_fwd", "moe_gmm_dw")
+            if any(name in c for c in calls)}
+
+
+def test_grouped_query_kernels_compile_for_v5e(one_chip):
+    """Qwen3-Next's full layer at the cell's size: 16 query heads on 2 KV
+    heads of 256 at T 8192, a K/V head's block shared by its 8 query
+    heads (one head an entry), resident K/V past the default of scoped
+    VMEM."""
+    b, t, h, hk, d = 3, 8192, 16, 2, 256
+    q = jax.ShapeDtypeStruct((b, t, h, d), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b, t, hk, d), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention(q, k, v, causal=True).astype(
+            jnp.float32) ** 2)
+
+    with jax.enable_x64(False):
+        text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+            q, kv, kv).compile().as_text()
+    assert {"flash_fwd", "flash_bwd"} <= _kernel_names(text)
+    # K and V reach the kernels with their 2 heads, never repeated to 16
+    operands = _kernel_operands(text)
+    # (the forward takes 24 query heads and their 3 KV heads a call,
+    # twice: the grid cap, in whole groups)
+    assert (24, t, d) in operands and (3, t, d) in operands
+    assert (b * h, t, d) not in operands or (b * hk, t, d) in operands
+
+
+def test_gated_delta_kernels_compile_for_v5e(one_chip, monkeypatch):
+    """The chunked gated delta rule at the cell's size (16 key and 32 value
+    heads of 128, 3 rows of 8192), forward and the in-kernel vjp."""
+    import deeplearning4j_tpu.ops.gated_delta as gd
+    from deeplearning4j_tpu.ops import pallas_util
+    monkeypatch.setattr(pallas_util, "off_chip", lambda: False)
+    b, t, hk, hv, d = 3, 8192, 16, 32, 128
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (sd((b, t, hk, d), jnp.bfloat16), sd((b, t, hk, d), jnp.bfloat16),
+            sd((b, t, hv, d), jnp.bfloat16), sd((b, t, hv), jnp.float32),
+            sd((b, t, hv), jnp.float32))
+
+    def loss(*a):
+        return jnp.sum(gd.gated_delta_rule(*a).astype(jnp.float32) ** 2)
+
+    with jax.enable_x64(False):
+        text = jax.jit(jax.grad(loss, (0, 1, 2, 3, 4))).lower(
+            *args).compile().as_text()
+    assert {"gdn_fwd", "gdn_bwd"} <= _kernel_names(text)
+
+
+def test_grouped_matmul_kernels_compile_for_v5e(one_chip, monkeypatch):
+    """The dropless experts at the cell's size: 32 held experts of
+    2048 x (2 x 512), a buffer for every pair of 24,576 tokens x 10."""
+    import deeplearning4j_tpu.ops.grouped_matmul as gm
+    from deeplearning4j_tpu.ops import pallas_util
+    monkeypatch.setattr(pallas_util, "off_chip", lambda: False)
+    held, d, f, n, k = 32, 2048, 512, 24576, 10
+    rows = gm.buffer_rows(n * k, held)
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(a, w, te, nl):
+        return jnp.sum(gm.grouped_matmul(a, w, te, nl).astype(
+            jnp.float32) ** 2)
+
+    with jax.enable_x64(False):
+        text = jax.jit(jax.grad(loss, (0, 1))).lower(
+            sd((rows, d), jnp.bfloat16), sd((held, d, 2 * f), jnp.float32),
+            sd((rows // gm.TILE_M,), jnp.int32),
+            sd((1,), jnp.int32)).compile().as_text()
+    assert {"moe_gmm_fwd", "moe_gmm_dw"} <= _kernel_names(text)
