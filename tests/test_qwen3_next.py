@@ -76,6 +76,14 @@ def close(a, b, tol):
         (np.max(np.abs(a - b)), scale)
 
 
+def agree(a, b, tol):
+    """Relative in the norm: what two orders of one float32 sum differ
+    by, where `close`'s largest element is one unlucky cancellation."""
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    assert np.linalg.norm(a - b) <= tol * np.linalg.norm(b), \
+        (np.linalg.norm(a - b), np.linalg.norm(b))
+
+
 MM = ref._mm_fn("f32")
 PIECES = {
     "deltanet": (lambda x, p, s, cfg: lk.gated_deltanet(x, p, cfg),
@@ -154,35 +162,120 @@ def test_no_row_is_dropped_whatever_the_router_sends(skewed):
     close(gp["We_down"], rp["We_down"], 1e-4)
 
 
-@pytest.mark.parametrize("t", [50, 64, 200])
-def test_chunked_delta_rule_matches_the_recurrence(t):
-    """T not a multiple of the chunk (64), and one that is."""
-    from deeplearning4j_tpu.ops.gated_delta import gated_delta_rule
-    b, hk, hv, dk, dv = 2, 2, 4, 16, 32
-    ks = jax.random.split(jax.random.PRNGKey(t), 6)
+def _delta_inputs(seed, b, t, hk, hv, dk, dv, dtype):
+    """q, k (normalised), v, g, beta and a cotangent; q, k, v and the
+    cotangent are bfloat16 values, held in `dtype`."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+
+    def held(x):
+        return x.astype(jnp.bfloat16).astype(dtype)
+
     q = ref._l2(jax.random.normal(ks[0], (b, t, hk, dk), jnp.float32)) * dk ** -0.5
     k = ref._l2(jax.random.normal(ks[1], (b, t, hk, dk), jnp.float32))
     v = jax.random.normal(ks[2], (b, t, hv, dv), jnp.float32)
     g = -0.3 * jnp.exp(jax.random.normal(ks[3], (b, t, hv), jnp.float32))
     beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, t, hv), jnp.float32))
     co = jax.random.normal(ks[5], (b, t, hv, dv), jnp.float32)
+    return held(q), held(k), held(v), g, beta, held(co)
+
+
+def _delta_loss_and_grads(q, k, v, g, beta, co):
+    from deeplearning4j_tpu.ops.gated_delta import gated_delta_rule
+
+    def loss(q, k, v, g, beta):
+        o = gated_delta_rule(q, k, v, g, beta)
+        return jnp.sum(co.astype(jnp.float32) * o.astype(jnp.float32)), o
+
+    with jax.default_matmul_precision("highest"):
+        (_, o), grads = jax.value_and_grad(
+            loss, argnums=(0, 1, 2, 3, 4), has_aux=True)(q, k, v, g, beta)
+    return (o,) + grads
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("t", [50, 64, 200])
+def test_chunked_delta_rule_matches_the_recurrence(t, dtype):
+    """T not a multiple of the chunk (64), and one that is. float32
+    operands to rounding; bfloat16 q, k, v against the recurrence run in
+    float32 on the same values, as far as the bfloat16 outputs (o, dq,
+    dk, dv: a rounding a head, a sum, a rounding) allow. The gates'
+    gradients are float32 either way."""
+    b, hk, hv, dk, dv = 2, 2, 4, 16, 32
+    q, k, v, g, beta, co = _delta_inputs(t, b, t, hk, hv, dk, dv, dtype)
+    f32 = [x.astype(jnp.float32) for x in (q, k, v, g, beta, co)]
 
     def plain(q, k, v, g, beta):
         r = hv // hk
-        return jnp.sum(co * ref.delta_rule(jnp.repeat(q, r, 2),
-                                           jnp.repeat(k, r, 2), v, g, beta))
-
-    def kernel(q, k, v, g, beta):
-        return jnp.sum(co * gated_delta_rule(q, k, v, g, beta))
+        o = ref.delta_rule(jnp.repeat(q, r, 2), jnp.repeat(k, r, 2), v, g,
+                           beta)
+        return jnp.sum(f32[5] * o), o
 
     with jax.default_matmul_precision("highest"):
-        lp, gp = jax.value_and_grad(plain, argnums=(0, 1, 2, 3, 4))(
-            q, k, v, g, beta)
-        lk_, gk = jax.value_and_grad(kernel, argnums=(0, 1, 2, 3, 4))(
-            q, k, v, g, beta)
-    assert abs(float(lp) - float(lk_)) <= 2e-5 * abs(float(lp)) + 1e-5
-    for a, c in zip(gk, gp):
-        close(a, c, 2e-5)
+        (_, o), grads = jax.value_and_grad(
+            plain, argnums=(0, 1, 2, 3, 4), has_aux=True)(*f32[:5])
+    got = _delta_loss_and_grads(q, k, v, g, beta, co)
+    held = 2e-5 if dtype == "float32" else 2.0 ** -7
+    for a, c, tol in zip(got, (o,) + grads, [held] * 4 + [2e-5] * 2):
+        close(a, c, tol)
+
+
+def test_bfloat16_operands_change_no_sum():
+    """What the kernels' cheaper products rest on: q, k, v and the
+    cotangent taken as bfloat16 give the sums that the same values give
+    as float32 operands (every product `HIGHEST`), over four chunks. The
+    float32 outputs (the gates' gradients, which the backward forms from
+    every chunk's saved state) to 1e-6; the outputs held in bfloat16 to
+    their rounding."""
+    shape = dict(b=1, t=200, hk=2, hv=4, dk=16, dv=32)
+    narrow = _delta_inputs(7, dtype="bfloat16", **shape)
+    wide = _delta_inputs(7, dtype="float32", **shape)
+    for x, y in zip(narrow, wide):
+        assert np.array_equal(np.asarray(x, np.float32), np.asarray(y))
+    got, want = _delta_loss_and_grads(*narrow), _delta_loss_and_grads(*wide)
+    for a, c in zip(got[:4], want[:4]):
+        close(a, c, 2.0 ** -7)
+    for a, c in zip(got[4:], want[4:]):
+        agree(a, c, 1e-6)
+
+
+def _one_chunk(seed, dtype):
+    c, dk, dv = 64, 16, 32
+    q, k, v, g, beta, do = (x[0, :, 0] for x in _delta_inputs(
+        seed, 1, c, 1, 1, dk, dv, dtype))
+    ks = jax.random.split(jax.random.PRNGKey(seed + 1), 2)
+    gam, beta = jnp.cumsum(g)[None, :], beta[None, :]
+    s0 = jax.random.normal(ks[0], (dk, dv), jnp.float32)
+    ds1 = jax.random.normal(ks[1], (dk, dv), jnp.float32)
+    return (q, k, v, gam, beta, s0), (do, ds1)
+
+
+def test_the_written_out_chunk_backward_is_the_plain_chunks_vjp():
+    """One chunk, a state before it and a cotangent of the state after
+    it: `_chunk_fwd` and `_chunk_bwd` against `_chunk` and `jax.vjp` of
+    it, the gates' gradients among them."""
+    from deeplearning4j_tpu.ops import gated_delta as gd
+    args, cot = _one_chunk(3, "float32")
+    with jax.default_matmul_precision("highest"):
+        want, pull = jax.vjp(gd._chunk, *args)
+        for a, c in zip(gd._chunk_fwd(*args), want):
+            close(a, c, 2e-6)
+        for a, c in zip(gd._chunk_bwd(*args, *cot), pull(cot)):
+            close(a, c, 1e-5)
+
+
+def test_a_chunk_in_bfloat16_operands_is_the_chunk_in_float32():
+    """The same on one chunk, where every output is float32: o, the
+    state and all six gradients to 1e-6."""
+    from deeplearning4j_tpu.ops import gated_delta as gd
+    (narrow, ncot), (wide, wcot) = (_one_chunk(5, d)
+                                    for d in ("bfloat16", "float32"))
+    assert narrow[0].dtype == jnp.bfloat16 and wide[0].dtype == jnp.float32
+    with jax.default_matmul_precision("highest"):
+        for a, c in zip(gd._chunk_fwd(*narrow), gd._chunk_fwd(*wide)):
+            agree(a, c, 1e-6)
+        for a, c in zip(gd._chunk_bwd(*narrow, *ncot),
+                        gd._chunk_bwd(*wide, *wcot)):
+            agree(a, c, 1e-6)
 
 
 def test_grouped_query_flash_kernel_matches_plain_attention(monkeypatch):

@@ -156,8 +156,11 @@ def test_grouped_query_kernels_compile_for_v5e(one_chip):
 
 def test_gated_delta_kernels_compile_for_v5e(one_chip, monkeypatch):
     """The chunked gated delta rule at the cell's size (16 key and 32 value
-    heads of 128, 3 rows of 8192), forward and the in-kernel vjp."""
+    heads of 128, 3 rows of 8192), forward and the written-out backward,
+    on the bfloat16 operands the layer hands them. The benchmark's
+    `gdn_scan_*` metrics find the two kernels by their names."""
     import deeplearning4j_tpu.ops.gated_delta as gd
+    from deeplearning4j_tpu.observability.tracing import default_spans
     from deeplearning4j_tpu.ops import pallas_util
     monkeypatch.setattr(pallas_util, "off_chip", lambda: False)
     b, t, hk, hv, d = 3, 8192, 16, 32, 128
@@ -175,7 +178,12 @@ def test_gated_delta_kernels_compile_for_v5e(one_chip, monkeypatch):
     with jax.enable_x64(False):
         text = jax.jit(jax.grad(loss, (0, 1, 2, 3, 4))).lower(
             *args).compile().as_text()
-    assert {"gdn_fwd", "gdn_bwd"} <= _kernel_names(text)
+    assert _kernel_names(text) == {"gdn_fwd", "gdn_bwd"}
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    last = [sp for sp in default_spans().snapshot().spans
+            if sp.name == "gdn.layout"][-1]
+    assert last.args == {"chunk": 64, "heads": hv, "block": 512,
+                         "operands": "bfloat16"}
 
 
 def test_grouped_matmul_kernels_compile_for_v5e(one_chip, monkeypatch):
