@@ -25,7 +25,7 @@ any jax/libtpu bump:
   * "CRASH REPRODUCED" -> the caps are still needed; nothing to do
     (matches_known_signature tells you which signature appeared).
   * "NO CRASH" -> the toolchain moved the boundary; the caps can be
-    raised (re-sweep with DL4JTPU_MAX_GRID overrides and update
+    raised (edit the two caps in
     ops/flash_attention.py).
 
 Chip-only; the failure is a compile error, nothing hangs. Not
@@ -33,21 +33,22 @@ collected by pytest (benchmarks/ is outside tests/).
 
 Usage: python benchmarks/grid_crash_repro.py
 """
+import importlib
 import json
 import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-os.environ["DL4JTPU_MAX_GRID"] = "100000"   # lift the cap: repro mode
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 
 def main() -> int:
-    from deeplearning4j_tpu.ops.flash_attention import _flash_forward
-
+    # the module, not the function that ops/__init__ re-exports as it
+    fa = importlib.import_module("deeplearning4j_tpu.ops.flash_attention")
+    fa._MAX_2D_GRID_FWD = fa._MAX_2D_GRID_BWD = 100000   # repro mode
     if jax.default_backend() != "tpu":
         print(json.dumps({"repro": "grid_crash", "skipped":
                           "needs the real TPU backend"}))
@@ -60,7 +61,7 @@ def main() -> int:
     k = jax.random.normal(ks[1], (bh, t, d), jnp.bfloat16)
     v = jax.random.normal(ks[2], (bh, t, d), jnp.bfloat16)
     try:
-        out, _ = jax.jit(lambda a, b, c: _flash_forward(
+        out, _ = jax.jit(lambda a, b, c: fa._flash_forward(
             a, b, c, d, 0.125, True, 0, 0, False))(q, k, v)
         float(jnp.sum(out.astype(jnp.float32)))
     except Exception as e:

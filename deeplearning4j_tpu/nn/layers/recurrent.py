@@ -174,8 +174,8 @@ class LSTM(BaseLayer):
 def wavefront_scan_stack(layers, plist, x, carries=None):
     """Run a STACK of unidirectional LSTM layers as one wavefront scan
     (measured r4: 1.14x at B=1024, 1.28x at B=8192 on the 2x200
-    char-RNN vs per-layer sequential scans —
-    benchmarks/lstm_stack_experiment.py).
+    char-RNN vs per-layer sequential scans; BASELINE.md r4, an
+    earlier toolchain).
 
     Schedule: T + n - 1 steps; at step s, layer j advances to time
     s - j, consuming h_{j-1}[s-j] — exactly the carry layer j-1 holds

@@ -20,7 +20,7 @@ Design constraints, mirroring the metrics substrate:
   engine adds a handful per request per chunk against
   milliseconds-to-seconds of compiled decode. `NULL_RECORDER` /
   `NULL_TRACE` mirror `NULL_REGISTRY`: disabling is injection, not
-  if-guards — the "off" arm of the `engine_slo` benchmark.
+  if-guards.
 - **Bounded memory.** The global ring is a `deque(maxlen=capacity)`;
   per-request traces are bounded by the request's own lifetime
   (≤ max_new_tokens/chunk decode events) and die with the handle.

@@ -20,8 +20,7 @@ a single dialect). Design constraints, in order:
 - **Injectable.** A process-default registry (`default_registry()`)
   for the common one-process case, plus freely constructible
   `MetricsRegistry` instances for per-engine isolation, and
-  `NULL_REGISTRY` whose instruments are no-ops — the "bare" arm of the
-  instrumented-vs-bare benchmark (flagship.py engine_decode_metrics).
+  `NULL_REGISTRY` whose instruments are no-ops.
 
 Exposition (Prometheus text / JSON / HTTP) lives in
 `observability/export.py`; span-based tracing in
@@ -379,7 +378,7 @@ _NULL_INSTRUMENT = _NullInstrument()
 
 class NullRegistry:
     """Registry whose instruments do nothing — instrumentation can be
-    disabled by injection (the benchmark's "bare" arm) instead of by
+    disabled by injection instead of by
     `if` guards at every call site."""
 
     def counter(self, name, help="", labelnames=()) -> _NullInstrument:

@@ -50,8 +50,7 @@ module (ISSUE-15) is that accounting layer, three instruments in one:
   router-fanned (`serving/fleet.Router.profilez`).
 
 Disable-by-injection mirrors the rest of the observability substrate:
-`NULL_PROFILER` makes every call a no-op — the profiling-off arm of
-the ``profiling_overhead`` benchmark (≤ 2% bound, BASELINE.md).
+`NULL_PROFILER` makes every call a no-op.
 """
 from __future__ import annotations
 
@@ -469,8 +468,7 @@ class EngineProfiler:
 
 
 class NullProfiler:
-    """No-op twin: disable profiling by injection (the benchmark's
-    profiling-off arm), never by if-guards at the call sites."""
+    """No-op twin: disable profiling by injection, never by if-guards."""
 
     enabled = False
     peak_flops = None

@@ -13,8 +13,7 @@ recorder's traces (observability/events.py) and publishes them twice:
   ``serving_e2e_seconds``, ``serving_queue_age_seconds``,
   ``serving_slo_requests_total{outcome}``, ``serving_goodput_ratio``);
 - as a windowed `report()` dict (p50/p95/p99 over the last N terminal
-  requests) — the `/slo` endpoint's body and the `engine_slo`
-  benchmark's output.
+  requests) — the `/slo` endpoint's body.
 
 Definitions (all from monotonic trace timestamps):
 

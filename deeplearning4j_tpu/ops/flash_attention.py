@@ -442,30 +442,10 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, stat_ref,
 # for a described v5e (PR 27; no chip run, so the caps stay).
 # `benchmarks/grid_crash_repro.py` is the checked-in minimal repro: run
 # it after any jax/libtpu bump — if it stops failing, the caps can be
-# raised; if smaller grids start failing, lower them via the env
-# overrides below (DL4JTPU_MAX_GRID sets both; _FWD/_BWD variants take
-# precedence). The assumed caps are logged once at first kernel build
-# so a mis-chunking run is diagnosable from its log.
-_MAX_2D_GRID_FWD = int(os.environ.get(
-    "DL4JTPU_MAX_GRID_FWD", os.environ.get("DL4JTPU_MAX_GRID", "96")))
-_MAX_2D_GRID_BWD = int(os.environ.get(
-    "DL4JTPU_MAX_GRID_BWD", os.environ.get("DL4JTPU_MAX_GRID", "32")))
-
-_caps_logged = False
-
-
-def _log_caps_once():
-    global _caps_logged
-    if _caps_logged:
-        return
-    _caps_logged = True
-    import logging
-    logging.getLogger(__name__).info(
-        "flash-attention 2-D grid caps: fwd=%d bwd=%d (empirical "
-        "compile-failure boundaries; override "
-        "DL4JTPU_MAX_GRID[_FWD|_BWD]; repro: "
-        "benchmarks/grid_crash_repro.py)",
-        _MAX_2D_GRID_FWD, _MAX_2D_GRID_BWD)
+# raised; if smaller grids start failing, lower them. It lifts the caps
+# by assigning these two names in its own process.
+_MAX_2D_GRID_FWD = 96
+_MAX_2D_GRID_BWD = 32
 
 
 def _bh_chunks(bh: int, nsb: int, cap: int, group: int = 1):
@@ -482,17 +462,18 @@ def _bh_chunks(bh: int, nsb: int, cap: int, group: int = 1):
 # scoped-vmem accounting lands 156KB over the 16MB cap (measured r5),
 # so longer sequences split over q at host level — forward q chunks
 # are fully independent (per-row online-softmax stats), no merge pass.
-_FWD_Q_CHUNK = int(os.environ.get("DL4JTPU_FWD_Q_CHUNK", "8192"))
+_FWD_Q_CHUNK = 8192
+_FWD_QSB = 2048
 
 
 def _q_superblock(tq: int) -> int:
     """Forward q-superblock: bounds per-program VMEM (full-T q/o
     blocks blow the 16MB budget past T=2048); K/V block indices are
     constant in that grid dim, so they stay VMEM-resident across a
-    head's superblocks. Env-overridable: very long K/V (>8k rows
+    head's superblocks. Very long K/V (>8k rows
     resident) needs a smaller superblock to stay under the scoped-vmem
     cap (r5)."""
-    return _inner_block(tq, int(os.environ.get("DL4JTPU_FWD_QSB", "2048")))
+    return _inner_block(tq, _FWD_QSB)
 
 
 def _k_superblock(sk: int, bk: int) -> int:
@@ -557,7 +538,6 @@ def _flash_forward_impl(q3, k3, v3, dh: int, scale: float, causal: bool,
     from deeplearning4j_tpu.ops.pallas_util import (interpret_arg,
                                                     out_struct)
 
-    _log_caps_once()
     n, tq, lanes = q3.shape
     sk = k3.shape[1]
     w = _block_width(lanes, dh)
@@ -686,20 +666,19 @@ def _fwd(q3, k3, v3, dh, scale, causal, q_offset, kv_offset, interpret,
 # three [T, 1] columns, lane-padded 128x; not found again since they
 # are rows) — past this the 16MB budget blew, so longer sequences
 # split over q at the host level (dK/dV are linear in the q chunks and
-# sum; dQ concatenates). Env-overridable for A/B runs; do NOT lower it
+# sum; dQ concatenates). Do NOT lower it
 # chasing speed — the round-4 end-to-end A/B measured chunk 512 COSTS
 # 16% on the flagship step (4x K/V re-reads); the default is the
-# measured optimum and the override exists for re-sweeps after
-# toolchain bumps
-_BWD_Q_CHUNK = int(os.environ.get("DL4JTPU_BWD_Q_CHUNK", "4096"))
+# measured optimum
+_BWD_Q_CHUNK = 4096
 
 
 # K/V extent past which the backward is 2-D host-tiled (see _bwd).
 # 4096 = the longest sk the single fused call compiles at on this
 # toolchain; the TILE edge is 2048 — the per-call extent PROVEN to
 # compose (the 12-layer T=2048 training program holds 12 such calls).
-_BWD_K_CHUNK = int(os.environ.get("DL4JTPU_BWD_K_CHUNK", "4096"))
-_BWD_LONG_TILE = int(os.environ.get("DL4JTPU_BWD_LONG_TILE", "2048"))
+_BWD_K_CHUNK = 4096
+_BWD_LONG_TILE = 2048
 
 
 def _chunk_of(n: int, cap: int) -> int:

@@ -49,7 +49,7 @@ D=1024 (8 heads of 128), K+1=5. History (one v5e, 2026-07-30/31, an
 earlier toolchain; BASELINE.md r4/r5 — not re-measured):
 B=64 12L/512d S=2048 ran 2.07 ms/step at short prefixes and 9.2
 ms/step at a ~full cache, against 21.7 ms/step for the round-3 jnp
-path; a (bs, bb) sweep (benchmarks/decode_kernel_sweep.py) found
+path; a (bs, bb) sweep found
 full-cache time invariant to block geometry, bs=128 best at short
 prefixes (finer prefix read), and 4MB cache blocks failing to compile
 — hence the 128-row, 2MB defaults below, which this module keeps
@@ -307,14 +307,9 @@ def decode_attention(q: Array, k_cache: Array, v_cache: Array, pos,
                          scale, h=q.shape[1])
 
 
-def _env_pos_int(name: str, default: int) -> int:
-    """Positive-int env override; malformed or non-positive values
-    fall back to the default rather than crashing decode."""
-    try:
-        v = int(os.environ.get(name, ""))
-    except ValueError:
-        return default
-    return v if v > 0 else default
+# a K/V block's cache rows and its bytes in VMEM: see _split_k_call
+_BLOCK_ROWS = 128
+_BLOCK_BYTES = 1 << 21
 
 
 def _split_k_call(kernel, q3: Array, k_cache: Array, v_cache: Array, pos,
@@ -331,8 +326,7 @@ def _split_k_call(kernel, q3: Array, k_cache: Array, v_cache: Array, pos,
     and the batch block keeps each K/V block within 2MB of VMEM (~8MB
     in flight double-buffered), sized by the cache's actual itemsize.
     Both were chosen by a sweep on an earlier toolchain (module
-    docstring) and are unchanged here. DL4JTPU_DECODE_BS and
-    DL4JTPU_DECODE_BLOCK_BYTES override them for such a sweep.
+    docstring) and are unchanged here.
 
     ``window`` (T query rows per batch row) divides the block budget:
     Mosaic keeps one [bb, bs, Dh] f32 product live per unrolled
@@ -352,11 +346,10 @@ def _split_k_call(kernel, q3: Array, k_cache: Array, v_cache: Array, pos,
         scale = 1.0 / (dh ** 0.5)
     pos_rows = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
     reach = jnp.minimum(pos_rows + (window - 1), s - 1)
-    bs = _largest_divisor(s, _env_pos_int("DL4JTPU_DECODE_BS", 128))
-    blk_bytes = _env_pos_int("DL4JTPU_DECODE_BLOCK_BYTES", 1 << 21)
+    bs = _largest_divisor(s, _BLOCK_ROWS)
     itemsize = jnp.dtype(k_cache.dtype).itemsize
     bb = _largest_divisor(
-        b, max(1, blk_bytes // max(1, window * bs * d * itemsize)))
+        b, max(1, _BLOCK_BYTES // max(1, window * bs * d * itemsize)))
     n_blocks = s // bs
     reach_blk = jnp.max(reach.reshape(b // bb, bb), axis=1)
 

@@ -450,7 +450,7 @@ class ElasticFaultInjector:
     coordinator (`train/elastic.py`) consults it at the start of every
     global step, so membership churn that would need real crashed
     hosts replays deterministically on the CPU backend
-    (tests/test_elastic_training.py, ``flagship.py elastic_train``).
+    (tests/test_elastic_training.py).
 
     All knobs are keyed by GLOBAL step index and fire one-shot: after
     a lossy resize rewinds the step counter, replayed steps do not
@@ -555,14 +555,14 @@ def hostile_tenant_storm(ticks: int = 120, *,
                          slow_seconds: float = 0.05,
                          ) -> Tuple[List[StormArrival], Dict]:
     """Deterministic hostile-tenant arrival script (ISSUE-16), shared
-    by the QoS fairness tests and ``flagship.py qos_storm``.
+    by the QoS fairness tests (tests/test_serving_qos.py).
 
     One well-behaved ``victim`` tenant submits a short high-priority
     request every ``victim_every`` ticks while ``hostiles`` flood
     tenants each submit ``flood_per_tick`` long low-priority requests
     EVERY tick — the adversarial mix a fair-share scheduler must not
     let starve the victim. No RNG is consulted: the same kwargs always
-    yield the same arrivals, so a bench run and a test assert on the
+    yield the same arrivals, so every test asserts on the
     same traffic.
 
     Returns ``(arrivals, injector_kwargs)``: arrivals sorted by

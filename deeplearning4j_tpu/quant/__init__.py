@@ -26,8 +26,8 @@ Integration points: `TransformerConfig.cache_dtype` (bf16 caches with
 f32 activations — the non-quantized half-step),
 `parallel.serving.make_continuous_{prefill,decode}(kv_mode=...)`,
 `serving.InferenceEngine(quantize=..., kv_quantize=...)`, checkpoint
-round-trip of QuantizedTensor trees through the manifest, and the
-`quant_decode` flagship bench arm. Accuracy envelope and layout:
+round-trip of QuantizedTensor trees through the manifest
+(tests/test_quant.py). Accuracy envelope and layout:
 docs/quantization.md.
 """
 from deeplearning4j_tpu.quant.core import (  # noqa: F401

@@ -199,7 +199,7 @@ def max_logit_divergence(cfg, params_f: Dict[str, Any],
                          params_q: Dict[str, Any], tokens,
                          dtype=None) -> float:
     """max |logits_float - logits_quantized| over a token batch — the
-    scalar the accuracy tests and the quant_decode bench arm report.
+    scalar the accuracy tests report.
     Runs both trees through the SAME `forward` so the only delta is
     the weights' precision."""
     from deeplearning4j_tpu.models.transformer import forward

@@ -320,7 +320,7 @@ class TieredRouter(Router):
                      if decode_autoscale else None)}
         self._handoff_seq = 0
         self._last_handoff: Optional[dict] = None
-        #: [{t, tier, direction, replicas}] — the bench's replica-count
+        #: [{t, tier, direction, replicas}] — the replica-count
         #: trajectory and the debugz audit trail
         self.autoscale_log: List[dict] = []
 

@@ -21,8 +21,7 @@ and steady-state mixed traffic triggers zero XLA recompiles.
 ``mode="batch"`` keeps the PR-1 batch-to-completion path: a dynamic
 batcher coalesces queued prompts of IDENTICAL length, re-stacks
 prompt+generated, and re-invokes `make_parallel_generate` per chunk —
-the benchmark baseline (`flagship.py --config engine_continuous`
-replays one trace through both modes) and the single-shot
+it is also the single-shot
 (`decode_chunk=0`) lowest-overhead mode.
 
 Failure semantics:
@@ -101,8 +100,7 @@ one lane per slot plus the queue lane) — wire them into
 `/debugz`, `/slo`, `/timeline.json`. Recording defaults ON with a
 live registry and mirrors it off: `registry=NULL_REGISTRY` (or
 `recorder=observability.NULL_RECORDER`) makes every trace call a
-no-op — the `engine_slo` benchmark's bare arm (overhead bound ≤ 2%,
-BASELINE.md).
+no-op.
 
 Paged KV + radix prefix sharing (round 12, ISSUE-7,
 `EngineConfig(paged=True, page_size=, kv_pages=, prefix_cache=)`):
@@ -185,8 +183,7 @@ plus a jax/jaxlib/backend salt, atomic publish + corrupt-entry
 fallback), and finally `jit(...).lower(...).compile()`. `warmup()` /
 `EngineConfig(warmup_on_init=True)` resolves the whole closed program
 set up front, so a restarted or autoscaled replica with a warm cache
-LOADS instead of recompiling — restart-to-first-token drops ~20x on
-the CPU container (BASELINE.md `cold_start`). Independently,
+LOADS instead of recompiling. Independently,
 `EngineConfig(pipeline=True)` double-buffers the continuous tick
 loop: each tick's compiled calls are DISPATCHED without blocking and
 the previous tick's outputs commit at one sync point, so host
@@ -220,8 +217,7 @@ recompute) x the per-token program cost — into
 `EngineConfig(profile_dir=)` + `engine.profilez(seconds)` back the
 `/profilez?seconds=N` on-demand jax.profiler capture (single-flight,
 503 when unsupported). `profiler=observability.NULL_PROFILER`
-disables it all by injection — the profiling_overhead benchmark's
-off arm (≤ 2% bound, BASELINE.md). See docs/observability.md
+disables it all by injection. See docs/observability.md
 "Profiling & cost attribution".
 
 Every behavior is deterministically testable on the CPU backend via
@@ -534,8 +530,7 @@ class EngineConfig:
     # instead of recompiling (serving_compiles_total{source=
     # "aot_cache"}). ``warmup_on_init`` runs `warmup()` inside
     # __init__ so the constructor returns a ready engine: the whole
-    # closed program set resolved (from the AOT cache when warm),
-    # restart-to-ready measured by the cold_start bench.
+    # closed program set resolved (from the AOT cache when warm).
     # ``pipeline`` switches the continuous tick loop to the
     # double-buffered schedule: compiled calls are DISPATCHED without
     # blocking and their outputs committed at the NEXT tick's single
@@ -543,7 +538,7 @@ class EngineConfig:
     # compute (decode/prefill token COUNTS are deterministic, so the
     # schedule runs one tick ahead of the committed values — token
     # values are never observed before their sync). True (the default
-    # since ISSUE-14: the loop soaked through round 17's bench matrix
+    # since ISSUE-14: tests/test_serving_pipeline.py holds the loop
     # token-exact with every failure semantic preserved) pipelines
     # every continuous engine; spec_decode (acceptance makes commit
     # counts nondeterministic) and mode="batch" AUTO-FALL-BACK to the
@@ -572,7 +567,7 @@ class EngineConfig:
     # attribution, serving_mfu, rooflines) defaults ON with a live
     # registry and OFF with NULL_REGISTRY, exactly like the flight
     # recorder; inject profiler=observability.NULL_PROFILER for the
-    # profiling-disabled arm (the profiling_overhead bench).
+    # profiling-disabled arm.
     profile_dir: Optional[str] = None
     tenant_top_n: int = 8
     # tenant QoS control plane (ISSUE-16). ``tenant_weights`` turns on
@@ -1407,8 +1402,6 @@ class InferenceEngine:
         self._busy_since: Optional[float] = None
         self._tick_busy_s = 0.0
         self._busy_total_s = 0.0     # cumulative dispatched-work time
-        #                              (the cold_start bench's time-
-        #                              weighted idle denominator)
         self._last_idle = 0.0
         self._tick_perf0 = _perf()
         # in-memory compiled-program cache bound (process-wide; the
@@ -1572,8 +1565,7 @@ class InferenceEngine:
         # continuous profiling & cost attribution (ISSUE-15): the
         # per-program cost table + device-time attribution + tenant
         # meter. Defaults ON with a live registry, mirroring the
-        # recorder; profiler=NULL_PROFILER is the disabled arm of the
-        # profiling_overhead benchmark.
+        # recorder; profiler=NULL_PROFILER is the disabled arm.
         if profiler is None:
             profiler = (NULL_PROFILER
                         if isinstance(self.registry, NullRegistry)
@@ -1689,8 +1681,7 @@ class InferenceEngine:
             buckets=DECODE_LATENCY_BUCKETS)
         # prefill-compute accounting (ISSUE-14): the prompt tokens
         # whose K/V THIS engine actually computed — prefix-cache hits
-        # and adopted handoffs excluded — i.e. the fleet affinity
-        # bench's "prefill compute spent" numerator
+        # and adopted handoffs excluded
         self._m_prefill_tokens = r.counter(
             "serving_prefill_tokens",
             "Prompt tokens prefilled by this engine (prefix-cache "
@@ -2292,8 +2283,7 @@ class InferenceEngine:
         whether any work was done. Batch mode: form one same-length
         batch and run it to completion. Continuous mode: fill free
         slots from the queue (one fused prefill), then advance every
-        occupied slot one decode chunk. Public so callers (and the
-        engine_continuous benchmark's arrival-replay loop) can
+        occupied slot one decode chunk. Public so callers can
         interleave submissions with decode progress."""
         if self._continuous:
             self._tick_no += 1
@@ -4347,7 +4337,7 @@ class InferenceEngine:
         serves from warm programs. With a warm `compile_cache_dir`
         every resolution is an AOT LOAD: restart-to-ready collapses
         from the compile set's cost to the deserialize set's
-        (the cold_start bench's claim). Returns a report dict
+        cost. Returns a report dict
         ({"seconds", "programs", "jit", "aot_cache"}), also kept on
         `engine.last_warmup` for debugz/health surfaces."""
         if not self._continuous:
@@ -5568,8 +5558,7 @@ class InferenceEngine:
 
     def slo_report(self) -> dict:
         """Windowed SLO report (observability/slo.py): TTFT / TPOT /
-        e2e / queue-age percentiles + goodput — `GET /slo`'s body and
-        the engine_slo benchmark's output."""
+        e2e / queue-age percentiles + goodput — `GET /slo`'s body."""
         return self.slo.report()
 
     def profile_report(self) -> dict:

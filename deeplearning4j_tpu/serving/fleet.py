@@ -179,7 +179,7 @@ class FleetConfig:
     # ``affinity_weight`` blends the advertised-cached-tokens fraction
     # into the dispatch score: score = occupancy + error-EMA penalty
     # - affinity_weight * (cached_tokens / prompt_len) — 0 disables
-    # affinity entirely (pure occupancy dispatch, the bench's control
+    # affinity entirely (pure occupancy dispatch, the tests' control
     # arm). The ANTI-HERD cap zeroes the bonus on any replica at or
     # above ``affinity_max_occupancy`` occupancy, so one hot tenant
     # cannot pin a single replica into overload — the spillover

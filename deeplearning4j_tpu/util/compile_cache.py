@@ -9,7 +9,7 @@ fixed path inside the checkout, ``<checkout>/.cache/jax`` (gitignored):
 the path is part of every entry's key, so a directory named after a pid,
 a temp name or a time would never hit.
 
-``chip_smoke.py``, ``bench.py``, ``benchmarks/bench_timing.py`` and
+``chip_smoke.py``, ``perfbench/harness/cells.py`` and
 ``tests/conftest.py`` all call `enable`; nothing else in the repo sets a
 compilation-cache directory. (``serving/compile_cache.py`` is a
 different thing: an opt-in store of serialized executables for one

@@ -18,8 +18,7 @@ should pass analytic model FLOPs instead.
 
 CAVEAT (verified on jax 0.9 / TPU v5e): XLA's cost model counts a
 `lax.scan` body ONCE, independent of trip count. For scanned multi-step
-programs, cost a single-step program and multiply by the step count
-(bench.py does exactly this).
+programs, cost a single-step program and multiply by the step count.
 """
 from __future__ import annotations
 

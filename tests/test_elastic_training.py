@@ -145,83 +145,6 @@ def test_elastic_off_training_is_unchanged(tmp_path):
     assert "training_elastic" not in prometheus_text(reg)
 
 
-def test_bench_mfu_regression_gate():
-    """ISSUE-18 satellite: `bench.py --check`'s gate logic — a gated
-    flagship arm whose achieved FLOP/s drops more than the tolerance
-    below the BASELINE.json floor fails; within-tolerance dips,
-    null-floor entries, and ungated configs pass. Pure-function test:
-    no bench runs."""
-    import importlib.util
-    import json
-    import pathlib
-
-    root = pathlib.Path(__file__).resolve().parents[1]
-    spec = importlib.util.spec_from_file_location("_bench_gate",
-                                                  root / "bench.py")
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    baseline = {"flops_gate": {"elastic_train": 1e9,
-                               "transformer_lm_12L512d_T2048": 1e13,
-                               "recorded_not_gated": None}}
-    ok = [{"config": "elastic_train", "flops_per_sec": 8.5e8},
-          {"config": "transformer_lm_12L512d_T2048",
-           "flops_per_sec": 1.1e13},
-          {"config": "some_other_bench", "value": 1}]
-    assert bench.check_gate(ok, baseline, tolerance=0.2) == []
-
-    # >20% drop on one arm: exactly that arm fails
-    bad = [{"config": "elastic_train", "flops_per_sec": 7.9e8},
-           {"config": "transformer_lm_12L512d_T2048",
-            "flops_per_sec": 1e13}]
-    fails = bench.check_gate(bad, baseline, tolerance=0.2)
-    assert len(fails) == 1 and fails[0].startswith("elastic_train")
-
-    # a tighter tolerance flips the same lines to failing
-    assert len(bench.check_gate(ok, baseline, tolerance=0.1)) == 1
-
-    # missing line, errored line, and a line with no flops_per_sec
-    # are all failures — silence must not pass the gate
-    assert len(bench.check_gate([], baseline)) == 2
-    errs = bench.check_gate(
-        [{"config": "elastic_train", "error": "Boom: x"},
-         {"config": "transformer_lm_12L512d_T2048", "value": 5}],
-        baseline)
-    assert len(errs) == 2
-
-    # metric-keyed dict entries (ISSUE-19): the spec-throughput gate
-    # reads its own bench-line key, with the config and floor named
-    # in the failure
-    mbase = {"flops_gate": {"spec_pipeline_x": {
-        "metric": "tokens_per_sec_pipelined_spec", "value": 1000.0}}}
-    assert bench.check_gate(
-        [{"config": "spec_pipeline_x",
-          "tokens_per_sec_pipelined_spec": 850.0}],
-        mbase, tolerance=0.2) == []
-    mfails = bench.check_gate(
-        [{"config": "spec_pipeline_x",
-          "tokens_per_sec_pipelined_spec": 750.0}],
-        mbase, tolerance=0.2)
-    assert len(mfails) == 1
-    assert mfails[0].startswith("spec_pipeline_x")
-    assert "tokens_per_sec_pipelined_spec" in mfails[0]
-    assert "8.000e+02" in mfails[0]          # the floor, by value
-    # a dict line missing the keyed metric fails loudly too
-    assert len(bench.check_gate(
-        [{"config": "spec_pipeline_x", "flops_per_sec": 1e12}],
-        mbase)) == 1
-
-    # the shipped BASELINE.json actually carries the gate, and the
-    # elastic bench reports through it
-    shipped = json.loads((root / "BASELINE.json").read_text())
-    assert "elastic_train" in shipped["flops_gate"]
-    assert "transformer_lm_12L512d_T2048" in shipped["flops_gate"]
-    assert "spec_pipeline_4L192d_Ns8_K7" in shipped["flops_gate"]
-    for v in shipped["flops_gate"].values():
-        floor = v.get("value") if isinstance(v, dict) else v
-        assert (floor or 0) > 0
-
-
 # ---------------------------------------------------------------------------
 # multiproc: real worker processes under membership change
 # ---------------------------------------------------------------------------
@@ -327,38 +250,3 @@ def test_hang_evicts_and_restores_bit_exactness(tmp_path):
     acts = [e.data.get("action") for e in rec.recent(kind="elastic")]
     assert acts.count("evict") == 1
     assert "replay" in acts
-
-
-def test_bench_error_line_makes_the_exit_code_nonzero(monkeypatch, capsys):
-    """ISSUE-21 satellite: a config that raises prints its `error`
-    line, the remaining configs still run, and `flagship_lines` reports
-    the count that bench.py turns into a non-zero exit."""
-    import importlib.util
-    import json
-    import pathlib
-    import sys
-    import types
-
-    root = pathlib.Path(__file__).resolve().parents[1]
-    spec = importlib.util.spec_from_file_location("_bench_rc",
-                                                  root / "bench.py")
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    def boom(reps):
-        raise RuntimeError("no such kernel")
-
-    fake = types.ModuleType("flagship")
-    fake.BENCHES = {n: (lambda reps, n=n: {"config": n, "value": 1})
-                    for n in ("transformer", "transformer_1024",
-                              "transformer_32kvocab", "decode_long")}
-    fake.BENCHES["decode"] = boom
-    monkeypatch.setitem(sys.modules, "flagship", fake)
-    assert bench.flagship_lines("transformer") == 1
-    lines = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()]
-    assert [ln["config"] for ln in lines] == [
-        "transformer", "transformer_1024", "transformer_32kvocab",
-        "decode", "decode_long"]
-    assert "error" in lines[3] and "error" not in lines[4]
-    fake.BENCHES["decode"] = fake.BENCHES["transformer"]
-    assert bench.flagship_lines("transformer") == 0

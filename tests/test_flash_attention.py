@@ -356,3 +356,34 @@ def test_bwd_2d_host_tiling_matches_reference(monkeypatch):
             np.testing.assert_allclose(
                 np.asarray(a), np.asarray(b), atol=3e-2,
                 err_msg=f"tiled vs jnp d{name} causal={causal}")
+
+
+@pytest.mark.parametrize("name", ["flash_attention", "flash_decode"])
+def test_the_kernel_file_reads_one_environment_key(name):
+    """DL4JTPU_FLASH (the platform's path: auto, 0, interpret) is the
+    only environment key an attention kernel file reads: the tile
+    sizes are module constants, which a test that needs another size
+    sets on the module."""
+    import ast
+    import pathlib
+
+    import deeplearning4j_tpu.ops as ops
+    tree = ast.parse((pathlib.Path(ops.__file__).parent
+                      / f"{name}.py").read_text())
+    keys, reads = set(), 0
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Call) and isinstance(
+                node.func, ast.Attribute) and ast.unparse(node.func) in (
+                "os.environ.get", "os.getenv"):
+            keys.add(ast.literal_eval(node.args[0]))
+            reads += 1
+        elif isinstance(node, ast.Subscript) and ast.unparse(
+                node.value) == "os.environ":
+            keys.add(ast.literal_eval(node.slice))
+            reads += 1
+    assert keys == {"DL4JTPU_FLASH"}
+    # no other way in: every mention of the environment is such a read
+    assert reads == sum(
+        isinstance(n, ast.Attribute)
+        and ast.unparse(n) in ("os.environ", "os.getenv")
+        for n in ast.walk(tree))

@@ -384,8 +384,8 @@ def test_window_batch_block_shrinks_with_the_window(monkeypatch):
 
     monkeypatch.setattr(pl, "pallas_call", spy)
     monkeypatch.setenv("DL4JTPU_FLASH", "interpret")
-    monkeypatch.setenv("DL4JTPU_DECODE_BLOCK_BYTES",
-                       str(8 * 128 * 64 * 4))       # 8 rows of [128, 64] f32
+    monkeypatch.setattr(fd, "_BLOCK_BYTES",
+                        8 * 128 * 64 * 4)           # 8 rows of [128, 64] f32
     b, h, dh, s = 8, 4, 16, 256
     q, k, v = _mk(b, h, dh, s, jnp.float32)
     pos = jnp.zeros((b,), jnp.int32)

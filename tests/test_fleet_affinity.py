@@ -210,20 +210,54 @@ def test_shared_prompt_lands_on_the_same_replica(params, mesh1):
         router.close()
 
 
-def test_occupancy_only_control_arm_ignores_affinity(params, mesh1):
-    """affinity_weight=0 is the bench's control: dispatch falls back
-    to pure occupancy and no affinity series moves."""
-    router = _router(params, mesh1,
-                     fleet_kw=dict(affinity_weight=0.0,
-                                   migrate_kv=False))
-    try:
-        for i in range(3):
-            router.submit(_prompt(i))
-            router.run_pending()
-        assert router.stats["affinity_hits"] == 0
-        assert router.stats["kv_migrations_ok"] == 0
-    finally:
-        router.close()
+@pytest.mark.parametrize("trace", ["one_prompt", "tenants"])
+def test_occupancy_only_control_arm_ignores_affinity(params, mesh1,
+                                                     trace):
+    """affinity_weight=0 is the control: dispatch falls back to pure
+    occupancy and no affinity series moves. `tenants`: on three
+    tenants' requests over two 2-slot replicas (each tenant its own
+    16-token system prompt; one request a tenant first, then the other
+    nine at once) the control prefills at least 1.5x the tokens that
+    affinity dispatch with migration does, for the same tokens out."""
+    def tenant_prompt(t, i):
+        system = (np.arange(16, dtype=np.int32) * (t + 2) + t) % 29
+        return np.concatenate(
+            [system, np.asarray([5 + i, (7 + i) % 32], np.int32)])
+    if trace == "one_prompt":
+        slots, waves = 1, [[_prompt(i)] for i in range(3)]
+    else:
+        rest = [(t, i) for i in range(1, 4) for t in range(3)]
+        slots, waves = 2, [
+            [tenant_prompt(t, 0) for t in range(3)],
+            [tenant_prompt(*rest[5 * k % 9]) for k in range(9)]]
+
+    def replay(fleet_kw):
+        router = _router(params, mesh1, fleet_kw=fleet_kw,
+                         num_slots=slots, max_batch_size=slots)
+        try:
+            hs = []
+            for wave in waves:
+                hs += [router.submit(p) for p in wave]
+                router.run_pending()
+            prefilled = sum(
+                c.replica.engine.registry.get(
+                    "serving_prefill_tokens").value
+                for c in router._ctls)
+            return (prefilled, dict(router.stats),
+                    [h.result(0) for h in hs])
+        finally:
+            router.close()
+
+    occ, occ_stats, occ_tokens = replay(
+        dict(affinity_weight=0.0, migrate_kv=False))
+    assert occ_stats["affinity_hits"] == 0
+    assert occ_stats["kv_migrations_ok"] == 0
+    if trace == "tenants":
+        aff, aff_stats, aff_tokens = replay(None)
+        assert aff_stats["affinity_hits"] > 0
+        assert occ >= 1.5 * aff, (occ, aff)
+        for a, b in zip(occ_tokens, aff_tokens):
+            np.testing.assert_array_equal(a, b)
 
 
 def test_anti_herd_cap_spills_to_an_emptier_replica(params, mesh1):

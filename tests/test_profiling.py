@@ -152,13 +152,16 @@ def test_device_seconds_attribution_sums_to_busy_total(params, mesh1):
 # per-tenant metering: exact fleet totals
 # ---------------------------------------------------------------------------
 
-def test_fleet_tenant_costs_sum_exactly(params, mesh1):
+@pytest.mark.parametrize("num_replicas", [2, 1])
+def test_fleet_tenant_costs_sum_exactly(params, mesh1, num_replicas):
     """The acceptance bar: across a 2-replica, 3-tenant run the
     federated per-tenant counters equal the sum of per-request bills
     (terminal trace events carry each request's accumulated cost),
-    and the fleet total equals the sum over tenants."""
+    and the fleet total equals the sum over tenants. One replica: one
+    engine's serving_request_cost_flops counters are the whole bill.
+    Every request is billed exactly once."""
     router = Router(cfg=CFG, mesh=mesh1, params=params,
-                    num_replicas=2,
+                    num_replicas=num_replicas,
                     engine_config=EngineConfig(
                         decode_chunk=2, max_new_tokens=4,
                         max_batch_size=2, backoff_base_s=0.0))
@@ -173,12 +176,19 @@ def test_fleet_tenant_costs_sum_exactly(params, mesh1):
         # per-request bills, harvested from the replica engines'
         # terminal trace events
         bills: dict = {}
+        n_bills, counters = 0, 0.0
         for ctl in router._ctls:
             for ev in ctl.replica.engine.recorder.recent(10_000):
                 if ev.kind == "finished":
                     t = ev.data.get("tenant", "default")
                     bills[t] = (bills.get(t, 0.0)
                                 + ev.data.get("cost_flops", 0.0))
+                    n_bills += 1
+            counters += sum(c.value for _, c in ctl.replica.engine
+                            .registry.get("serving_request_cost_flops")
+                            .collect())
+        assert n_bills == len(hs)
+        assert counters == pytest.approx(sum(bills.values()), rel=1e-12)
         assert set(rep["tenants"]) == set(tenants)
         for t in tenants:
             assert rep["tenants"][t]["flops"] == pytest.approx(
@@ -467,8 +477,8 @@ def test_chip_peak_tables():
 
 def test_null_profiler_disables_by_injection(params, mesh1):
     """profiler=NULL_PROFILER: no serving_mfu / serving_program_* /
-    tenant series in the scrape, zero per-request bills — the
-    profiling_overhead benchmark's off arm."""
+    tenant series in the scrape, zero per-request bills — and the same
+    tokens as a profiled engine serves."""
     eng = InferenceEngine(CFG, mesh1, params,
                           EngineConfig(decode_chunk=2,
                                        max_new_tokens=4),
@@ -484,6 +494,12 @@ def test_null_profiler_disables_by_injection(params, mesh1):
     assert "serving_tenant_tokens" not in text
     assert h.cost_flops == 0.0
     assert "profiling" not in eng.debugz()
+    on = InferenceEngine(CFG, mesh1, params,
+                         EngineConfig(decode_chunk=2, max_new_tokens=4))
+    h_on = on.submit(_prompt(), tenant="t")
+    on.run_pending()
+    assert h_on.cost_flops > 0.0
+    np.testing.assert_array_equal(h.result(0), h_on.result(0))
 
 
 def test_tenant_meter_unit():

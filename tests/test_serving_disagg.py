@@ -37,6 +37,7 @@ from deeplearning4j_tpu.serving import (AutoscalePolicy, Autoscaler,
                                         EngineConfig, FleetConfig,
                                         HandoffError, InferenceEngine,
                                         RequestStatus, TieredRouter)
+from deeplearning4j_tpu.serving.engine import _compiled_kv_adopt
 
 CFG = TransformerConfig(vocab_size=32, d_model=32, n_heads=4,
                         n_layers=2, max_len=64)
@@ -207,20 +208,27 @@ def _held_export(params, mesh, ec, prompt, release=True):
     return eng, h, kv
 
 
-@pytest.mark.parametrize("kv_quantize", [None, "int8"],
-                         ids=["float", "int8"])
-def test_export_adopt_roundtrip_bit_exact(params, mesh1, kv_quantize):
+@pytest.mark.parametrize("kv_quantize,spec", [
+    (None, False), ("int8", False), (None, True)],
+    ids=["float", "int8", "spec"])
+def test_export_adopt_roundtrip_bit_exact(params, mesh1, kv_quantize,
+                                          spec):
     """The committed rows (values AND per-row scales) survive
     host-gather -> device-put -> decode bit-exactly: re-exporting the
     adopting engine's pool returns the identical prefix — and the
-    decode continuation equals the single-engine run."""
-    ec = _ec(kv_quantize=kv_quantize)
+    decode continuation equals the single-engine run. The adoption is
+    ONE batched all-layer program (a per-layer loop would build
+    n_layers of them), on a speculative engine too."""
+    ec = _ec(kv_quantize=kv_quantize,
+             **(dict(spec_decode=True, spec_k=3, draft="layers:1")
+                if spec else {}))
     prompt = _prompt(10, 2)
     want = _reference(params, mesh1, [prompt], ec=ec)[0]
     src, h, kv = _held_export(params, mesh1, ec, prompt)
     assert kv.pos == prompt.shape[0]
     assert kv.tok == int(h.generated[-1])
     assert (kv.k_scale is not None) == (kv_quantize == "int8")
+    _compiled_kv_adopt.cache_clear()       # so this adoption builds it
     dst = InferenceEngine(CFG, mesh1, params, ec)
     prompt_d = np.concatenate([prompt, h.generated]).astype(np.int32)
     hd = dst.submit(prompt_d, max_new_tokens=11, kv=kv, hold_kv=True)
@@ -236,6 +244,9 @@ def test_export_adopt_roundtrip_bit_exact(params, mesh1, kv_quantize):
         np.testing.assert_array_equal(back.v_scale[:, :kv.pos],
                                       kv.v_scale)
     assert int(dst._m_adoptions.labels("ok").value) == 1
+    assert sum(int(child.value) for labels, child in
+               dst.registry.get("serving_compiles").collect()
+               if labels[0] == "kv_adopt") == 1
 
 
 def test_export_requires_hold_and_releases(params, mesh1):

@@ -343,29 +343,45 @@ def test_wire_server_handler_failure_is_typed_at_dialer():
 # tiered serving: degradation + quantize-on-adopt (in-process)
 # ---------------------------------------------------------------------------
 
-def test_corrupt_frame_degrades_to_reprefill(params, mesh1):
+@pytest.mark.parametrize("arm", ["corrupt", "fallback"])
+def test_corrupt_frame_degrades_to_reprefill(params, mesh1, arm):
     """FleetFaultInjector.corrupt_frame_at runs the first handoff
     through a REAL encode -> flip-one-byte -> decode round trip: the
     frame's CRC32 rejects it, the request re-prefills on the decode
     tier, the answer is still bit-exact — and the failure is visible
-    as a typed `kvwire` trace event + serving_kvwire_frames{crc}."""
+    as a typed `kvwire` trace event + serving_kvwire_frames{crc}.
+    `fallback`: a prefill tier that cannot export (supports_handoff
+    False) sends NO frame — every request re-prefills on the decode
+    tier, counted as a fallback and not as a failure, bit-exact too."""
     prompts = [_prompt(8, i) for i in range(3)]
     want = _reference(params, mesh1, prompts)
-    inj = FleetFaultInjector(corrupt_frame_at=[0])
+    inj = FleetFaultInjector(
+        corrupt_frame_at=[0] if arm == "corrupt" else [])
     r = _tiered(params, mesh1, fault_injector=inj)
     try:
+        if arm == "fallback":
+            r._ctls[0].replica.supports_handoff = False
         hs = [r.submit(p, max_new_tokens=12) for p in prompts]
         _drive(r)
         for h, w in zip(hs, want):
             np.testing.assert_array_equal(h.result(0), w)
             assert h.status == RequestStatus.COMPLETED
+        m = r._kvwire_metrics()
+        if arm == "fallback":
+            assert inj.frames_corrupted == 0
+            assert r.stats["handoffs_ok"] == 0
+            assert r.stats["handoffs_failed"] == 0
+            assert r.stats["handoffs_fallback"] == 3
+            assert int(m["bytes"].value) == 0
+            return
         assert inj.frames_corrupted == 1
         assert r.stats["handoffs_failed"] == 1
         assert r.stats["handoffs_ok"] == 2
+        assert r.stats["handoffs_fallback"] == 0
+        assert int(m["bytes"].value) > 0
         evs = [e for h in hs for e in h.trace.events
                if e.kind == "kvwire"]
         assert any(e.data["outcome"] == "crc" for e in evs)
-        m = r._kvwire_metrics()
         assert int(m["frames"].labels("export", "crc").value) == 1
         # the prefill tier's held slot was released despite the
         # corrupt frame (no leaked seats)

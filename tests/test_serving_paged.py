@@ -547,8 +547,7 @@ def test_paged_metrics_published_and_lint_clean(params, mesh1):
 def test_paged_pool_is_smaller_at_equal_capacity(params, mesh1):
     """The capacity lever itself: serving the shared-prefix mix at the
     same slot count, a working-set-sized paged pool holds >= 40% fewer
-    KV bytes than the contiguous pool (ISSUE-7 acceptance, CPU-scale
-    version of the flagship bench assertion)."""
+    KV bytes than the contiguous pool (ISSUE-7 acceptance)."""
     cont = InferenceEngine(CFG, mesh1, params, _contiguous())
     want = [cont.submit(p) for p in _shared_mix()]
     cont.run_pending()

@@ -28,6 +28,7 @@ import pytest
 
 from deeplearning4j_tpu.models.transformer import (TransformerConfig,
                                                    init_params)
+from deeplearning4j_tpu.observability.export import prometheus_text
 from deeplearning4j_tpu.parallel.failure import (FleetFaultInjector,
                                                  hostile_tenant_storm,
                                                  storm_prompt)
@@ -343,17 +344,21 @@ def test_priority_overcommit_reaches_engine_preemption(params, mesh1):
 # hostile-tenant storm: fleet-level zero-lost-high-priority (+ kill)
 # ---------------------------------------------------------------------------
 
-def _run_storm(params, mesh1, arrivals, inj_kwargs):
+def _run_storm(params, mesh1, arrivals, inj_kwargs, qos=True, ttft=None):
+    """Replays `arrivals` through a 2-replica fleet, one router tick a
+    storm tick. `qos` False: no weights, no tenants, no priorities.
+    `ttft`, a dict, gets each arrival's ticks from submit to its first
+    committed token (the clock the fair-share scheduler divides)."""
     inj = FleetFaultInjector(**inj_kwargs)
     router = Router(
         cfg=CFG, mesh=mesh1, params=params, num_replicas=2,
         engine_config=_config(
             max_new_tokens=8, tick_token_budget=16,
-            tenant_weights={"victim": 4.0},
-            preemption_budget=1),
+            **(dict(tenant_weights={"victim": 4.0},
+                    preemption_budget=1) if qos else {})),
         fault_injector=inj,
         config=FleetConfig(restart_backoff_base_s=0.01))
-    handles = {}
+    handles, born = {}, {}
     try:
         pending = sorted(arrivals, key=lambda a: a.tick)
         tick = 0
@@ -363,24 +368,56 @@ def _run_storm(params, mesh1, arrivals, inj_kwargs):
                 handles[a] = router.submit(
                     storm_prompt(a, CFG.vocab_size),
                     max_new_tokens=min(a.max_new_tokens, 8),
-                    tenant=a.tenant, priority=a.priority)
+                    **(dict(tenant=a.tenant, priority=a.priority)
+                       if qos else {}))
+                born[a] = tick
             router.tick()
             tick += 1
+            if ttft is not None:
+                for a, h in handles.items():
+                    if a not in ttft and h.generated.shape[0] > 0:
+                        ttft[a] = tick - born[a]
             if not pending and all(h.done()
                                    for h in handles.values()):
                 break
         assert all(h.done() for h in handles.values())
+        if not qos:
+            assert not any(
+                "qos" in prometheus_text(c.replica.engine.registry)
+                for c in router._ctls)
     finally:
         router.close()
     return handles, inj
 
 
-def test_storm_zero_lost_high_priority(params, mesh1):
-    arrivals, ik = hostile_tenant_storm(
+def _small_storm(**kw):
+    return hostile_tenant_storm(
         ticks=10, hostiles=2, flood_per_tick=1, victim_every=2,
         victim_prompt=8, victim_new=8, hostile_prompt=24,
-        hostile_new=8)
+        hostile_new=8, **kw)
+
+
+@pytest.mark.parametrize("bound", ["zero_lost", "ttft_vs_solo"])
+def test_storm_zero_lost_high_priority(params, mesh1, bound):
+    """The storm's two bounds. `zero_lost`: every high-priority request
+    completes whole. `ttft_vs_solo`: with QoS on, the victim's worst
+    time to first token, in scheduler ticks, stays within 1.25x of what
+    it gets alone on the fleet, where the same storm without QoS makes
+    it wait several times as long."""
+    arrivals, ik = _small_storm()
     assert ik == {}
+    if bound == "ttft_vs_solo":
+        victims = [a for a in arrivals if a.tenant == "victim"]
+        worst = {}
+        for arm, arr, qos in (("solo", victims, False),
+                              ("off", arrivals, False),
+                              ("on", arrivals, True)):
+            ttft = {}
+            _run_storm(params, mesh1, arr, ik, qos=qos, ttft=ttft)
+            worst[arm] = max(ttft[a] for a in victims)
+        assert worst["on"] <= 1.25 * worst["solo"], worst
+        assert worst["off"] > 2 * worst["solo"], worst
+        return
     handles, _ = _run_storm(params, mesh1, arrivals, ik)
     victims = [(a, h) for a, h in handles.items()
                if a.tenant == "victim"]
@@ -393,10 +430,7 @@ def test_storm_zero_lost_high_priority(params, mesh1):
 def test_storm_zero_lost_high_priority_under_kill_one(params, mesh1):
     """Kill a replica mid-storm: failover + preemption together still
     lose ZERO high-priority requests (committed-prefix resume)."""
-    arrivals, ik = hostile_tenant_storm(
-        ticks=10, hostiles=2, flood_per_tick=1, victim_every=2,
-        victim_prompt=8, victim_new=8, hostile_prompt=24,
-        hostile_new=8, kill_tick=5, kill_replica=0)
+    arrivals, ik = _small_storm(kill_tick=5, kill_replica=0)
     assert ik == {"kill_at": {5: 0}}
     handles, inj = _run_storm(params, mesh1, arrivals, ik)
     assert inj.kills_injected == 1
@@ -583,31 +617,46 @@ def test_debugz_tenant_priority_columns(params, mesh1):
 # legacy preservation: QoS off is bit-identical, same compile keys
 # ---------------------------------------------------------------------------
 
-def test_qos_off_bit_identical_no_new_compile_keys(params, mesh1):
+@pytest.mark.parametrize("traffic", ["engine", "storm"])
+def test_qos_off_bit_identical_no_new_compile_keys(params, mesh1,
+                                                   traffic):
     """A QoS-off engine built after the baseline reuses every compiled
     program (zero new cache entries — the cache keys did not move)
     and produces byte-identical tokens; a QoS-ON engine changes
-    scheduling only, so its tokens match too."""
-    ref = _solo(params, mesh1, _prompt(24, 6), 4)
+    scheduling only, so its tokens match too. `storm`: the same of the
+    hostile-tenant storm replayed through the fleet (and, QoS off, no
+    qos series in either replica's scrape)."""
+    if traffic == "engine":
+        def replay(qos):
+            eng = InferenceEngine(
+                CFG, mesh1, params,
+                _config(tick_token_budget=8, preemption_budget=1,
+                        tenant_weights={"gold": 3.0})
+                if qos else _config())
+            h = eng.submit(_prompt(24, 6),
+                           **(dict(tenant="gold", priority=1)
+                              if qos else {}))
+            eng.run_pending()
+            return [h.result(0)]
+        ref = [_solo(params, mesh1, _prompt(24, 6), 4)]
+    else:
+        arrivals, ik = _small_storm()
+
+        def replay(qos):
+            handles, _ = _run_storm(params, mesh1, arrivals, ik, qos=qos)
+            return [handles[a].result(0) for a in arrivals]
+        ref = replay(False)
     with assert_no_recompiles(_compiled_prefill,
                               _compiled_chunked_prefill,
                               _compiled_decode_chunk):
-        eng = InferenceEngine(CFG, mesh1, params, _config())
-        h = eng.submit(_prompt(24, 6))
-        eng.run_pending()
-    np.testing.assert_array_equal(h.result(0), ref)
-
-    qos = InferenceEngine(
-        CFG, mesh1, params,
-        _config(tick_token_budget=8, preemption_budget=1,
-                tenant_weights={"gold": 3.0}))
-    hq = qos.submit(_prompt(24, 6), tenant="gold", priority=1)
-    qos.run_pending()
-    np.testing.assert_array_equal(hq.result(0), ref)
+        got = replay(False)
+    for a, b in zip(ref, got):
+        np.testing.assert_array_equal(a, b)
+    for a, b in zip(ref, replay(True)):
+        np.testing.assert_array_equal(a, b)
 
 
 def test_qos_off_engine_has_no_qos_series(params, mesh1):
-    from deeplearning4j_tpu.observability.export import prometheus_text
     eng = InferenceEngine(CFG, mesh1, params, _config())
     h = eng.submit(_prompt(), tenant="t")
     eng.run_pending()

@@ -1,6 +1,6 @@
 """Cross-layer LSTM wavefront fusion == the sequential per-layer scans
-(nn/layers/recurrent.wavefront_scan_stack; measured 1.14-1.28x on chip,
-benchmarks/lstm_stack_experiment.py). Exactness is the scan-everything
+(nn/layers/recurrent.wavefront_scan_stack; measured 1.14-1.28x on chip
+on an earlier toolchain, BASELINE.md r4). Exactness is the scan-everything
 house rule's proof obligation: same cell math, same states, same final
 carries, through the full MultiLayerNetwork surface."""
 import numpy as np
