@@ -236,30 +236,23 @@ def moe_topk(x: Array, p: Dict[str, Array], cfg, first: int = 0) -> Array:
     n, k, held = b * t, cfg.moe_top_k, cfg.experts_held
     xf = x.reshape(n, d)
     mark("moe.share", held=held, of=cfg.n_experts, top_k=k, tokens=n,
-         buffer_rows=gm.buffer_rows(n * k, held))
-    default_registry().counter(
+         buffer_rows=gm.capacity_rows(n * k, held, cfg.n_experts),
+         worst_rows=gm.buffer_rows(n * k, held),
+         capacity_factor=gm.CAPACITY_FACTOR,
+         overflow_counted=gm.counts_overflow())
+    registry = default_registry()
+    registry.counter(
         "moe_calls", "top-k mixture-of-experts layers traced").inc()
+    registry.counter(
+        "moe_overflow", "executions of a dropless layer's overflow "
+        "path: more live rows than its capacity, every one computed")
     with jax.named_scope("moe.route"):
         router = p["router"]
         if not cfg.train_router:        # frozen: the leaf's gradient only
             router = lax.stop_gradient(router)
         idx, w = route(xf, router, k)
-        plan = gm.plan_groups(idx, first, held)
-    with jax.named_scope("moe.dispatch"):
-        buf = gm.dispatch(xf, plan)
-        w_row = jnp.where(plan.valid, w.reshape(-1)[plan.pair_of], 0.0)
-    with jax.named_scope("moe.experts"):
-        gu = gm.grouped_matmul(buf, p["We_gu"], plan.tile_expert,
-                               plan.n_live)
-        f = gu.shape[-1] // 2
-        mid = jax.nn.silu(gu[:, :f]) * gu[:, f:]
-        out = gm.grouped_matmul(mid, p["We_down"], plan.tile_expert,
-                                plan.n_live)
-    with jax.named_scope("moe.combine"):
-        # rows of tiles that hold nothing were never written: mask before
-        # anything is multiplied into them
-        out = jnp.where(plan.valid[:, None], out, 0)
-        y = gm.combine(out * w_row[:, None].astype(out.dtype), plan)
+        plan = gm.plan_groups(idx, first, held, cfg.n_experts)
+    y = gm.dropless_experts(xf, w, p["We_gu"], p["We_down"], plan)
     with jax.named_scope("moe.shared"):
         gate = jax.nn.sigmoid(jnp.matmul(xf.astype(F32), p["Ws_gate"],
                                          precision=_HI))

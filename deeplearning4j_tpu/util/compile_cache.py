@@ -45,3 +45,12 @@ def enable(min_compile_time_secs: Optional[float] = None) -> str:
                           float(min_compile_time_secs))
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     return path
+
+
+def in_use() -> bool:
+    """Whether a persistent compilation cache is configured, by `enable`
+    or from outside."""
+    import jax
+
+    return bool(jax.config.jax_enable_compilation_cache
+                and jax.config.jax_compilation_cache_dir)

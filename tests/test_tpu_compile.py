@@ -124,7 +124,7 @@ def _kernel_names(text):
     calls = [line.split("=")[0] for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     return {name for name in ("flash_fwd", "flash_bwd", "gdn_fwd", "gdn_bwd",
-                              "moe_gmm_fwd", "moe_gmm_dw")
+                              "moe_gmm_fwd", "moe_gmm_dw", "moe_combine")
             if any(name in c for c in calls)}
 
 
@@ -187,24 +187,44 @@ def test_gated_delta_kernels_compile_for_v5e(one_chip, monkeypatch):
 
 
 def test_grouped_matmul_kernels_compile_for_v5e(one_chip, monkeypatch):
-    """The dropless experts at the cell's size: 32 held experts of
-    2048 x (2 x 512), a buffer for every pair of 24,576 tokens x 10."""
+    """The dropless experts at the cell's size, forward and backward: 32
+    of 512 experts of 2048 x (2 x 512) held, 24,576 tokens x 10. Both
+    branches are in the program: the first chunk of the capacity's
+    39,168 rows (153 row tiles, 249 row blocks by token tile, bfloat16),
+    and one chunk of the overflow path (the same rows, a float32 sum
+    across chunks, an expert with no tile zeroed). The benchmark's
+    `moe_experts_share.train` and `moe_gmm_roofline.train` find the
+    grouped matmuls by `moe_gmm_`; the combine is named apart."""
     import deeplearning4j_tpu.ops.grouped_matmul as gm
     from deeplearning4j_tpu.ops import pallas_util
     monkeypatch.setattr(pallas_util, "off_chip", lambda: False)
-    held, d, f, n, k = 32, 2048, 512, 24576, 10
-    rows = gm.buffer_rows(n * k, held)
+    held, of, d, f, n, k = 32, 512, 2048, 512, 24576, 10
+    rows = gm.capacity_rows(n * k, held, of)
+    chunks = -(-gm.buffer_rows(n * k, held) // rows)
+    assert (rows, chunks) == (39168, 7)
 
     def sd(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    def loss(a, w, te, nl):
-        return jnp.sum(gm.grouped_matmul(a, w, te, nl).astype(
-            jnp.float32) ** 2)
+    plan = gm.GroupPlan(sd((chunks, rows // gm.TILE_M), jnp.int32),
+                        sd((1,), jnp.int32), sd((chunks, rows), jnp.int32),
+                        sd((chunks, rows), jnp.int32),
+                        sd((chunks, rows), jnp.bool_))
+
+    def loss(x, w_gu, w_down, weight, plan):
+        return jnp.sum(gm.dropless_experts(
+            x, weight, w_gu, w_down, plan).astype(jnp.float32) ** 2)
 
     with jax.enable_x64(False):
-        text = jax.jit(jax.grad(loss, (0, 1))).lower(
-            sd((rows, d), jnp.bfloat16), sd((held, d, 2 * f), jnp.float32),
-            sd((rows // gm.TILE_M,), jnp.int32),
-            sd((1,), jnp.int32)).compile().as_text()
-    assert {"moe_gmm_fwd", "moe_gmm_dw"} <= _kernel_names(text)
+        text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+            sd((n, d), jnp.bfloat16), sd((held, d, 2 * f), jnp.float32),
+            sd((held, f, d), jnp.float32), sd((n, k), jnp.float32),
+            plan).compile().as_text()
+    assert {"moe_gmm_fwd", "moe_gmm_dw", "moe_combine"} <= _kernel_names(text)
+    assert " conditional(" in text
+    operands = _kernel_operands(text)
+    # a chunk's rows, its rows by token tile, and nothing of the worst
+    # case's 254,208 rows or of the 245,760 pairs
+    assert (rows, d) in operands and (rows + n, d) in operands
+    assert not [dims for dims in operands
+                if dims[0] in (gm.buffer_rows(n * k, held), n * k)]
