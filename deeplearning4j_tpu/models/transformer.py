@@ -93,16 +93,45 @@ class TransformerConfig:
     # promote back through the usual matmul dtype rules.
     cache_dtype: Optional[str] = None
     # --- a period of unlike layers (models/layer_kinds.py) -------------
-    # the kinds of one period's layers, in order ("deltanet", "full");
-    # () is the one GPT-2 block above. With layer_types the model has
-    # RMSNorm (1 + w), no learned positions, and a top-k mixture of
-    # experts with a gated shared expert in every layer. Training path
+    # the kinds of one period's layers, in order ("deltanet", "full",
+    # "mamba2", "attention"); () is the one GPT-2 block above. With
+    # layer_types the model has RMSNorm (1 + w), no learned positions,
+    # and after every mixer the MLP that `mlp_kind` names. Training path
     # only: the serving engine refuses a config that sets them.
     layer_types: Tuple[str, ...] = ()
+    # True: a period's runs of like layers are stacked (`blocks.r<j>`
+    # leaves `[P, n, ...]`) and scanned, one traced body a run; False:
+    # `blocks.l<i>`, one body a layer
+    stack_runs: bool = False
     n_kv_heads: int = 0             # 0: as many as n_heads
     head_dim: int = 0               # 0: d_model // n_heads
     rotary_fraction: float = 0.0    # part of a head that rotary turns
     rope_theta: float = 10000.0
+    # the attention mixer's parts: a sigmoid output gate beside the query,
+    # RMS norms of q and k over the head, the score scale (0: d_head^-0.5)
+    attn_gate: bool = True
+    qk_norm: bool = True
+    attn_scale: float = 0.0
+    # Mamba-2 (ops/mamba2_ssd.py): heads, their size, the state's size,
+    # groups sharing B and C, the causal convolution's width
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv_width: int = 4
+    # a typed layer's second half: "moe" (top-k experts and a gated shared
+    # expert) or "swiglu" (one dense gated MLP of width dense_d_ff)
+    mlp_kind: str = "moe"
+    dense_d_ff: int = 0
+    # multipliers: the embedding's rows by embed_scale, a mixer's and an
+    # MLP's output by residual_scale before it joins the stream, the
+    # logits divided by logits_scale
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    logits_scale: float = 1.0
+    # True: no Wout; the head is the embedding transposed, whose gradient
+    # is the sum of the gather's and the head's (typed layers only)
+    tie_head: bool = False
     gdn_key_heads: int = 0          # Gated DeltaNet: key / value heads,
     gdn_value_heads: int = 0        # their sizes and the causal
     gdn_key_dim: int = 0            # convolution's width
@@ -299,17 +328,39 @@ def forward_hidden(cfg: TransformerConfig, params: Dict[str, Any],
     return layer_norm(h, params["lnfg"], params["lnfb"], cfg.eps)
 
 
+def head_matrix(cfg: TransformerConfig, params: Dict[str, Any]) -> Array:
+    """The output projection `[D, V]`: `Wout`, or with `cfg.tie_head` the
+    embedding transposed."""
+    return params["embed"].T if cfg.tie_head else params["Wout"]
+
+
+def scaled_logits(cfg: TransformerConfig, logits: Array) -> Array:
+    """float32 logits over `cfg.logits_scale`."""
+    logits = logits.astype(jnp.float32)
+    if cfg.logits_scale != 1.0:
+        logits = logits / cfg.logits_scale
+    return logits
+
+
+def embed_tokens(cfg: TransformerConfig, params: Dict[str, Any],
+                 tokens: Array) -> Array:
+    h = params["embed"].astype(cfg.activation_dtype())[tokens]
+    if cfg.embed_scale != 1.0:
+        h = h * cfg.embed_scale
+    return h
+
+
 def _forward_hidden_typed(cfg: TransformerConfig, params: Dict[str, Any],
                           tokens: Array) -> Array:
     """forward_hidden for a config with `layer_types`: no positions are
-    added (the full layers rotate, the DeltaNet layers recur), the scan is
-    over periods, remat keeps a layer's input (the one policy)."""
+    added (the full layers rotate or go without, the others recur), the
+    scan is over periods, remat keeps a layer's input (the one policy)."""
     from deeplearning4j_tpu.models import layer_kinds
     if cfg.remat and cfg.remat_policy != "full":
         raise ValueError(f"remat_policy {cfg.remat_policy!r} with "
                          "layer_types: only 'full' is there")
     with jax.named_scope("embed"):
-        h = params["embed"].astype(cfg.activation_dtype())[tokens]
+        h = embed_tokens(cfg, params, tokens)
     h = layer_kinds.periods_forward(h, params["blocks"], cfg)
     return layer_kinds.rms_norm(h, params["lnfg"], cfg.eps)
 
@@ -318,7 +369,10 @@ def forward(cfg: TransformerConfig, params: Dict[str, Any],
             tokens: Array) -> Array:
     """tokens [B, T] int32 -> logits [B, T, V]."""
     h = forward_hidden(cfg, params, tokens)
-    return jnp.matmul(h, params["Wout"].astype(h.dtype))
+    logits = jnp.matmul(h, head_matrix(cfg, params).astype(h.dtype))
+    if cfg.logits_scale != 1.0:
+        logits = scaled_logits(cfg, logits).astype(h.dtype)
+    return logits
 
 
 # ---------------------------------------------------------------------------
@@ -654,15 +708,22 @@ def chunked_cross_entropy(h: Array, wout: Array, targets: Array,
     return jnp.mean(m + jnp.log(s) - tl)
 
 
+def scaled_hidden(cfg: TransformerConfig, h: Array) -> Array:
+    """The chunked loss never holds the logits, so there the final
+    hidden state is divided by `cfg.logits_scale` instead."""
+    return h if cfg.logits_scale == 1.0 else h / cfg.logits_scale
+
+
 def loss_fn(cfg: TransformerConfig, params: Dict[str, Any], tokens: Array,
             targets: Array) -> Array:
     h = forward_hidden(cfg, params, tokens)
     with jax.named_scope("head_loss"):
+        wout = head_matrix(cfg, params)
         if cfg.xent_chunk > 0 and cfg.vocab_size > cfg.xent_chunk:
-            return chunked_cross_entropy(h, params["Wout"], targets,
-                                         cfg.xent_chunk)
-        logits = jnp.matmul(h, params["Wout"].astype(h.dtype))
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+            return chunked_cross_entropy(scaled_hidden(cfg, h), wout,
+                                         targets, cfg.xent_chunk)
+        logits = jnp.matmul(h, wout.astype(h.dtype))
+        logp = jax.nn.log_softmax(scaled_logits(cfg, logits), axis=-1)
         nll = -jnp.take_along_axis(
             logp, targets[..., None].astype(jnp.int32), axis=-1)[..., 0]
         return jnp.mean(nll)

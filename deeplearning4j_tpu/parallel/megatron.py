@@ -40,7 +40,8 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from deeplearning4j_tpu.models.transformer import (
-    TransformerConfig, chunked_cross_entropy)
+    TransformerConfig, chunked_cross_entropy, embed_tokens, head_matrix,
+    scaled_hidden, scaled_logits)
 from deeplearning4j_tpu.nn.layers.attention import layer_norm
 from deeplearning4j_tpu.parallel.optim import (AdamState,  # noqa: F401
                                                adam_update_tree,
@@ -102,11 +103,14 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
         # axis 'pipe' shards; nothing else of them is divided (the held
         # experts are this rank's share already)
         from deeplearning4j_tpu.models import layer_kinds
-        return {"embed": P(), "lnfg": P(), "Wout": P(), "blocks": {
-            f"l{i}": {name: P("pipe", *([None] * len(shape)))
-                      for name, shape in
-                      layer_kinds.layer_shapes(cfg, kind).items()}
-            for i, kind in enumerate(cfg.layer_types)}}
+        out = {"embed": P(), "lnfg": P(), "blocks": {
+            key: {name: P("pipe", *([None] * (len(shape) + len(lead))))
+                  for name, shape in
+                  layer_kinds.layer_shapes(cfg, kind).items()}
+            for key, kind, lead in layer_kinds.block_keys(cfg)}}
+        if not cfg.tie_head:
+            out["Wout"] = P()
+        return out
     blocks: Dict[str, P] = {
         "Wq": P("pipe", None, "model"), "Wk": P("pipe", None, "model"),
         "Wv": P("pipe", None, "model"), "Wo": P("pipe", "model", None),
@@ -469,10 +473,10 @@ def make_parallel_train_step(cfg: TransformerConfig, mesh: Mesh, *,
         from deeplearning4j_tpu.models import layer_kinds
         if tp > 1 or sp > 1:
             raise ValueError(
-                "TransformerConfig.layer_types: the typed layers (Gated "
-                "DeltaNet, gated grouped-query attention, top-k MoE) are "
-                "not divided over the 'model' or 'seq' axes; use data "
-                "and pipe")
+                f"TransformerConfig.layer_types={cfg.layer_types}: the "
+                "typed layers (Gated DeltaNet, Mamba-2, grouped-query "
+                "attention, top-k MoE, dense SwiGLU) are not divided over "
+                "the 'model' or 'seq' axes; use data and pipe")
         if pipeline_schedule == "1f1b" and s > 1:
             raise ValueError("TransformerConfig.layer_types: the 1f1b "
                              "schedule is not there for typed layers")
@@ -509,7 +513,7 @@ def make_parallel_train_step(cfg: TransformerConfig, mesh: Mesh, *,
         b_loc, tl = tokens_loc.shape
         seq_idx = lax.axis_index("seq").astype(jnp.int32)
         with jax.named_scope("embed"):
-            h = params["embed"].astype(dt)[tokens_loc]
+            h = embed_tokens(cfg, params, tokens_loc)
             if not cfg.layer_types:     # typed layers add no positions
                 pos = lax.dynamic_slice(params["pos"],
                                         (seq_idx * tl, jnp.int32(0)),
@@ -529,17 +533,18 @@ def make_parallel_train_step(cfg: TransformerConfig, mesh: Mesh, *,
                 hf = rms_norm(hf, params["lnfg"], cfg.eps)
             else:
                 hf = layer_norm(hf, params["lnfg"], params["lnfb"], cfg.eps)
+            wout = head_matrix(cfg, params)
             if cfg.xent_chunk > 0 and cfg.vocab_size > cfg.xent_chunk:
                 # streaming vocab-panel loss on the LOCAL tokens (Wout
                 # is replicated; each shard scans its own panels) — the
                 # same real-vocab memory wall the single-chip loss_fn
                 # dodges, models/transformer.chunked_cross_entropy
                 local_sum = chunked_cross_entropy(
-                    hf, params["Wout"], targets_loc,
+                    scaled_hidden(cfg, hf), wout, targets_loc,
                     cfg.xent_chunk) * (b_loc * tl)
             else:
-                logits = jnp.matmul(hf, params["Wout"].astype(hf.dtype))
-                logits = logits.astype(jnp.float32)
+                logits = jnp.matmul(hf, wout.astype(hf.dtype))
+                logits = scaled_logits(cfg, logits)
                 logp = jax.nn.log_softmax(logits, axis=-1)
                 nll = -jnp.take_along_axis(
                     logp, targets_loc[..., None].astype(jnp.int32),

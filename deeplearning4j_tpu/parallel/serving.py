@@ -419,8 +419,9 @@ def _refuse_unserved(cfg: TransformerConfig) -> None:
     if cfg.layer_types:
         raise ValueError(
             f"TransformerConfig.layer_types={cfg.layer_types}: the serving "
-            "engine has no recurrent state beside its paged K/V cache and "
-            "cannot serve typed layers; they run on the training path only")
+            "engine has no recurrent state (a Gated DeltaNet's or a "
+            "Mamba-2 layer's) beside its paged K/V cache and cannot serve "
+            "typed layers; they run on the training path only")
     if cfg.n_kv_heads and cfg.n_kv_heads != cfg.n_heads:
         raise ValueError(
             f"TransformerConfig.n_kv_heads={cfg.n_kv_heads} with n_heads="

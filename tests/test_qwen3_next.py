@@ -88,7 +88,7 @@ MM = ref._mm_fn("f32")
 PIECES = {
     "deltanet": (lambda x, p, s, cfg: lk.gated_deltanet(x, p, cfg),
                  lambda x, p, s, cfg: ref.gated_deltanet(x, p, s, MM)),
-    "full": (lambda x, p, s, cfg: lk.gated_attention(x, p, cfg),
+    "full": (lambda x, p, s, cfg: lk.grouped_query_attention(x, p, cfg),
              lambda x, p, s, cfg: ref.gated_attention(x, p, s, MM)),
     "moe": (lambda x, p, s, cfg: lk.moe_topk(x, p, cfg),
             lambda x, p, s, cfg: ref.moe(x, p, s, MM)),

@@ -124,7 +124,8 @@ def _kernel_names(text):
     calls = [line.split("=")[0] for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     return {name for name in ("flash_fwd", "flash_bwd", "gdn_fwd", "gdn_bwd",
-                              "moe_gmm_fwd", "moe_gmm_dw", "moe_combine")
+                              "moe_gmm_fwd", "moe_gmm_dw", "moe_combine",
+                              "ssd_fwd", "ssd_bwd")
             if any(name in c for c in calls)}
 
 
@@ -184,6 +185,70 @@ def test_gated_delta_kernels_compile_for_v5e(one_chip, monkeypatch):
             if sp.name == "gdn.layout"][-1]
     assert last.args == {"chunk": 64, "heads": hv, "block": 512,
                          "operands": "bfloat16"}
+
+
+def test_state_space_dual_kernels_compile_for_v5e(one_chip, monkeypatch):
+    """Mamba-2's chunked scan at Granite's size (64 heads of 64, one group
+    of B and C, state 128, 1 row of 8192), forward and the written-out
+    backward, on the bfloat16 operands the layer hands them: `[B, T, 64 x
+    64]` as the block leaves it, two heads a 128-lane group, nothing the
+    kernels move with a minor dimension of 64 or of 1. The benchmark's
+    `ssd_scan_*` metrics find the two kernels by their names."""
+    import deeplearning4j_tpu.ops.mamba2_ssd as ssd
+    from deeplearning4j_tpu.observability.tracing import default_spans
+    from deeplearning4j_tpu.ops import pallas_util
+    monkeypatch.setattr(pallas_util, "off_chip", lambda: False)
+    b, t, h, p, n = 1, 8192, 64, 64, 128
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (sd((b, t, h, p), jnp.bfloat16), sd((b, t, 1, n), jnp.bfloat16),
+            sd((b, t, 1, n), jnp.bfloat16), sd((b, t, h), jnp.float32),
+            sd((b, t, h), jnp.float32))
+
+    def loss(*a):
+        return jnp.sum(ssd.ssd_scan(*a).astype(jnp.float32) ** 2)
+
+    with jax.enable_x64(False):
+        text = jax.jit(jax.grad(loss, (0, 1, 2, 3, 4))).lower(
+            *args).compile().as_text()
+    assert _kernel_names(text) == {"ssd_fwd", "ssd_bwd"}
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    operands = _kernel_operands(text)
+    assert (b, t, h * p) in operands and (b, h, t // 128, 128) in operands
+    for dims in operands:
+        assert dims[-1] % 128 == 0, operands
+    last = [sp for sp in default_spans().snapshot().spans
+            if sp.name == "ssd.layout"][-1]
+    assert last.args == {"chunk": 128, "heads": h, "head_dim": p, "state": n,
+                         "groups": 1, "block": 1024, "operands": "bfloat16"}
+
+
+def test_granites_attention_compiles_per_head_for_v5e(one_chip):
+    """Granite's attention layer at the cell's size: 32 query heads on 8
+    KV heads of 64 at T 8192 with the scale 1/64. A row of 8192 is past
+    the lane-dense form's one superblock whatever the group, so it runs one
+    head an entry of `[B*H, T, 64]` (PERF.md says what that costs), a KV
+    head's block shared by its 4 query heads and never repeated."""
+    b, t, h, hk, d = 1, 8192, 32, 8, 64
+    assert not fa._lane_dense_width(h, d, t, t)
+    q = jax.ShapeDtypeStruct((b, t, h, d), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b, t, hk, d), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention(
+            q, k, v, causal=True, scale=1.0 / 64).astype(jnp.float32) ** 2)
+
+    with jax.enable_x64(False):
+        text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+            q, kv, kv).compile().as_text()
+    assert {"flash_fwd", "flash_bwd"} <= _kernel_names(text)
+    operands = _kernel_operands(text)
+    assert (b * h, t, d) not in operands or (b * hk, t, d) in operands
+    assert not [dims for dims in operands if dims == (b * h, t, d)
+                and (b * hk, t, d) not in operands]
 
 
 def test_grouped_matmul_kernels_compile_for_v5e(one_chip, monkeypatch):
