@@ -193,14 +193,20 @@ def _delta_loss_and_grads(q, k, v, g, beta, co):
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("t", [50, 64, 200])
-def test_chunked_delta_rule_matches_the_recurrence(t, dtype):
-    """T not a multiple of the chunk (64), and one that is. float32
-    operands to rounding; bfloat16 q, k, v against the recurrence run in
-    float32 on the same values, as far as the bfloat16 outputs (o, dq,
-    dk, dv: a rounding a head, a sum, a rounding) allow. The gates'
-    gradients are float32 either way."""
-    b, hk, hv, dk, dv = 2, 2, 4, 16, 32
+@pytest.mark.parametrize("t,ratio", [(50, 2), (64, 2), (200, 2), (1100, 2),
+                                     (200, 1), (600, 3), (200, 4)])
+def test_chunked_delta_rule_matches_the_recurrence(t, ratio, dtype):
+    """T not a multiple of the chunk (64), one that is, and two that span
+    more than one block (512) so the state crosses programs; two value
+    heads a key head (a program is the pair), four (two programs a key
+    head, whose `dq` and `dk` add up outside the kernel), one and three (a
+    program is one head). float32 operands to rounding; bfloat16 q, k, v
+    against the recurrence run in float32 on the same values, as far as
+    the bfloat16 outputs (o, dq, dk, dv: a sum over a program's heads, a
+    rounding, a sum, a rounding) allow. The gates' gradients are float32
+    either way."""
+    b, hk, dk, dv = 2, 2, 16, 32
+    hv = hk * ratio
     q, k, v, g, beta, co = _delta_inputs(t, b, t, hk, hv, dk, dv, dtype)
     f32 = [x.astype(jnp.float32) for x in (q, k, v, g, beta, co)]
 
@@ -249,33 +255,111 @@ def _one_chunk(seed, dtype):
     return (q, k, v, gam, beta, s0), (do, ds1)
 
 
+def _chunk_panels(q, k, gam, beta):
+    """[k; q] and the state-free panels of one chunk of one head, as a
+    kernel program forms them."""
+    from deeplearning4j_tpu.ops import gated_delta as gd
+    kq = jnp.concatenate([k, q], axis=0)
+    w, = gd._lockstep([gd._panels(gd._dot(kq, k, gd._NT), gam, beta)])
+    return kq, w
+
+
+def _chunk_fwd(q, k, v, gam, beta, s0):
+    """(o [C, dv], s1) of one chunk through the kernels' generators."""
+    from deeplearning4j_tpu.ops import gated_delta as gd
+    kq, w = _chunk_panels(q, k, gam, beta)
+    return gd._lockstep([gd._fwd_rest(w, kq, v, s0)])[0]
+
+
+def _chunk_bwd(q, k, v, gam, beta, s0, do, ds1):
+    """(dq, dk, dv, dgam, dbeta, ds0) of one chunk through them."""
+    from deeplearning4j_tpu.ops import gated_delta as gd
+    kq, w = _chunk_panels(q, k, gam, beta)
+    g, = gd._lockstep([gd._bwd_rest(w, kq, v, s0, do, ds1)])
+    return gd._dq_dk(kq, [g]) + (g.dv, g.dgam, g.dbeta, g.ds0)
+
+
 def test_the_written_out_chunk_backward_is_the_plain_chunks_vjp():
     """One chunk, a state before it and a cotangent of the state after
-    it: `_chunk_fwd` and `_chunk_bwd` against `_chunk` and `jax.vjp` of
-    it, the gates' gradients among them."""
+    it: the kernels' generators (`_panels`, `_fwd_rest`, `_bwd_rest`)
+    against `_chunk` and `jax.vjp` of it, the gates' gradients among
+    them."""
     from deeplearning4j_tpu.ops import gated_delta as gd
     args, cot = _one_chunk(3, "float32")
     with jax.default_matmul_precision("highest"):
         want, pull = jax.vjp(gd._chunk, *args)
-        for a, c in zip(gd._chunk_fwd(*args), want):
+        for a, c in zip(_chunk_fwd(*args), want):
             close(a, c, 2e-6)
-        for a, c in zip(gd._chunk_bwd(*args, *cot), pull(cot)):
+        for a, c in zip(_chunk_bwd(*args, *cot), pull(cot)):
             close(a, c, 1e-5)
 
 
 def test_a_chunk_in_bfloat16_operands_is_the_chunk_in_float32():
     """The same on one chunk, where every output is float32: o, the
     state and all six gradients to 1e-6."""
-    from deeplearning4j_tpu.ops import gated_delta as gd
     (narrow, ncot), (wide, wcot) = (_one_chunk(5, d)
                                     for d in ("bfloat16", "float32"))
     assert narrow[0].dtype == jnp.bfloat16 and wide[0].dtype == jnp.float32
     with jax.default_matmul_precision("highest"):
-        for a, c in zip(gd._chunk_fwd(*narrow), gd._chunk_fwd(*wide)):
+        for a, c in zip(_chunk_fwd(*narrow), _chunk_fwd(*wide)):
             agree(a, c, 1e-6)
-        for a, c in zip(gd._chunk_bwd(*narrow, *ncot),
-                        gd._chunk_bwd(*wide, *wcot)):
+        for a, c in zip(_chunk_bwd(*narrow, *ncot),
+                        _chunk_bwd(*wide, *wcot)):
             agree(a, c, 1e-6)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_merged_inverse_is_the_plain_chunks(dtype):
+    """`_panels`' inverse, six rounds of `p [p | sum]` with the pieces of
+    a split side by side along the contraction, against `_chunk`'s
+    `p = p p; inv += inv p`: float32 operands to 2e-6, and bfloat16
+    operands against float32 ones of the same values to 1e-6."""
+    from deeplearning4j_tpu.ops import gated_delta as gd
+    (q, k, _, gam, beta, _), _ = _one_chunk(9, dtype)
+    c = q.shape[0]
+    with jax.default_matmul_precision("highest"):
+        _, w = _chunk_panels(q, k, gam, beta)
+        _, wide = _chunk_panels(q.astype(jnp.float32),
+                                k.astype(jnp.float32), gam, beta)
+        a = jnp.tril(beta.T * jnp.exp(jnp.tril(gam.T - gam))
+                     * gd._dot(k, k, gd._NT), -1)
+        p = -a
+        inv = jnp.eye(c) + p
+        for _ in range(5):
+            p = gd._dot(p, p, gd._NN)
+            inv = inv + gd._dot(inv, p, gd._NN)
+    close(w.inv, inv, 2e-6)
+    agree(w.inv, wide.inv, 1e-6)
+    # and it is the inverse
+    close(np.asarray(w.inv, np.float64) @ (np.eye(c) + np.asarray(a)),
+          np.eye(c), 2e-6)
+
+
+@pytest.mark.parametrize("ratio,heads_per_program", [(2, 2), (4, 2), (1, 1),
+                                                     (3, 1)])
+def test_a_traced_call_says_how_many_heads_a_program_took(
+        ratio, heads_per_program):
+    """The mark `gdn.layout` and the counter `gdn_calls` carry the form a
+    call took: a key head's value heads in pairs where they pair up, else
+    one a program."""
+    from deeplearning4j_tpu.observability.metrics import default_registry
+    from deeplearning4j_tpu.observability.tracing import default_spans
+    calls = default_registry().counter(
+        "gdn_calls", "", labelnames=("pass", "operands",
+                                     "heads_per_program"))
+    keys = [(w, "bfloat16", str(heads_per_program))
+            for w in ("forward", "backward")]
+    before = [calls.labels(*key).value for key in keys]
+    _delta_loss_and_grads(*_delta_inputs(1, 1, 40, 1, ratio, 16, 16,
+                                         "bfloat16"))
+    assert [calls.labels(*key).value - v
+            for key, v in zip(keys, before)] == [1, 1]
+    last = [sp for sp in default_spans().snapshot().spans
+            if sp.name == "gdn.layout"][-1]
+    assert last.args == {"chunk": 64, "heads": ratio, "block": 64,
+                         "operands": "bfloat16",
+                         "heads_per_program": heads_per_program,
+                         "inverse": "phased"}
 
 
 def test_grouped_query_flash_kernel_matches_plain_attention(monkeypatch):

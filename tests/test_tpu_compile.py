@@ -158,8 +158,9 @@ def test_grouped_query_kernels_compile_for_v5e(one_chip):
 def test_gated_delta_kernels_compile_for_v5e(one_chip, monkeypatch):
     """The chunked gated delta rule at the cell's size (16 key and 32 value
     heads of 128, 3 rows of 8192), forward and the written-out backward,
-    on the bfloat16 operands the layer hands them. The benchmark's
-    `gdn_scan_*` metrics find the two kernels by their names."""
+    on the bfloat16 operands the layer hands them, a key head's two value
+    heads a program. The benchmark's `gdn_scan_*` metrics find the two
+    kernels by their names."""
     import deeplearning4j_tpu.ops.gated_delta as gd
     from deeplearning4j_tpu.observability.tracing import default_spans
     from deeplearning4j_tpu.ops import pallas_util
@@ -184,7 +185,8 @@ def test_gated_delta_kernels_compile_for_v5e(one_chip, monkeypatch):
     last = [sp for sp in default_spans().snapshot().spans
             if sp.name == "gdn.layout"][-1]
     assert last.args == {"chunk": 64, "heads": hv, "block": 512,
-                         "operands": "bfloat16"}
+                         "operands": "bfloat16", "heads_per_program": 2,
+                         "inverse": "phased"}
 
 
 def test_state_space_dual_kernels_compile_for_v5e(one_chip, monkeypatch):
