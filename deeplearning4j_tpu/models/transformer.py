@@ -94,7 +94,7 @@ class TransformerConfig:
     cache_dtype: Optional[str] = None
     # --- a period of unlike layers (models/layer_kinds.py) -------------
     # the kinds of one period's layers, in order ("deltanet", "full",
-    # "mamba2", "attention"); () is the one GPT-2 block above. With
+    # "mamba2", "attention", "mla"); () is the one GPT-2 block above. With
     # layer_types the model has RMSNorm (1 + w), no learned positions,
     # and after every mixer the MLP that `mlp_kind` names. Training path
     # only: the serving engine refuses a config that sets them.
@@ -148,6 +148,30 @@ class TransformerConfig:
     # The hidden state still gets its gradient through the routing
     # weights: the mathematics of every other leaf is whole
     train_router: bool = True
+    # how the router scores: "softmax" (over all experts, then the top_k)
+    # or "sigmoid" (an expert's score its own; a bias a layer, a buffer
+    # that no gradient reaches, enters the choice and not the weight);
+    # the chosen experts' normalised weights times routed_scale; False:
+    # the shared expert joins ungated (no Ws_gate)
+    router_scoring: str = "softmax"
+    routed_scale: float = 1.0
+    shared_gate: bool = True
+    # latent attention ("mla"): queries and keys/values through low-rank
+    # latents with an RMSNorm each; a head's key is its own qk_nope_dim
+    # and qk_rope_dim rotary dimensions that all heads share
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    # layers before the first period, counted in n_layers, outside the
+    # scan: the period's first mixer and a dense SwiGLU of dense_d_ff
+    lead_dense_layers: int = 0
+    # multi-token prediction: one further layer fed by the final hidden
+    # state and the next token's embedding; its loss, through the same
+    # embedding and head, joins the main one times mtp_loss_weight
+    mtp_layers: int = 0
+    mtp_loss_weight: float = 0.0
 
     @property
     def d_head(self) -> int:
@@ -325,6 +349,16 @@ def forward_hidden(cfg: TransformerConfig, params: Dict[str, Any],
                if cfg.remat_policy == "dots" else None)
         body = jax.checkpoint(body, prevent_cse=False, policy=pol)
     h, _ = lax.scan(body, h, params["blocks"])
+    return final_norm(cfg, params, h)
+
+
+def final_norm(cfg: TransformerConfig, params: Dict[str, Any],
+               h: Array) -> Array:
+    """The norm before the head: RMSNorm `(1 + w)` with `layer_types`,
+    else the GPT-2 block's LayerNorm."""
+    if cfg.layer_types:
+        from deeplearning4j_tpu.models.layer_kinds import rms_norm
+        return rms_norm(h, params["lnfg"], cfg.eps)
     return layer_norm(h, params["lnfg"], params["lnfb"], cfg.eps)
 
 
@@ -361,8 +395,10 @@ def _forward_hidden_typed(cfg: TransformerConfig, params: Dict[str, Any],
                          "layer_types: only 'full' is there")
     with jax.named_scope("embed"):
         h = embed_tokens(cfg, params, tokens)
+    if cfg.lead_dense_layers:
+        h = layer_kinds.lead_forward(h, params["lead"], cfg)
     h = layer_kinds.periods_forward(h, params["blocks"], cfg)
-    return layer_kinds.rms_norm(h, params["lnfg"], cfg.eps)
+    return final_norm(cfg, params, h)
 
 
 def forward(cfg: TransformerConfig, params: Dict[str, Any],
@@ -714,19 +750,51 @@ def scaled_hidden(cfg: TransformerConfig, h: Array) -> Array:
     return h if cfg.logits_scale == 1.0 else h / cfg.logits_scale
 
 
+def nll_sum(cfg: TransformerConfig, wout: Array, hf: Array,
+            targets: Array) -> Array:
+    """Summed negative log-likelihood of `targets` [B, T] under the head
+    `wout` [D, V] on normed hidden states `hf` [B, T, D]: the dense
+    float32 logits, or with `cfg.xent_chunk` the streaming vocab-panel
+    scan (`chunked_cross_entropy`), which never holds them."""
+    if cfg.xent_chunk > 0 and cfg.vocab_size > cfg.xent_chunk:
+        return chunked_cross_entropy(scaled_hidden(cfg, hf), wout, targets,
+                                     cfg.xent_chunk) * targets.size
+    logits = jnp.matmul(hf, wout.astype(hf.dtype))
+    logp = jax.nn.log_softmax(scaled_logits(cfg, logits), axis=-1)
+    return -jnp.sum(jnp.take_along_axis(
+        logp, targets[..., None].astype(jnp.int32), axis=-1))
+
+
+def head_loss_sum(cfg: TransformerConfig, params: Dict[str, Any],
+                  hf: Array, targets: Array) -> Array:
+    """What a block of rows adds to the loss's sum, from its final-normed
+    hidden states `hf` [B, T, D]: the loss is this over every row's
+    B x T. The single-device `loss_fn` and the parallel step's
+    (parallel/megatron.py) both are this. With `cfg.mtp_layers` the
+    multi-token-prediction module's term is in it: position i, fed
+    ``hf[i]`` and the embedding of ``targets[i]`` (token i + 1), predicts
+    ``targets[i + 1]`` through the same embedding and head; a row's last
+    position has no such term, and the mean over the T - 1 that have
+    joins times `cfg.mtp_loss_weight`."""
+    wout = head_matrix(cfg, params)
+    with jax.named_scope("head_loss"):
+        total = nll_sum(cfg, wout, hf, targets)
+    if cfg.mtp_layers:
+        from deeplearning4j_tpu.models.layer_kinds import mtp_hidden
+        t = targets.shape[1]
+        with jax.named_scope("mtp"):
+            u = mtp_hidden(hf, embed_tokens(cfg, params, targets),
+                           params["mtp"], cfg)
+        with jax.named_scope("mtp.head_loss"):
+            total = total + (cfg.mtp_loss_weight * t / (t - 1)) * nll_sum(
+                cfg, wout, u[:, :-1], targets[:, 1:])
+    return total
+
+
 def loss_fn(cfg: TransformerConfig, params: Dict[str, Any], tokens: Array,
             targets: Array) -> Array:
     h = forward_hidden(cfg, params, tokens)
-    with jax.named_scope("head_loss"):
-        wout = head_matrix(cfg, params)
-        if cfg.xent_chunk > 0 and cfg.vocab_size > cfg.xent_chunk:
-            return chunked_cross_entropy(scaled_hidden(cfg, h), wout,
-                                         targets, cfg.xent_chunk)
-        logits = jnp.matmul(h, wout.astype(h.dtype))
-        logp = jax.nn.log_softmax(scaled_logits(cfg, logits), axis=-1)
-        nll = -jnp.take_along_axis(
-            logp, targets[..., None].astype(jnp.int32), axis=-1)[..., 0]
-        return jnp.mean(nll)
+    return head_loss_sum(cfg, params, h, targets) / targets.size
 
 
 class TransformerLM:

@@ -40,8 +40,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from deeplearning4j_tpu.models.transformer import (
-    TransformerConfig, chunked_cross_entropy, embed_tokens, head_matrix,
-    scaled_hidden, scaled_logits)
+    TransformerConfig, embed_tokens, final_norm, head_loss_sum, nll_sum)
 from deeplearning4j_tpu.nn.layers.attention import layer_norm
 from deeplearning4j_tpu.parallel.optim import (AdamState,  # noqa: F401
                                                adam_update_tree,
@@ -110,6 +109,14 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
             for key, kind, lead in layer_kinds.block_keys(cfg)}}
         if not cfg.tie_head:
             out["Wout"] = P()
+        # the leading layers and the MTP module are whole on every rank
+        # (`make_parallel_train_step` refuses them a 'pipe' axis)
+        whole = lambda shapes: jax.tree_util.tree_map(  # noqa: E731
+            lambda _: P(), shapes, is_leaf=lambda x: isinstance(x, tuple))
+        if cfg.lead_dense_layers:
+            out["lead"] = whole(layer_kinds.lead_shapes(cfg))
+        if cfg.mtp_layers:
+            out["mtp"] = whole(layer_kinds.mtp_shapes(cfg))
         return out
     blocks: Dict[str, P] = {
         "Wq": P("pipe", None, "model"), "Wk": P("pipe", None, "model"),
@@ -364,13 +371,7 @@ def _value_and_grad_1f1b(params, tokens_loc, targets_loc,
 
     def head_loss_sum(hp, y, tgt):
         hf = layer_norm(y, hp["lnfg"], hp["lnfb"], cfg.eps)
-        if cfg.xent_chunk > 0 and cfg.vocab_size > cfg.xent_chunk:
-            return chunked_cross_entropy(hf, hp["Wout"], tgt,
-                                         cfg.xent_chunk) * tgt.size
-        logits = jnp.matmul(hf, hp["Wout"].astype(hf.dtype))
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-        return jnp.sum(-jnp.take_along_axis(
-            logp, tgt[..., None].astype(jnp.int32), axis=-1)[..., 0])
+        return nll_sum(cfg, hp["Wout"], hf, tgt)
 
     n_slots = 2 * s          # 2S-1 live ring slots + 1 trash slot
     perm_fwd = [(j, j + 1) for j in range(s - 1)]
@@ -483,6 +484,13 @@ def make_parallel_train_step(cfg: TransformerConfig, mesh: Mesh, *,
         if layer_kinds.n_periods(cfg) % s:
             raise ValueError("whole periods of layer_types must divide "
                              "by pipe size")
+        if s > 1 and (cfg.lead_dense_layers or cfg.mtp_layers):
+            raise ValueError(
+                f"TransformerConfig.lead_dense_layers="
+                f"{cfg.lead_dense_layers}, mtp_layers={cfg.mtp_layers}: "
+                "layers outside the periods (the leading dense layers, the "
+                "multi-token-prediction module) are not placed on a stage "
+                "of the 'pipe' axis; use pipe=1")
     else:
         if cfg.n_layers % s:
             raise ValueError("n_layers must divide by pipe size")
@@ -519,6 +527,9 @@ def make_parallel_train_step(cfg: TransformerConfig, mesh: Mesh, *,
                                         (seq_idx * tl, jnp.int32(0)),
                                         (tl, cfg.d_model))
                 h = h + pos.astype(dt)[None]
+        if cfg.lead_dense_layers:
+            from deeplearning4j_tpu.models.layer_kinds import lead_forward
+            h = lead_forward(h, params["lead"], cfg)
         # microbatch split for the pipeline
         if b_loc % m_:
             raise ValueError(f"local batch {b_loc} not divisible by "
@@ -527,29 +538,11 @@ def make_parallel_train_step(cfg: TransformerConfig, mesh: Mesh, *,
         h_mb = h.reshape(m_, mb, tl, cfg.d_model)
         out = _pipeline_apply(params["blocks"], h_mb, cfg, mesh)
         hf = out.reshape(b_loc, tl, cfg.d_model)
+        # the head and the loss on the LOCAL tokens (the head is
+        # replicated; with xent_chunk each shard scans its own panels)
         with jax.named_scope("head_loss"):
-            if cfg.layer_types:
-                from deeplearning4j_tpu.models.layer_kinds import rms_norm
-                hf = rms_norm(hf, params["lnfg"], cfg.eps)
-            else:
-                hf = layer_norm(hf, params["lnfg"], params["lnfb"], cfg.eps)
-            wout = head_matrix(cfg, params)
-            if cfg.xent_chunk > 0 and cfg.vocab_size > cfg.xent_chunk:
-                # streaming vocab-panel loss on the LOCAL tokens (Wout
-                # is replicated; each shard scans its own panels) — the
-                # same real-vocab memory wall the single-chip loss_fn
-                # dodges, models/transformer.chunked_cross_entropy
-                local_sum = chunked_cross_entropy(
-                    scaled_hidden(cfg, hf), wout, targets_loc,
-                    cfg.xent_chunk) * (b_loc * tl)
-            else:
-                logits = jnp.matmul(hf, wout.astype(hf.dtype))
-                logits = scaled_logits(cfg, logits)
-                logp = jax.nn.log_softmax(logits, axis=-1)
-                nll = -jnp.take_along_axis(
-                    logp, targets_loc[..., None].astype(jnp.int32),
-                    axis=-1)[..., 0]
-                local_sum = jnp.sum(nll)
+            hf = final_norm(cfg, params, hf)
+        local_sum = head_loss_sum(cfg, params, hf, targets_loc)
         if s > 1:
             is_last = (lax.axis_index("pipe") == s - 1)
             local_sum = jnp.where(is_last, local_sum, 0.0)

@@ -413,15 +413,25 @@ def _resolve_quant(quantized, kv_mode):
 def _refuse_unserved(cfg: TransformerConfig) -> None:
     """The serving programs hold one kind of state, a K/V cache of
     `n_heads` heads a layer. A config with layer kinds (recurrent state
-    beside the cache) or fewer KV heads than query heads trains
+    beside the cache, a latent in its place, layers outside the scanned
+    stack) or fewer KV heads than query heads trains
     (models/layer_kinds.py) and is not served: refused here, where every
     `make_*` and `_check_spec` pass, by the field's name."""
     if cfg.layer_types:
+        also = ""
+        if "mla" in cfg.layer_types:
+            also += (f"; latent attention (q_lora_rank={cfg.q_lora_rank}, "
+                     f"kv_lora_rank={cfg.kv_lora_rank}) has no latent K/V "
+                     "cache here")
+        if cfg.lead_dense_layers or cfg.mtp_layers:
+            also += (f"; lead_dense_layers={cfg.lead_dense_layers} and "
+                     f"mtp_layers={cfg.mtp_layers} lie outside the stack "
+                     "of like blocks the programs scan")
         raise ValueError(
             f"TransformerConfig.layer_types={cfg.layer_types}: the serving "
             "engine has no recurrent state (a Gated DeltaNet's or a "
             "Mamba-2 layer's) beside its paged K/V cache and cannot serve "
-            "typed layers; they run on the training path only")
+            "typed layers; they run on the training path only" + also)
     if cfg.n_kv_heads and cfg.n_kv_heads != cfg.n_heads:
         raise ValueError(
             f"TransformerConfig.n_kv_heads={cfg.n_kv_heads} with n_heads="

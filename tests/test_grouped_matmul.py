@@ -77,7 +77,7 @@ def test_chunks_match_the_reference_whatever_the_routing(
     x = jax.random.normal(jax.random.PRNGKey(4), (2, TOKENS // 2, 64),
                           jnp.float32)
 
-    def route(xf, router, k):       # the pairs by hand, the weights live
+    def route(xf, router, k, *_):   # the pairs by hand, the weights live
         return idx, jax.nn.softmax(
             jnp.matmul(xf.astype(jnp.float32), router[:, :k]), axis=-1)
 
