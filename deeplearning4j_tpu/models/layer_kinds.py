@@ -68,6 +68,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from deeplearning4j_tpu.models.remat import remat_layer
+
 Array = jax.Array
 F32 = jnp.float32
 KINDS = ("deltanet", "full", "mamba2", "attention", "mla")
@@ -515,10 +517,10 @@ def layer_forward(h: Array, p: Dict[str, Array], cfg, kind: str,
 
 
 def _one_layer(cfg, kind: str, mlp: str = None):
-    """`layer_forward` of (h, p); with `cfg.remat` it keeps only its
-    input."""
-    fn = lambda h, p: layer_forward(h, p, cfg, kind, mlp)  # noqa: E731
-    return jax.checkpoint(fn) if cfg.remat else fn
+    """`layer_forward` of (h, p); with `cfg.remat` it keeps its input and
+    its attention kernel's results (models/remat.py)."""
+    return remat_layer(lambda h, p: layer_forward(h, p, cfg, kind, mlp),
+                       cfg, site="layer_kinds.one_layer")
 
 
 def lead_forward(h: Array, lead: Dict[str, Dict[str, Array]], cfg) -> Array:
@@ -575,17 +577,17 @@ def period_forward(h: Array, blocks: Dict[str, Dict[str, Array]],
                    cfg, sliced: bool = False) -> Array:
     """One period's layers in turn; `blocks`' entries without the period
     axis, a run of like layers scanned where they are stacked
-    (`block_keys`). With `cfg.remat` each layer keeps only its input.
+    (`block_keys`). With `cfg.remat` each layer keeps its input and its
+    attention kernel's results (models/remat.py).
     `sliced`: the entries are slices of a scanned stack of periods."""
     for key, kind, lead in block_keys(cfg):
         fn = lambda h_, p_, kind=kind, own=sliced or bool(lead): layer_forward(  # noqa: E731,E501
             h_, _own_slice(p_, h_) if own else p_, cfg, kind)
-        if cfg.remat:
-            # prevent_cse stays on: a period's layers share one scan body
-            # (one iteration where one period is held), and without the
-            # barrier XLA merges a layer's recomputation with its forward
-            # and keeps every layer's activations
-            fn = jax.checkpoint(fn)
+        # prevent_cse stays on: a period's layers share one scan body (one
+        # iteration where one period is held), and without the barrier XLA
+        # merges a layer's recomputation with its forward and keeps every
+        # layer's activations
+        fn = remat_layer(fn, cfg, site="layer_kinds.period")
         if lead:
             h = lax.scan(lambda h_, p_, fn=fn: (fn(h_, p_), None), h,
                          blocks[key])[0]

@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from deeplearning4j_tpu.models.remat import remat_layer
 from deeplearning4j_tpu.nn.layers.attention import (dot_product_attention,
                                                     layer_norm)
 
@@ -56,22 +57,24 @@ class TransformerConfig:
     # activations are NOT kept through the scan, trading recompute FLOPs
     # for HBM — the long-context lever when T*L activations outgrow HBM
     remat: bool = False
-    # what the checkpoint keeps when remat=True:
-    #   'full'  — keep only the block input, recompute everything (max
-    #             HBM savings; backward re-runs the whole block —
-    #             including the flash-attention forward kernel, the
-    #             single most expensive recompute)
-    #   'dots'  — jax.checkpoint_policies.dots_with_no_batch_dims_saveable:
-    #             keep matmul outputs, recompute elementwise tails; the
-    #             flash kernel is a custom_vjp the policy cannot see
-    #             inside, so its forward still re-runs (measured ~2%)
+    # what the checkpoint keeps when remat=True (models/remat.py builds it
+    # at every site, the parallel step's too):
+    #   'full'  — the block's input and its attention kernel's output and
+    #             log-sum-exp (the flash kernel names them): H*Dh / D of
+    #             the input's bytes more a layer, B x T x H*Dh
+    #             activations, plus a float32 or two a row and head. The
+    #             backward recomputes everything XLA emits (norms,
+    #             projections, q/k/v, the MLP) and does not run the Pallas
+    #             forward kernel again
+    #   'dots'  — the same and, by
+    #             jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+    #             the matmul outputs: only elementwise tails recomputed
     #   'mlp'   — checkpoint ONLY the MLP (its [B,T,4D] intermediate is
     #             the memory hog; its recompute is cheap MXU work) and
-    #             keep every attention residual — the backward never
-    #             re-runs the VPU-bound attention kernel. The measured
-    #             throughput sweet spot when activations fit
-    #             (BASELINE.md r3); 'full' remains the long-context
-    #             fallback
+    #             keep every attention residual. The measured throughput
+    #             sweet spot when activations fit (BASELINE.md r3); the
+    #             single-device and FSDP steps only: the parallel step and
+    #             the typed layers refuse it by name
     remat_policy: str = "full"
     # sequence-parallel attention strategy when the mesh's 'seq' axis > 1:
     # 'ring' (parallel/ring.py: K/V ppermute ring) or 'ulysses'
@@ -339,16 +342,15 @@ def forward_hidden(cfg: TransformerConfig, params: Dict[str, Any],
                          "expected 'full', 'dots' or 'mlp'")
     remat_mlp = cfg.remat and cfg.remat_policy == "mlp"
 
-    def body(h, p):
-        return block_forward(h, p, cfg, remat_mlp=remat_mlp), None
+    def block(h, p):
+        return block_forward(h, p, cfg, remat_mlp=remat_mlp)
 
-    if cfg.remat and not remat_mlp:
+    if not remat_mlp:
         # prevent_cse=False: under lax.scan the loop structure already
         # prevents the CSE the default barrier guards against
-        pol = (jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-               if cfg.remat_policy == "dots" else None)
-        body = jax.checkpoint(body, prevent_cse=False, policy=pol)
-    h, _ = lax.scan(body, h, params["blocks"])
+        block = remat_layer(block, cfg, site="transformer.forward_hidden",
+                            prevent_cse=False)
+    h, _ = lax.scan(lambda h, p: (block(h, p), None), h, params["blocks"])
     return final_norm(cfg, params, h)
 
 
@@ -388,11 +390,8 @@ def _forward_hidden_typed(cfg: TransformerConfig, params: Dict[str, Any],
                           tokens: Array) -> Array:
     """forward_hidden for a config with `layer_types`: no positions are
     added (the full layers rotate or go without, the others recur), the
-    scan is over periods, remat keeps a layer's input (the one policy)."""
+    scan is over periods, remat is a layer's (models/remat.py)."""
     from deeplearning4j_tpu.models import layer_kinds
-    if cfg.remat and cfg.remat_policy != "full":
-        raise ValueError(f"remat_policy {cfg.remat_policy!r} with "
-                         "layer_types: only 'full' is there")
     with jax.named_scope("embed"):
         h = embed_tokens(cfg, params, tokens)
     if cfg.lead_dense_layers:

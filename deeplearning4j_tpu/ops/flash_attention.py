@@ -89,6 +89,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 Array = jax.Array
 
@@ -654,10 +655,20 @@ def _flash_attention3(q3, k3, v3, dh, scale, causal, q_offset, kv_offset,
                           kv_offset, interpret, group)[0]
 
 
+# The forward kernel's two results, as `jax.checkpoint` policies may ask
+# for them by name (models/remat.py: a rematerialised layer that keeps them
+# hands `flash_bwd` what the first forward wrote, and its recomputation
+# holds no forward kernel). Identities outside a checkpoint.
+RESIDUAL_NAMES = ("flash_fwd.o", "flash_fwd.stats")
+
+
 def _fwd(q3, k3, v3, dh, scale, causal, q_offset, kv_offset, interpret,
          group=1):
     out, stats = _flash_forward(q3, k3, v3, dh, scale, causal, q_offset,
                                 kv_offset, interpret, group)
+    # named before they leave as output and as residuals: the residual
+    # has to be the named value for a policy to save it
+    out, stats = map(checkpoint_name, (out, stats), RESIDUAL_NAMES)
     return out, (q3, k3, v3, out, stats)
 
 

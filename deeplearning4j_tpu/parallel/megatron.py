@@ -39,6 +39,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from deeplearning4j_tpu.models.remat import remat_layer
 from deeplearning4j_tpu.models.transformer import (
     TransformerConfig, embed_tokens, final_norm, head_loss_sum, nll_sum)
 from deeplearning4j_tpu.nn.layers.attention import layer_norm
@@ -241,14 +242,11 @@ def _stage_fn(x: Array, blocks_local, cfg, mesh) -> Array:
         from deeplearning4j_tpu.models import layer_kinds
         return layer_kinds.periods_forward(x, blocks_local, cfg)
 
-    def body(h, p):
-        return _block_fwd_sharded(h, p, cfg, mesh), None
-
-    if getattr(cfg, "remat", False):
-        # blockwise rematerialization under the scan (prevent_cse=False:
-        # the loop structure already blocks the CSE the default guards)
-        body = jax.checkpoint(body, prevent_cse=False)
-    y, _ = lax.scan(body, x, blocks_local)
+    # blockwise rematerialization under the scan (prevent_cse=False: the
+    # loop structure already blocks the CSE the default guards)
+    block = remat_layer(lambda h, p: _block_fwd_sharded(h, p, cfg, mesh),
+                        cfg, site="parallel.megatron", prevent_cse=False)
+    y, _ = lax.scan(lambda h, p: (block(h, p), None), x, blocks_local)
     return y
 
 

@@ -295,3 +295,52 @@ def test_grouped_matmul_kernels_compile_for_v5e(one_chip, monkeypatch):
     assert (rows, d) in operands and (rows + n, d) in operands
     assert not [dims for dims in operands
                 if dims[0] in (gm.buffer_rows(n * k, held), n * k)]
+
+
+def test_rematerialised_step_holds_two_kernels_a_layer_pair_for_v5e(
+        one_chip, monkeypatch):
+    """A small GPT-2 step through `make_parallel_train_step` with
+    `remat`, compiled whole: the scanned block's forward loop holds
+    `flash_fwd`, its backward loop `flash_bwd` alone, because the
+    checkpoint keeps the forward kernel's results (models/remat.py) and
+    XLA drops the call from the recomputation. With the input kept alone
+    the backward loop runs the forward kernel again: three."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from deeplearning4j_tpu.models import remat
+    from deeplearning4j_tpu.models.transformer import (TransformerConfig,
+                                                       init_params)
+    from deeplearning4j_tpu.parallel.megatron import (
+        make_parallel_train_step, param_specs)
+    from deeplearning4j_tpu.parallel.mesh import MeshSpec, make_mesh
+    from deeplearning4j_tpu.parallel.optim import AdamState
+    # the program's kernel gate asks for the backend; this process is
+    # held to the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = TransformerConfig(vocab_size=512, d_model=256, n_heads=4,
+                            n_layers=4, max_len=256, dtype="bfloat16",
+                            remat=True)
+    mesh = make_mesh(MeshSpec(), devices=list(one_chip.device_set))
+
+    def sd(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    params = jax.tree_util.tree_map(
+        lambda leaf, spec: sd(leaf.shape, np.float32, spec),
+        jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))),
+        param_specs(cfg))
+    opt = AdamState(m=params, v=params, count=sd((), np.int32, P()))
+    toks = sd((2, 256), np.int32, P(("data",), ("seq",)))
+
+    def kernels():
+        step = make_parallel_train_step(cfg, mesh, learning_rate=1e-3)
+        with jax.enable_x64(False):
+            text = step.lower(params, opt, toks, toks).compile().as_text()
+        assert _kernel_names(text) == {"flash_fwd", "flash_bwd"}
+        return text.count('custom_call_target="tpu_custom_call"')
+
+    assert kernels() == 2
+    monkeypatch.setattr(remat, "RESIDUAL_NAMES", ())
+    assert kernels() == 3
