@@ -19,12 +19,19 @@ heads of a block are told apart by lane MASK, not by lane slice:
 ``dot(where(lane in head s, q, 0), k)`` contracts all W lanes, the
 other head's lanes adding exact zeros, and on a 128-deep MXU a
 contraction of 128 costs what one of 64 costs. The softmax statistics
-are lane-major, ``[B, groups, rows, T]`` with T on the lanes. Shapes
-that cannot take that form (``H*Dh`` not a multiple of W, Dh 80 or 96,
-sequences past one superblock) run the SAME kernel bodies over
+are lane-major, ``[B, groups, rows, T]`` with T on the lanes. A head of
+a multiple of 128 lanes is a program's own block at every length and in
+any query group (a K/V head's block is its query head's ``// group``):
+the host's splits of long sequences, the forward's q chunks and ranges
+of heads and the backward's q x k tiles, move static offsets in the
+index maps over the whole operands and never slice them. Narrower heads
+share a block within one superblock and without a group. Shapes that
+cannot take that form (``H*Dh`` not a multiple of W, Dh 80 or 96, head
+pairs past one superblock or in a group) run the SAME kernel bodies over
 ``[B*H, T, Dh]`` with one head a program (`_lane_dense_width` decides,
 from shapes alone; a trace-time mark ``flash_attention.layout`` and the
-counter ``flash_attention_calls{layout}`` say which was taken).
+counter ``flash_attention_calls{layout}`` say which was taken, and the
+mark how many kernel calls a forward and a backward issue).
 
 What was measured before it (ledger, PR 26, GPT-2 medium at
 ``[32, 1024, 16, 64]``): with ``[B*H, T, 64]`` operands half of every
@@ -444,9 +451,16 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, stat_ref,
 # `benchmarks/grid_crash_repro.py` is the checked-in minimal repro: run
 # it after any jax/libtpu bump — if it stops failing, the caps can be
 # raised; if smaller grids start failing, lower them. It lifts the caps
-# by assigning these two names in its own process.
+# by assigning these names in its own process.
 _MAX_2D_GRID_FWD = 96
 _MAX_2D_GRID_BWD = 32
+# The forward over the block's own layout, counted as its grid's
+# programs (entries x lane groups x superblocks): on one v5e, jax 0.9.0 /
+# libtpu 0.0.34, grids of (2, 20, 4), (3, 16, 4), (4, 16, 4) and
+# (2, 32, 4) at T 8192 and head_dim 256 compiled and ran (2026-10-15),
+# the last two 256 programs; nothing larger was tried. The one-head-an-
+# entry form keeps its own cap above, as it was measured.
+_MAX_LANE_GRID_FWD = 256
 
 
 def _bh_chunks(bh: int, nsb: int, cap: int, group: int = 1):
@@ -457,6 +471,40 @@ def _bh_chunks(bh: int, nsb: int, cap: int, group: int = 1):
         return [(0, bh)]
     step = max(group, cap // nsb // group * group)
     return [(lo, min(step, bh - lo)) for lo in range(0, bh, step)]
+
+
+def _grid_parts(n: int, groups: int, nsb: int, cap: int):
+    """The block's own layout split for the caps above, as (first entry,
+    entries, first lane group, lane groups) of each call: every program
+    the grid has counts, entries x lane groups x superblocks. Even
+    ranges of lane groups while one entry range fits, else one lane
+    group a call over as many entries as fit. Each part addresses its
+    blocks by offsets in the index maps and writes into the one output
+    buffer the parts share: nothing is sliced or concatenated."""
+    if nsb <= 1 or n * groups * nsb <= cap:
+        return [(0, n, 0, groups)]
+    if n * nsb <= cap:
+        calls = -(-groups // (cap // (n * nsb)))
+        step = -(-groups // calls)
+        return [(0, n, g0, min(step, groups - g0))
+                for g0 in range(0, groups, step)]
+    step = max(1, cap // nsb)
+    return [(b0, min(step, n - b0), g0, 1)
+            for g0 in range(groups) for b0 in range(0, n, step)]
+
+
+def _plus(x, c: int):
+    """A block index moved by a static offset; the index itself at 0,
+    so that a call that is not split lowers as it always has."""
+    return x + c if c else x
+
+
+def _skip_refs(kernel, first: int, count: int):
+    """``kernel`` handed every ref but the ``count`` from ``first`` on:
+    the buffers a part writes into by aliasing, which it never reads."""
+    def body(*refs):
+        return kernel(*refs[:first], *refs[first + count:])
+    return body
 
 
 # q extent per forward kernel call: at T=16384 the full-T call's
@@ -496,21 +544,61 @@ def _flash_forward(q3, k3, v3, dh: int, scale: float, causal: bool,
     block's own layout (N = B, L = H*Dh) or one head an entry
     (N = B*H, L = Dh). rows: a log-sum-exp a head of a block, or m and
     log(l) apart (`_one_lse`). With ``group`` > 1 (grouped-query
-    attention, one head an entry) k3 and v3 hold N // group entries and
-    entry n of q3 reads entry n // group of them: the K/V block is
-    shared by its group through the block index, never repeated."""
+    attention) k3 and v3 hold a group's shared K/V head once: in the
+    block's own layout lane group g of q3 reads lane group g // group of
+    them, one head an entry entry n reads entry n // group, through the
+    block index, never repeated."""
+    if q3.shape[2] != dh:
+        return _forward_lane_dense(q3, k3, v3, dh, scale, causal, q_offset,
+                                   kv_offset, interpret, group)
     tq = q3.shape[1]
-    if tq > _FWD_Q_CHUNK:
-        chunk = _chunk_of(tq, _FWD_Q_CHUNK)
-        if chunk and chunk < tq:
-            outs = [_flash_forward_impl(
-                q3[:, lo:lo + chunk], k3, v3, dh, scale, causal,
-                q_offset + lo, kv_offset, interpret, group)
-                for lo in range(0, tq, chunk)]
-            return (jnp.concatenate([o for o, _ in outs], axis=1),
-                    jnp.concatenate([st for _, st in outs], axis=-1))
+    chunk = _fwd_q_chunk(tq, causal, q_offset, kv_offset)
+    if chunk < tq:
+        outs = [_flash_forward_impl(
+            q3[:, lo:lo + chunk], k3, v3, dh, scale, causal,
+            q_offset + lo, kv_offset, interpret, group)
+            for lo in range(0, tq, chunk)]
+        return (jnp.concatenate([o for o, _ in outs], axis=1),
+                jnp.concatenate([st for _, st in outs], axis=-1))
     return _flash_forward_impl(q3, k3, v3, dh, scale, causal, q_offset,
                                kv_offset, interpret, group)
+
+
+def _fwd_q_chunk(tq: int, causal: bool, q_offset: int,
+                 kv_offset: int) -> int:
+    """q rows a forward call takes: past `_FWD_Q_CHUNK` the largest
+    chunk that divides T, else T. Rows that can be fully masked keep
+    one call over T, so that every call writes the same statistic rows
+    (`_one_lse`)."""
+    if tq > _FWD_Q_CHUNK and _one_lse(causal, q_offset, kv_offset):
+        return _chunk_of(tq, _FWD_Q_CHUNK) or tq
+    return tq
+
+
+def _fwd_pieces(shape, dh: int, causal: bool, q_offset: int,
+                kv_offset: int):
+    """The calls of a forward over the block's own layout, as (q rows,
+    part): rows (first, count) as `_fwd_q_chunk` gives them, parts as
+    `_grid_parts` does."""
+    n, tq, lanes = shape
+    chunk = _fwd_q_chunk(tq, causal, q_offset, kv_offset)
+    parts = _grid_parts(n, lanes // _block_width(lanes, dh),
+                        chunk // _q_superblock(chunk), _MAX_LANE_GRID_FWD)
+    return [((lo, chunk), part) for lo in range(0, tq, chunk)
+            for part in parts]
+
+
+def _forward_lane_dense(q3, k3, v3, dh, scale, causal, q_offset, kv_offset,
+                        interpret, group):
+    """The forward over the block's own layout at every length: each
+    call addresses its q rows and its heads through the index maps, and
+    all of them write one output and one statistics buffer."""
+    out = None
+    for rows, part in _fwd_pieces(q3.shape, dh, causal, q_offset,
+                                  kv_offset):
+        out = _fwd_call(q3, k3, v3, dh, scale, causal, q_offset, kv_offset,
+                        interpret, group, rows=rows, part=part, into=out)
+    return tuple(out)
 
 
 def _block_width(lanes: int, dh: int) -> int:
@@ -531,9 +619,27 @@ def _vmem_params(nbytes: int):
         vmem_limit_bytes=min(100 * 2 ** 20, 2 * nbytes + 24 * 2 ** 20))
 
 
-def _flash_forward_impl(q3, k3, v3, dh: int, scale: float, causal: bool,
-                        q_offset: int, kv_offset: int, interpret: bool,
-                        group: int = 1):
+def _kv_index(lanes: int, dh: int, group: int, b0: int, g0: int, rows):
+    """Block index map of a K/V operand, its row block ``rows(i)`` of
+    the grid's third index: a group's shared head through the entry
+    (one head an entry) or through the lane group (the block's own
+    layout), moved by the part's offsets."""
+    if lanes == dh or group == 1:
+        return lambda b, g, i: (_plus(b, b0) // group, rows(i),
+                                _plus(g, g0))
+    return lambda b, g, i: (_plus(b, b0), rows(i), _plus(g, g0) // group)
+
+
+def _fwd_call(q3, k3, v3, dh: int, scale: float, causal: bool,
+              q_offset: int, kv_offset: int, interpret: bool,
+              group: int = 1, rows=None, part=None, into=None):
+    """One `flash_fwd` call, by default over all of q3, returning new
+    (o, stats). ``rows`` (first q row, rows) and ``part`` (first entry,
+    entries, first lane group, lane groups) select a piece by static
+    offsets in the index maps; its results still have the whole's
+    shape, the piece's blocks written and the rest left to the pieces
+    after it, which write into ``into``, aliased: the buffers an earlier
+    piece returned."""
     import jax.experimental.pallas as pl
 
     from deeplearning4j_tpu.ops.pallas_util import (interpret_arg,
@@ -543,35 +649,56 @@ def _flash_forward_impl(q3, k3, v3, dh: int, scale: float, causal: bool,
     sk = k3.shape[1]
     w = _block_width(lanes, dh)
     groups = lanes // w
+    r0, nr = rows or (0, tq)
+    b0, nb, g0, ng = part or (0, n, 0, groups)
     nrows = (w // dh) * (1 if _one_lse(causal, q_offset, kv_offset) else 2)
-    bq = _inner_block(tq)
+    bq = _inner_block(nr)
     bk = _inner_block(sk)
-    qsb = _q_superblock(tq)
+    qsb = _q_superblock(nr)
+    i0 = r0 // qsb
     kernel = functools.partial(
         _flash_fwd_kernel, scale=scale, causal=causal,
-        qo=int(q_offset), ko=int(kv_offset), bq=bq, bk=bk, dh=dh)
-    qspec = pl.BlockSpec((1, qsb, w), lambda b, g, i: (b, i, g))
-    kvspec = pl.BlockSpec((1, sk, w), lambda b, g, i: (b // group, 0, g))
-    stat_spec = pl.BlockSpec((1, 1, nrows, qsb),
-                             lambda b, g, i: (b, g, 0, i))
+        qo=int(q_offset + r0), ko=int(kv_offset), bq=bq, bk=bk, dh=dh)
+    qspec = pl.BlockSpec((1, qsb, w), lambda b, g, i: (
+        _plus(b, b0), _plus(i, i0), _plus(g, g0)))
+    kvspec = pl.BlockSpec((1, sk, w), _kv_index(lanes, dh, group, b0, g0,
+                                                lambda i: 0))
+    stat_spec = pl.BlockSpec((1, 1, nrows, qsb), lambda b, g, i: (
+        _plus(b, b0), _plus(g, g0), 0, _plus(i, i0)))
     resident = (2 * sk + 2 * qsb) * w * q3.dtype.itemsize
+    args, in_specs, aliases = (q3, k3, v3), [qspec, kvspec, kvspec], {}
+    if into is not None:
+        args += tuple(into)
+        in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
+        aliases = {3: 0, 4: 1}
+        kernel = _skip_refs(kernel, 3, 2)
+    return pl.pallas_call(
+        kernel,
+        out_shape=[out_struct((n, tq, lanes), q3.dtype, q3, k3, v3),
+                   out_struct((n, groups, nrows, tq), jnp.float32,
+                              q3, k3, v3)],
+        grid=(nb, ng, nr // qsb),
+        in_specs=in_specs,
+        out_specs=[qspec, stat_spec],
+        input_output_aliases=aliases,
+        interpret=interpret_arg(interpret, q3, k3, v3),
+        compiler_params=_vmem_params(resident),
+        name="flash_fwd",
+    )(*args)
+
+
+def _flash_forward_impl(q3, k3, v3, dh: int, scale: float, causal: bool,
+                        q_offset: int, kv_offset: int, interpret: bool,
+                        group: int = 1):
+    """The forward of one head an entry: the batch-head axis sliced
+    into chunks that keep the grid within its cap."""
+    n, tq, _ = q3.shape
 
     def call(qc, kc, vc):
-        c = qc.shape[0]
-        return pl.pallas_call(
-            kernel,
-            out_shape=[out_struct((c, tq, lanes), q3.dtype, qc, kc, vc),
-                       out_struct((c, groups, nrows, tq), jnp.float32,
-                                  qc, kc, vc)],
-            grid=(c, groups, tq // qsb),
-            in_specs=[qspec, kvspec, kvspec],
-            out_specs=[qspec, stat_spec],
-            interpret=interpret_arg(interpret, qc, kc, vc),
-            compiler_params=_vmem_params(resident),
-            name="flash_fwd",
-        )(qc, kc, vc)
+        return _fwd_call(qc, kc, vc, dh, scale, causal, q_offset,
+                         kv_offset, interpret, group)
 
-    chunks = _bh_chunks(n, tq // qsb, _MAX_2D_GRID_FWD, group)
+    chunks = _bh_chunks(n, tq // _q_superblock(tq), _MAX_2D_GRID_FWD, group)
     if len(chunks) == 1:
         return call(q3, k3, v3)
     outs = [call(q3[lo:lo + c], k3[lo // group:(lo + c) // group],
@@ -581,13 +708,15 @@ def _flash_forward_impl(q3, k3, v3, dh: int, scale: float, causal: bool,
                  for i in range(2))
 
 
-def _flash_backward(q3, k3, v3, o3, stats, g, dh, scale, causal,
-                    q_offset, kv_offset, interpret, group: int = 1):
-    """Pallas backward: ONE program per (batch row, lane group)
-    producing dQ, dK and dV together (shared probability panels).
-    With ``group`` > 1 a program reads its group's shared K/V entry and
-    writes its own query head's dK and dV, which are then summed over
-    the group in float32."""
+def _bwd_call(q3, k3, v3, o3, do3, stats, dh, scale, causal, q_offset,
+              kv_offset, interpret, group=1, rows=None, krows=None,
+              part=None, into=None):
+    """One `flash_bwd` call, by default over all of its operands,
+    returning new (dq, dk, dv), dk and dv a query head's own. ``rows``
+    (first q row, rows) and ``krows`` (first k row, rows) select a tile
+    of the whole operands by static offsets in the index maps; its
+    results are the tile's, dq [N, rows, L] and dk, dv [N, krows, L].
+    ``part`` and ``into`` split the tile's grid as `_fwd_call`'s do."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -598,36 +727,67 @@ def _flash_backward(q3, k3, v3, o3, stats, g, dh, scale, causal,
     sk = k3.shape[1]
     w = _block_width(lanes, dh)
     groups = lanes // w
-    bq = _inner_block(tq)
+    r0, nr = rows or (0, tq)
+    k0, nk = krows or (0, sk)
+    b0, nb, g0, ng = part or (0, n, 0, groups)
+    bq = _inner_block(nr)
     # 256-col k-tiles: the fused three-gradient kernel's panel stack
     # (s/p/dp/ds + dq scratch) must fit the 16MB scoped-VMEM budget
-    bk = _inner_block(sk, 256)
-    ksb = _k_superblock(sk, bk)
-    statics = dict(scale=scale, causal=causal, qo=int(q_offset),
-                   ko=int(kv_offset), bq=bq, bk=bk, dh=dh)
-    full = pl.BlockSpec((1, tq, w), lambda b, g, j: (b, 0, g))
-    kspec = pl.BlockSpec((1, ksb, w), lambda b, g, j: (b, j, g))
-    kin = pl.BlockSpec((1, ksb, w), lambda b, g, j: (b // group, j, g))
-    stat_spec = pl.BlockSpec((1, 1, stats.shape[2], tq),
-                             lambda b, g, j: (b, g, 0, 0))
-    resident = ((3 * tq + 4 * ksb) * q3.dtype.itemsize + 2 * tq) * w
+    bk = _inner_block(nk, 256)
+    ksb = _k_superblock(nk, bk)
+    i0, j0 = r0 // nr, k0 // ksb
+    kernel = functools.partial(
+        _flash_bwd_kernel, scale=scale, causal=causal,
+        qo=int(q_offset + r0), ko=int(kv_offset + k0), bq=bq, bk=bk, dh=dh)
+    full = pl.BlockSpec((1, nr, w), lambda b, g, j: (
+        _plus(b, b0), i0, _plus(g, g0)))
+    kin = pl.BlockSpec((1, ksb, w), _kv_index(lanes, dh, group, b0, g0,
+                                              lambda j: _plus(j, j0)))
+    stat_spec = pl.BlockSpec((1, 1, stats.shape[2], nr), lambda b, g, j: (
+        _plus(b, b0), _plus(g, g0), 0, i0))
+    dq_spec = pl.BlockSpec((1, nr, w), lambda b, g, j: (
+        _plus(b, b0), 0, _plus(g, g0)))
+    dkv_spec = pl.BlockSpec((1, ksb, w), lambda b, g, j: (
+        _plus(b, b0), j, _plus(g, g0)))
+    resident = ((3 * nr + 4 * ksb) * q3.dtype.itemsize + 2 * nr) * w
+    args = (q3, k3, v3, o3, do3, stats)
+    in_specs, aliases = [full, kin, kin, full, full, stat_spec], {}
+    if into is not None:
+        args += tuple(into)
+        in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 3
+        aliases = {6: 0, 7: 1, 8: 2}
+        kernel = _skip_refs(kernel, 6, 3)
+    return pl.pallas_call(
+        kernel,
+        out_shape=[out_struct((n, nr, lanes), q3.dtype, *args[:6]),
+                   out_struct((n, nk, lanes), k3.dtype, *args[:6]),
+                   out_struct((n, nk, lanes), v3.dtype, *args[:6])],
+        grid=(nb, ng, nk // ksb),
+        in_specs=in_specs,
+        out_specs=[dq_spec, dkv_spec, dkv_spec],
+        input_output_aliases=aliases,
+        scratch_shapes=[pltpu.VMEM((nr, w), jnp.float32),
+                        pltpu.VMEM((w // dh, nr), jnp.float32)],
+        interpret=interpret_arg(interpret, *args[:6]),
+        compiler_params=_vmem_params(resident),
+        name="flash_bwd",
+    )(*args)
+
+
+def _flash_backward(q3, k3, v3, o3, stats, g, dh, scale, causal,
+                    q_offset, kv_offset, interpret, group: int = 1):
+    """Pallas backward of one head an entry: ONE program per (batch
+    row, lane group) producing dQ, dK and dV together (shared
+    probability panels). With ``group`` > 1 a program reads its group's
+    shared K/V entry and writes its own query head's dK and dV, which
+    are then summed over the group in float32."""
+    n = q3.shape[0]
+    sk = k3.shape[1]
+    ksb = _k_superblock(sk, _inner_block(sk, 256))
 
     def call(args):
-        c = args[0].shape[0]
-        return pl.pallas_call(
-            functools.partial(_flash_bwd_kernel, **statics),
-            out_shape=[out_struct((c, tq, lanes), q3.dtype, *args),
-                       out_struct((c, sk, lanes), k3.dtype, *args),
-                       out_struct((c, sk, lanes), v3.dtype, *args)],
-            grid=(c, groups, sk // ksb),
-            in_specs=[full, kin, kin, full, full, stat_spec],
-            out_specs=[full, kspec, kspec],
-            scratch_shapes=[pltpu.VMEM((tq, w), jnp.float32),
-                            pltpu.VMEM((w // dh, tq), jnp.float32)],
-            interpret=interpret_arg(interpret, *args),
-            compiler_params=_vmem_params(resident),
-            name="flash_bwd",
-        )(*args)
+        return _bwd_call(*args, dh, scale, causal, q_offset, kv_offset,
+                         interpret, group)
 
     def over_group(x):          # a K/V head's query heads add up
         if group == 1:
@@ -646,6 +806,102 @@ def _flash_backward(q3, k3, v3, o3, stats, g, dh, scale, causal,
     dq, dk, dv = (jnp.concatenate([o[i] for o in outs], axis=0)
                   for i in range(3))
     return dq, over_group(dk), over_group(dv)
+
+
+def _bwd_tiles(tq: int, sk: int, causal: bool, q_offset: int,
+               kv_offset: int):
+    """The backward's tiles (first q row, rows, first k row, rows): past
+    `_BWD_K_CHUNK` keys a q x k grid of (<=2048, <=2048) tiles without
+    those wholly above the causal diagonal (skipped only where no row
+    can be fully masked: such a row's uniform softmax reaches every key,
+    and its dV with it); else q chunks past `_BWD_Q_CHUNK` over all
+    keys; else one tile."""
+    if sk > _BWD_K_CHUNK:
+        kc, qc = _chunk_of(sk, _BWD_LONG_TILE), _chunk_of(tq, _BWD_LONG_TILE)
+        if kc and qc:
+            skip = causal and _one_lse(causal, q_offset, kv_offset)
+            return [(qlo, qc, klo, kc) for qlo in range(0, tq, qc)
+                    for klo in range(0, sk, kc)
+                    if not (skip and kv_offset + klo
+                            > q_offset + qlo + qc - 1)]
+    chunk = tq if tq <= _BWD_Q_CHUNK else _chunk_of(tq, _BWD_Q_CHUNK)
+    return [(lo, chunk, 0, sk) for lo in range(0, tq, chunk)]
+
+
+def _bwd_pieces(shape, sk: int, dh: int, causal: bool, q_offset: int,
+                kv_offset: int):
+    """The backward's tiles over the block's own layout, each with the
+    parts (`_grid_parts`) its grid is called in."""
+    n, tq, lanes = shape
+    groups = lanes // _block_width(lanes, dh)
+    return [(tile, _grid_parts(
+        n, groups, tile[3] // _k_superblock(tile[3],
+                                            _inner_block(tile[3], 256)),
+        _MAX_2D_GRID_BWD))
+        for tile in _bwd_tiles(tq, sk, causal, q_offset, kv_offset)]
+
+
+def _backward_lane_dense(q3, k3, v3, o3, stats, g, dh, scale, causal,
+                         q_offset, kv_offset, interpret, group):
+    """The backward over the block's own layout at every length: each
+    tile's q, o, dO and statistics rows and its k and v rows are
+    selected by the index maps over the whole operands, and dq, dk and
+    dv leave in the same layout. A tile's partials are added in float32
+    once its last has come (with a query group a call's dk and dv first
+    over a K/V head's query heads, by a reshape of the lane axis), and
+    the tiles are laid side by side along T: one pass over the partials
+    and one over the result. The tiles run so that the widest partials
+    are added soonest (a k column after another for dk and dv, with a
+    group a q row after another for dq): fewer are held at once."""
+    tq, sk = q3.shape[1], k3.shape[1]
+    pieces = sorted(_bwd_pieces(q3.shape, sk, dh, causal, q_offset,
+                                kv_offset),
+                    key=lambda p: p[0][2::-2] if group == 1 else p[0])
+    sizes = pieces[0][0][1::2] + pieces[0][0][3:]
+    left = ({}, {}, {})              # partials still to come, by tile
+    for (qlo, _, klo, _), _ in pieces:
+        for count, lo in zip(left, (qlo, klo, klo)):
+            count[lo] = count.get(lo, 0) + 1
+    parts, tiles = ({}, {}, {}), ({}, {}, {})
+    for (qlo, qc, klo, kc), split in pieces:
+        out = None
+        for part in split:
+            out = _bwd_call(q3, k3, v3, o3, g, stats, dh, scale, causal,
+                            q_offset, kv_offset, interpret, group,
+                            rows=(qlo, qc), krows=(klo, kc), part=part,
+                            into=out)
+        for i, lo in enumerate((qlo, klo, klo)):
+            x = out[i] if i == 0 else _over_group(out[i], dh, group)
+            parts[i].setdefault(lo, []).append(x)
+            left[i][lo] -= 1
+            if not left[i][lo]:
+                ps = parts[i].pop(lo)
+                tiles[i][lo] = ps[0] if len(ps) == 1 else functools.reduce(
+                    jnp.add, [p.astype(jnp.float32) for p in ps]
+                ).astype(ps[0].dtype)
+    return tuple(_side_by_side(t, length, rows) for t, length, rows in
+                 zip(tiles, (tq, sk, sk), sizes))
+
+
+def _side_by_side(tiles, length: int, rows: int):
+    """One gradient from its tiles ({first row: [N, rows, L]}) along T;
+    a tile that no call reached is zero."""
+    some = next(iter(tiles.values()))
+    ts = [tiles.get(lo, jnp.zeros_like(some)) for lo in range(0, length,
+                                                              rows)]
+    return ts[0] if len(ts) == 1 else jnp.concatenate(ts, axis=1)
+
+
+def _over_group(x, dh: int, group: int):
+    """dk or dv of a call's query heads [N, rows, H*Dh] as its K/V
+    heads', a group's ``group`` query heads added in float32 by a
+    reshape of the lane axis; without a group, ``x`` itself."""
+    if group == 1:
+        return x
+    n, rows, lanes = x.shape
+    return jnp.sum(x.astype(jnp.float32).reshape(
+        n, rows, lanes // (group * dh), group, dh), axis=3).reshape(
+        n, rows, lanes // group).astype(x.dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
@@ -716,14 +972,21 @@ def _bwd(dh, scale, causal, q_offset, kv_offset, interpret, group, res, g):
     sums over k tiles, dK/dV sum over q tiles, and causally
     fully-masked tiles (k tile entirely after the q tile's last row)
     are skipped at trace time. This takes single-chip training from
-    T<=4096 to T=8192+ on this toolchain."""
+    T<=4096 to T=8192+ on this toolchain. Over the block's own layout
+    the same tiles (`_bwd_tiles`) address the whole operands through the
+    index maps (`_backward_lane_dense`); one head an entry slices them."""
     q3, k3, v3, o3, stats = res
+    if q3.shape[2] != dh:
+        return _backward_lane_dense(q3, k3, v3, o3, stats, g, dh, scale,
+                                    causal, q_offset, kv_offset, interpret,
+                                    group)
     sk = k3.shape[1]
     tq = q3.shape[1]
     if sk > _BWD_K_CHUNK:
         kc = _chunk_of(sk, _BWD_LONG_TILE)
         qc = _chunk_of(tq, _BWD_LONG_TILE)
         if kc and qc:
+            tiles = _bwd_tiles(tq, sk, causal, q_offset, kv_offset)
             dqs = []
             dks = [None] * (sk // kc)
             dvs = [None] * (sk // kc)
@@ -731,8 +994,7 @@ def _bwd(dh, scale, causal, q_offset, kv_offset, interpret, group, res, g):
                 qsl = slice(qlo, qlo + qc)
                 dq = None
                 for ki, klo in enumerate(range(0, sk, kc)):
-                    if causal and (kv_offset + klo
-                                   > q_offset + qlo + qc - 1):
+                    if (qlo, qc, klo, kc) not in tiles:
                         continue    # tile entirely above the diagonal
                     ksl = slice(klo, klo + kc)
                     dq_c, dk_c, dv_c = _flash_backward(
@@ -839,24 +1101,54 @@ def flash_attention_available(q: Array, k: Array,
     return jax.default_backend() == "tpu"
 
 
-def _lane_dense_width(h: int, d: int, tq: int, sk: int) -> int:
+def _lane_dense_width(h: int, d: int, tq: int, sk: int,
+                      group: int = 1) -> int:
     """Lanes a program takes of the block's own [B, T, H*Dh] layout
     (128, or a whole wider head), or 0 for shapes that keep one head an
     entry of [B*H, T, Dh]. From shapes alone: heads must fill whole
     128-lane groups (Dh divides 128 or is a multiple of it, the local
-    H*Dh a multiple of the width), and the sequence one superblock in
-    each direction with no host tiling — every training shape the
-    kernels are measured at; the long-T tilings were found for, and
-    stay with, the per-head form."""
+    H*Dh a multiple of the width) and the keys tile (else the
+    backward's jnp fallback). A head of a multiple of 128 lanes is a
+    program's own block at every length and for any query group: the
+    host's tilings move index maps over it, and a K/V head's lane block
+    is its query head's // group. Narrower heads share a block by lane
+    mask, which holds one superblock in each direction with no host
+    tiling and no group; longer or grouped, they keep one head an
+    entry."""
     w = max(128, d)
     if (128 % d and d % 128) or (h * d) % w:
         return 0
-    if _q_superblock(tq) != tq or tq > _BWD_Q_CHUNK or sk > _BWD_K_CHUNK \
+    if sk % min(BLOCK_Q, sk):
+        return 0
+    if d % 128 == 0:
+        return w
+    if group > 1 or _q_superblock(tq) != tq or tq > _BWD_Q_CHUNK \
+            or sk > _BWD_K_CHUNK \
             or _k_superblock(sk, _inner_block(sk, 256)) != sk:
         return 0
-    if sk % min(BLOCK_Q, sk):       # the backward's jnp fallback
-        return 0
     return w
+
+
+def _kernel_calls(n: int, tq: int, sk: int, lanes: int, dh: int,
+                  causal: bool, q_offset: int, kv_offset: int,
+                  group: int):
+    """(forward, backward): the kernel calls that one forward and one
+    backward of operands [n, tq, lanes] over sk keys issue, from the
+    plans the two paths follow."""
+    if lanes != dh:
+        return (len(_fwd_pieces((n, tq, lanes), dh, causal, q_offset,
+                                kv_offset)),
+                sum(len(parts) for _, parts in _bwd_pieces(
+                    (n, tq, lanes), sk, dh, causal, q_offset, kv_offset)))
+    chunk = _fwd_q_chunk(tq, causal, q_offset, kv_offset)
+    fwd = tq // chunk * len(_bh_chunks(
+        n, chunk // _q_superblock(chunk), _MAX_2D_GRID_FWD, group))
+    if sk % min(BLOCK_Q, sk):           # the jnp fallback
+        return fwd, 0
+    return fwd, sum(len(_bh_chunks(
+        n, kc // _k_superblock(kc, _inner_block(kc, 256)),
+        _MAX_2D_GRID_BWD, group))
+        for *_, kc in _bwd_tiles(tq, sk, causal, q_offset, kv_offset))
 
 
 def flash_attention(q: Array, k: Array, v: Array, *, causal: bool = False,
@@ -865,8 +1157,8 @@ def flash_attention(q: Array, k: Array, v: Array, *, causal: bool = False,
     """[B, T, H, D] attention via the Pallas kernel. Same contract as
     attention.dot_product_attention (which dispatches here). k and v
     may hold fewer heads than q (grouped-query attention): KV head j
-    serves query heads [j * H / Hkv, (j + 1) * H / Hkv), one head an
-    entry, the K/V block shared through its block index."""
+    serves query heads [j * H / Hkv, (j + 1) * H / Hkv), the K/V block
+    shared through its block index in either layout."""
     from deeplearning4j_tpu.observability.metrics import default_registry
     from deeplearning4j_tpu.observability.tracing import mark
 
@@ -879,11 +1171,15 @@ def flash_attention(q: Array, k: Array, v: Array, *, causal: bool = False,
         raise ValueError(f"query heads {h} not a multiple of KV heads "
                          f"{hkv}")
     group = h // hkv
-    w = _lane_dense_width(h, d, tq, k.shape[1]) if group == 1 else 0
+    w = _lane_dense_width(h, d, tq, k.shape[1], group)
     layout = "lane_dense" if w else "per_head"
+    fwd_calls, bwd_calls = _kernel_calls(
+        b if w else b * h, tq, k.shape[1], h * d if w else d, d,
+        bool(causal), int(q_offset), int(kv_offset), group)
     # trace time: once a compiled program, not once a step
     mark("flash_attention.layout", layout=layout,
-         heads_per_block=max(1, w // d))
+         heads_per_block=max(1, w // d), fwd_calls=fwd_calls,
+         bwd_calls=bwd_calls)
     default_registry().counter(
         "flash_attention_calls",
         "flash_attention traces by operand layout (lane_dense: the "
@@ -892,7 +1188,7 @@ def flash_attention(q: Array, k: Array, v: Array, *, causal: bool = False,
 
     if w:       # [B, T, H, D] -> [B, T, H*D]: a bitcast
         def to3(x):
-            return x.reshape(b, x.shape[1], h * d)
+            return x.reshape(b, x.shape[1], x.shape[2] * d)
     else:       # [B, T, H, D] -> [B*H, T, D]: a transpose
         def to3(x):
             return jnp.transpose(x, (0, 2, 1, 3)).reshape(

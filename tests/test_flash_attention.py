@@ -187,11 +187,25 @@ def test_layout_gate_mark_and_counter():
     assert _lane_dense_width(2, 16, 128, 128) == 0      # H*Dh = 32
     assert _lane_dense_width(8, 96, 1024, 1024) == 0    # Dh = 96
     assert _lane_dense_width(3, 64, 1024, 1024) == 0    # half a group
-    # past one superblock: the host tilings keep the per-head form
-    assert _lane_dense_width(16, 128, 4096, 4096) == 0
-    assert _lane_dense_width(16, 128, 1024, 8192) == 0
+    # a head of whole 128-lane tiles is a program's block at every
+    # length, the host's tilings moving index maps, for any group:
+    # GLM-4.7-Flash's attention (20 heads of 256 at T 8192) and
+    # Qwen3-Next's (16 query heads on 2 KV heads of 256)
+    assert _lane_dense_width(16, 128, 4096, 4096) == 128
+    assert _lane_dense_width(16, 128, 8192, 8192) == 128
+    assert _lane_dense_width(16, 256, 4096, 4096) == 256
+    assert _lane_dense_width(20, 256, 8192, 8192) == 256
+    assert _lane_dense_width(16, 128, 1024, 8192) == 128
+    assert _lane_dense_width(16, 256, 8192, 8192, 8) == 256
+    # a head pair past one superblock, or grouped, keeps the per-head
+    # form: Granite-4.0-H's 32 query heads on 8 KV heads of 64
+    assert _lane_dense_width(32, 64, 8192, 8192, 4) == 0
+    assert _lane_dense_width(16, 64, 8192, 8192) == 0
+    assert _lane_dense_width(16, 64, 4096, 4096) == 0
+    assert _lane_dense_width(16, 64, 1024, 1024, 2) == 0
     # a kv length the backward cannot tile (its jnp fallback)
     assert _lane_dense_width(16, 64, 128, 200) == 0
+    assert _lane_dense_width(16, 128, 128, 200) == 0
 
     def seen(layout):
         fam = default_registry().get("flash_attention_calls")
@@ -206,7 +220,73 @@ def test_layout_gate_mark_and_counter():
         assert seen(layout) == before + 1
         last = [sp for sp in default_spans().snapshot().spans
                 if sp.name == "flash_attention.layout"][-1]
-        assert last.args == {"layout": layout, "heads_per_block": hpb}
+        assert last.args == {"layout": layout, "heads_per_block": hpb,
+                             "fwd_calls": 1, "bwd_calls": 1}
+
+
+@pytest.mark.parametrize("shape,group,calls", [
+    # (entries, T, lanes, head_dim): the parent's per-head GLM split the
+    # forward's 40 entries 24 + 16 and tiled the backward's 10 live tiles
+    ((40, 8192, 256, 256), 1, (2, 10)),
+    ((2, 8192, 20 * 256, 256), 1, (1, 10)),       # GLM-4.7-Flash
+    ((3, 8192, 16 * 256, 256), 8, (1, 10)),       # Qwen3-Next
+    ((32, 8192, 64, 64), 4, (2, 10)),             # Granite-4.0-H
+    ((32, 1024, 16 * 64, 64), 1, (1, 1)),         # GPT-2 medium
+], ids=["glm_per_head", "glm", "qwen", "granite", "gpt2m"])
+def test_kernel_calls_of_the_cells_attention(shape, group, calls):
+    """The calls one forward and one backward issue at the cells' sizes,
+    from the plans the two paths follow (no trace)."""
+    from deeplearning4j_tpu.ops import flash_attention as _  # noqa: F401
+    import sys
+    fa = sys.modules["deeplearning4j_tpu.ops.flash_attention"]
+    n, t, lanes, d = shape
+    assert fa._kernel_calls(n, t, t, lanes, d, True, 0, 0, group) == calls
+
+
+def test_glm_shaped_trace_marks_its_kernel_calls(monkeypatch):
+    """GLM's attention at a tiny size: 20 heads of 128 lanes (one a
+    program) over four backward tiles a side, the tiles forced small.
+    The mark says lane_dense, one forward call (two parts of one buffer
+    where the grid cap splits the heads) and the ten live tiles, and
+    tracing the gradient makes exactly those kernel calls."""
+    import sys
+
+    import jax.experimental.pallas as pl
+
+    from deeplearning4j_tpu.observability.tracing import default_spans
+    fa = sys.modules["deeplearning4j_tpu.ops.flash_attention"]
+    monkeypatch.setattr(fa, "_BWD_K_CHUNK", 256)
+    monkeypatch.setattr(fa, "_BWD_LONG_TILE", 128)
+    inner = fa._inner_block
+    # q-superblocks of 256 rows, score tiles of 128
+    monkeypatch.setattr(fa, "_inner_block", lambda n, cap=512: inner(
+        n, 256 if cap == fa._FWD_QSB else 128))
+    names = []
+    real = pl.pallas_call
+
+    def counted(*a, **kw):
+        names.append(kw["name"])
+        return real(*a, **kw)
+
+    monkeypatch.setattr(pl, "pallas_call", counted)
+    b, t, h, d = 1, 512, 20, 128
+    q = _rand((b, t, h, d), 0)
+
+    def loss(x):
+        return jnp.sum(flash_attention(x, x, x, causal=True) ** 2)
+
+    # 1 x 20 heads x 2 superblocks = 40 programs: one call, or two
+    # parts of 10 heads under a cap of 20
+    for cap, fwd in ((256, 1), (20, 2)):
+        monkeypatch.setattr(fa, "_MAX_LANE_GRID_FWD", cap)
+        names.clear()
+        jax.eval_shape(jax.grad(loss), q)
+        last = [sp for sp in default_spans().snapshot().spans
+                if sp.name == "flash_attention.layout"][-1]
+        assert last.args == {"layout": "lane_dense", "heads_per_block": 1,
+                             "fwd_calls": fwd, "bwd_calls": 10}
+        assert names.count("flash_fwd") == fwd
+        assert names.count("flash_bwd") == 10
 
 
 def test_lane_dense_lowering_has_no_transpose(monkeypatch):
@@ -296,11 +376,21 @@ def test_multi_superblock_and_chunked_backward_paths():
         fa._BWD_Q_CHUNK = orig_chunk
 
 
-def test_bwd_2d_host_tiling_matches_reference(monkeypatch):
+@pytest.mark.parametrize("h,hk,dh,kv_offset", [
+    (2, 2, 32, 0), (2, 2, 32, 64), (2, 2, 128, 0), (4, 2, 128, 0),
+    (2, 2, 128, 64)], ids=["per_head", "per_head_masked_rows",
+                           "lane_dense", "lane_dense_group2",
+                           "lane_dense_masked_rows"])
+def test_bwd_2d_host_tiling_matches_reference(monkeypatch, h, hk, dh,
+                                              kv_offset):
     """The r5 long-sequence backward (2-D q x k host tiling over the
     fused kernel, global softmax stats per tile, causal tile skipping)
-    must equal the jnp reference grads. Forced tiny tiles so the path
-    runs at test-sized T."""
+    must equal the jnp reference grads, one head an entry (slices) and
+    in the block's own layout (index maps over the whole operands,
+    grouped too). Forced tiny tiles so the path runs at test-sized T,
+    and grid caps so small that the calls split into parts (of the
+    batch-head axis, or of one buffer by aliasing). Where kv_offset
+    passes q_offset rows can be fully masked: no tile may be skipped."""
     import sys
 
     import deeplearning4j_tpu.ops.flash_attention  # noqa: F401
@@ -314,14 +404,25 @@ def test_bwd_2d_host_tiling_matches_reference(monkeypatch):
     # per-row stats) so fwd+bwd chunked paths are covered together
     monkeypatch.setattr(fa, "_FWD_Q_CHUNK", 256)
     monkeypatch.setenv("DL4JTPU_FLASH", "interpret")
+    # superblocks of 128 rows (two k-superblocks from 256 keys on), tiles
+    # of 64, and caps that split every grid with superblocks
+    inner, ksb = fa._inner_block, fa._k_superblock
+    monkeypatch.setattr(fa, "_inner_block", lambda n, cap=512: inner(
+        n, 128 if cap == fa._FWD_QSB else 64))
+    monkeypatch.setattr(fa, "_k_superblock", lambda sk, bk: (
+        sk // 2 if sk >= 256 and sk % (2 * bk) == 0 else ksb(sk, bk)))
+    for cap in ("_MAX_2D_GRID_FWD", "_MAX_2D_GRID_BWD", "_MAX_LANE_GRID_FWD"):
+        monkeypatch.setattr(fa, cap, 4)
     rng = np.random.RandomState(0)
-    B, T, H, Dh = 2, 512, 2, 32
-    q, k, v = (jnp.asarray(rng.randn(B, T, H, Dh), jnp.float32)
-               for _ in range(3))
+    B, T = 2, 512
+    q = jnp.asarray(rng.randn(B, T, h, dh), jnp.float32)
+    k, v = (jnp.asarray(rng.randn(B, T, hk, dh), jnp.float32)
+            for _ in range(2))
+    kw = dict(kv_offset=kv_offset)
     for causal in (True, False):
         def loss_kernel(q, k, v):
             return jnp.sum(fa.flash_attention(
-                q, k, v, causal=causal).astype(jnp.float32) ** 2)
+                q, k, v, causal=causal, **kw).astype(jnp.float32) ** 2)
 
         # tiled grads (chunk attrs forced small by the monkeypatches)
         gk = jax.grad(loss_kernel, argnums=(0, 1, 2))(q, k, v)
@@ -349,7 +450,7 @@ def test_bwd_2d_host_tiling_matches_reference(monkeypatch):
                 from deeplearning4j_tpu.nn.layers.attention import \
                     dot_product_attention
                 return jnp.sum(dot_product_attention(
-                    q, k, v, causal=causal).astype(jnp.float32) ** 2)
+                    q, k, v, causal=causal, **kw).astype(jnp.float32) ** 2)
 
             gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
         for a, b, name in zip(gk, gr, "qkv"):

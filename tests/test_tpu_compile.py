@@ -45,8 +45,9 @@ def _mosaic_kernels(monkeypatch):
 
 
 def _compiled_grad(sharding, shape, **kw):
-    """Compiled text of the gradient of all three inputs. Operands
-    arrive as the projections leave them, [B, T, H*Dh]."""
+    """Compiled text of the gradient of all three inputs, and the mark
+    `flash_attention.layout` its trace left. Operands arrive as the
+    projections leave them, [B, T, H*Dh]."""
     b, t, h, d = shape
     x = jax.ShapeDtypeStruct((b, t, h * d), jnp.bfloat16, sharding=sharding)
 
@@ -58,8 +59,15 @@ def _compiled_grad(sharding, shape, **kw):
     # the tests' x64 mode is not how the chip runs: Mosaic has no
     # 64-bit index arithmetic
     with jax.enable_x64(False):
-        return jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+        text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
             x, x, x).compile().as_text()
+    return text, _layout_mark()
+
+
+def _layout_mark():
+    from deeplearning4j_tpu.observability.tracing import default_spans
+    return [sp for sp in default_spans().snapshot().spans
+            if sp.name == "flash_attention.layout"][-1].args
 
 
 def _kernel_operands(text):
@@ -86,15 +94,30 @@ def _kernel_operands(text):
     ((8, 1024, 16, 64), dict(causal=True, q_offset=1024)),
     ((8, 1024, 16, 64), dict(causal=True, kv_offset=512)),
     ((4, 1024, 8, 32), dict(causal=False)),
-], ids=["gpt2m", "cgpt13", "ring_block", "masked_rows", "four_heads"])
+    # past one superblock, a head of whole 128-lane tiles a program: the
+    # forward over q-superblocks, the backward's 2-D host tiling by
+    # index maps over the whole operands. GLM-4.7-Flash's attention (20
+    # heads of 256, 2 rows of 8192), and 8 heads of 128
+    ((2, 8192, 20, 256), dict(causal=True)),
+    ((1, 8192, 8, 128), dict(causal=True)),
+], ids=["gpt2m", "cgpt13", "ring_block", "masked_rows", "four_heads", "glm",
+        "t8192"])
 def test_lane_dense_kernels_compile_for_v5e(one_chip, shape, kw):
     assert fa._lane_dense_width(shape[2], shape[3], shape[1], shape[1])
-    text = _compiled_grad(one_chip, shape, **kw)
-    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    text, mark = _compiled_grad(one_chip, shape, **kw)
+    assert mark["layout"] == "lane_dense"
+    calls = mark["fwd_calls"] + mark["bwd_calls"]
+    assert text.count('custom_call_target="tpu_custom_call"') == calls
     operands = _kernel_operands(text)
     # forward: q, k, v in, o and the statistics out; backward: q, k, v,
-    # o, dO and the statistics in, dq, dk, dv out
-    assert len(operands) == 14, operands
+    # o, dO and the statistics in, dq, dk, dv out; besides, a buffer that
+    # a call writes into by aliasing (the backward's tiles their dq, dk
+    # and dv over all rows) is an operand shaped as its result
+    calls = [_kernel_operands(line) for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert sorted(len(c) for c in calls)[:mark["fwd_calls"]] == \
+        [5] * mark["fwd_calls"]
+    assert all(9 <= len(c) <= 12 for c in calls if len(c) != 5), calls
     # nothing the kernels move is padded: no minor dimension of 64 or
     # of 1 in a 128-lane tile
     for dims in operands:
@@ -109,11 +132,13 @@ def test_lane_dense_kernels_compile_for_v5e(one_chip, shape, kw):
     (4, 1024, 8, 96),       # Dh neither divides 128 nor is divided by it
     (4, 64, 2, 16),         # a single short tile, H*Dh = 32
     (2, 4096, 16, 64),      # two k-superblocks in the backward
-    (1, 8192, 8, 128),      # q-superblocks, the host's q x k tiling
-], ids=["dh96", "t64", "t4096", "t8192"])
+    (1, 8192, 8, 64),       # a head pair past one superblock: the
+                            # host's q x k tiling over slices
+], ids=["dh96", "t64", "t4096", "t8192_dh64"])
 def test_per_head_kernels_compile_for_v5e(one_chip, shape):
     assert not fa._lane_dense_width(shape[2], shape[3], shape[1], shape[1])
-    text = _compiled_grad(one_chip, shape, causal=True)
+    text, mark = _compiled_grad(one_chip, shape, causal=True)
+    assert mark["layout"] == "per_head"
     assert text.count('custom_call_target="tpu_custom_call"') >= 2
 
 
@@ -131,9 +156,9 @@ def _kernel_names(text):
 
 def test_grouped_query_kernels_compile_for_v5e(one_chip):
     """Qwen3-Next's full layer at the cell's size: 16 query heads on 2 KV
-    heads of 256 at T 8192, a K/V head's block shared by its 8 query
-    heads (one head an entry), resident K/V past the default of scoped
-    VMEM."""
+    heads of 256 at T 8192 in the block's own layout, a K/V head's lane
+    block shared by its 8 query heads through the index map, resident
+    K/V past the default of scoped VMEM."""
     b, t, h, hk, d = 3, 8192, 16, 2, 256
     q = jax.ShapeDtypeStruct((b, t, h, d), jnp.bfloat16, sharding=one_chip)
     kv = jax.ShapeDtypeStruct((b, t, hk, d), jnp.bfloat16,
@@ -147,12 +172,19 @@ def test_grouped_query_kernels_compile_for_v5e(one_chip):
         text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
             q, kv, kv).compile().as_text()
     assert {"flash_fwd", "flash_bwd"} <= _kernel_names(text)
-    # K and V reach the kernels with their 2 heads, never repeated to 16
+    mark = _layout_mark()
+    assert mark == {"layout": "lane_dense", "heads_per_block": 1,
+                    "fwd_calls": 1, "bwd_calls": 10}
     operands = _kernel_operands(text)
-    # (the forward takes 24 query heads and their 3 KV heads a call,
-    # twice: the grid cap, in whole groups)
-    assert (24, t, d) in operands and (3, t, d) in operands
-    assert (b * h, t, d) not in operands or (b * hk, t, d) in operands
+    assert (b, t, h * d) in operands and (b, t, hk * d) in operands
+    assert not [dims for dims in operands if dims[0] in (b * h, b * hk)]
+    # K and V reach every kernel call with their 2 heads, never repeated
+    # to 16
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 11
+    for line in calls:
+        assert _kernel_operands(line).count((b, t, hk * d)) == 2, line[:300]
 
 
 def test_gated_delta_kernels_compile_for_v5e(one_chip, monkeypatch):
@@ -234,7 +266,7 @@ def test_granites_attention_compiles_per_head_for_v5e(one_chip):
     head an entry of `[B*H, T, 64]` (PERF.md says what that costs), a KV
     head's block shared by its 4 query heads and never repeated."""
     b, t, h, hk, d = 1, 8192, 32, 8, 64
-    assert not fa._lane_dense_width(h, d, t, t)
+    assert not fa._lane_dense_width(h, d, t, t, h // hk)
     q = jax.ShapeDtypeStruct((b, t, h, d), jnp.bfloat16, sharding=one_chip)
     kv = jax.ShapeDtypeStruct((b, t, hk, d), jnp.bfloat16,
                               sharding=one_chip)
@@ -247,6 +279,8 @@ def test_granites_attention_compiles_per_head_for_v5e(one_chip):
         text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
             q, kv, kv).compile().as_text()
     assert {"flash_fwd", "flash_bwd"} <= _kernel_names(text)
+    assert _layout_mark() == {"layout": "per_head", "heads_per_block": 1,
+                              "fwd_calls": 2, "bwd_calls": 10}
     operands = _kernel_operands(text)
     assert (b * h, t, d) not in operands or (b * hk, t, d) in operands
     assert not [dims for dims in operands if dims == (b * h, t, d)
