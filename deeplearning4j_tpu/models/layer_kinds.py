@@ -27,6 +27,10 @@ A layer is ``h += r mixer(norm(h)); h += r mlp(norm(h))`` with
   shares; a head's key is ``[k_nope | k_r]``, its value has a width of
   its own; no q/k norms, no gate. K and V are formed whole (the
   "expanded" form) and go through the same attention as the other kinds.
+- ``"shortconv"``: the gated short convolution (LFM2's). ``Win`` gives
+  ``B | C | x~``; ``B * x~`` through a depthwise causal convolution of
+  `cfg.shortconv_width` taps, no bias, no activation; ``Wo(C * conv)``.
+  The gates and the convolution are the scope ``shortconv.conv``.
 - ``"deltanet"``: Gated DeltaNet. ``Wqkvz`` (laid out by key head: q, k,
   v of its value heads, z of its value heads) and ``Wba``; q, k, v through
   a causal depthwise convolution and SiLU; the gated delta rule
@@ -35,9 +39,10 @@ A layer is ``h += r mixer(norm(h)); h += r mlp(norm(h))`` with
 
 A layer's second half is what `cfg.mlp_kind` names: ``"swiglu"``, one dense
 gated MLP of width `dense_d_ff` (``W_gu`` gate | up, ``W_down``), or
-``"moe"``, a top-k mixture of experts with a shared expert, gated or
-(`cfg.shared_gate` false) not (`moe_topk`); `cfg.router_scoring` says how
-the router scores (`route`). That layer is told which experts it holds
+``"moe"``, a top-k mixture of experts with a shared expert of
+`cfg.shared_d_ff` (none where it is 0), gated or (`cfg.shared_gate` false)
+not (`moe_topk`); `cfg.router_scoring` says how the router scores
+(`route`). That layer is told which experts it holds
 (`cfg.experts_held`, ids from `first`): it routes over all
 `cfg.n_experts`, normalises over all `moe_top_k` chosen and adds only what
 the held experts give, without drops: every routed row is computed
@@ -52,11 +57,12 @@ With `cfg.stack_runs` a period's runs of like layers are stacked too:
 `cfg.tie_head` there is no ``Wout``.
 
 Outside the periods: `cfg.lead_dense_layers` layers before the first one
-(``lead["l<i>"][name]``, no period axis: the period's first mixer and a
-dense SwiGLU whatever `mlp_kind` says; `lead_forward`), and with
-`cfg.mtp_layers` a multi-token-prediction module after the final norm
-(``mtp``: ``enorm``, ``hnorm``, ``eh_proj``, one ``layer`` of the period's
-last kind, ``norm``; `mtp_hidden`), whose loss models/transformer.py adds.
+(``lead["l<i>"][name]``, no period axis: the mixer `cfg.lead_layer_types`
+names, or the period's first, and a dense SwiGLU whatever `mlp_kind` says;
+`lead_forward`), and with `cfg.mtp_layers` a multi-token-prediction module
+after the final norm (``mtp``: ``enorm``, ``hnorm``, ``eh_proj``, one
+``layer`` of the period's last kind, ``norm``; `mtp_hidden`), whose loss
+models/transformer.py adds.
 These kinds run on the training path (models/transformer.py's forward and
 parallel/megatron.py's step on data / pipe axes); serving refuses them.
 """
@@ -72,7 +78,7 @@ from deeplearning4j_tpu.models.remat import remat_layer
 
 Array = jax.Array
 F32 = jnp.float32
-KINDS = ("deltanet", "full", "mamba2", "attention", "mla")
+KINDS = ("deltanet", "full", "mamba2", "attention", "mla", "shortconv")
 _HI = lax.Precision.HIGHEST
 
 
@@ -91,10 +97,11 @@ def layer_shapes(cfg, kind: str, mlp: str = None) -> Dict[str, tuple]:
     elif mlp == "moe":
         out = {"ln1": (d,), "ln2": (d,), "router": (d, cfg.n_experts),
                "We_gu": (cfg.experts_held, d, 2 * f),
-               "We_down": (cfg.experts_held, f, d),
-               "Ws_gu": (d, 2 * fs), "Ws_down": (fs, d)}
-        if cfg.shared_gate:
-            out["Ws_gate"] = (d, 1)
+               "We_down": (cfg.experts_held, f, d)}
+        if fs:
+            out.update(Ws_gu=(d, 2 * fs), Ws_down=(fs, d))
+            if cfg.shared_gate:
+                out["Ws_gate"] = (d, 1)
         if cfg.router_scoring == "sigmoid":
             out["router_bias"] = (cfg.n_experts,)
     else:
@@ -133,6 +140,8 @@ def layer_shapes(cfg, kind: str, mlp: str = None) -> Dict[str, tuple]:
                    Wkv_a=(d, rkv + cfg.qk_rope_dim), kv_a_norm=(rkv,),
                    Wkv_b=(rkv, h * (cfg.qk_nope_dim + cfg.v_head_dim)),
                    Wo=(h * cfg.v_head_dim, d))
+    elif kind == "shortconv":
+        out.update(Win=(d, 3 * d), conv=(cfg.shortconv_width, d), Wo=(d, d))
     else:
         raise ValueError(f"unknown layer kind {kind!r}: expected one of "
                          f"{KINDS}")
@@ -148,11 +157,23 @@ def n_periods(cfg) -> int:
     return n // len(cfg.layer_types)
 
 
+def lead_kinds(cfg) -> tuple:
+    """The leading layers' mixers: `cfg.lead_layer_types`, one a layer, or
+    the period's first for each."""
+    kinds = cfg.lead_layer_types or (cfg.layer_types[0],) * \
+        cfg.lead_dense_layers
+    if len(kinds) != cfg.lead_dense_layers:
+        raise ValueError(f"TransformerConfig.lead_layer_types={kinds} names "
+                         f"{len(kinds)} mixers for lead_dense_layers="
+                         f"{cfg.lead_dense_layers}")
+    return tuple(kinds)
+
+
 def lead_shapes(cfg) -> Dict[str, Dict[str, tuple]]:
-    """`lead`'s entries, a leading layer each: the period's first mixer
+    """`lead`'s entries, a leading layer each: its mixer (`lead_kinds`)
     and a dense SwiGLU."""
-    return {f"l{i}": layer_shapes(cfg, cfg.layer_types[0], "swiglu")
-            for i in range(cfg.lead_dense_layers)}
+    return {f"l{i}": layer_shapes(cfg, kind, "swiglu")
+            for i, kind in enumerate(lead_kinds(cfg))}
 
 
 def mtp_shapes(cfg) -> Dict[str, Any]:
@@ -353,6 +374,25 @@ def causal_conv(x: Array, w: Array, bias: Array = None) -> Array:
     return y.astype(x.dtype)
 
 
+def short_conv(x: Array, p: Dict[str, Array], cfg) -> Array:
+    """The gated short convolution: ``[B | C | x~] = x Win``, ``B * x~``
+    through the depthwise causal convolution (`causal_conv`: the first tap
+    multiplies the oldest position), gated by ``C``, then ``Wo``."""
+    from deeplearning4j_tpu.observability.metrics import default_registry
+    from deeplearning4j_tpu.observability.tracing import mark
+    d = x.shape[-1]
+    # trace time: the convolution's shape and the two gates around it
+    mark("shortconv.layout", channels=d, taps=cfg.shortconv_width, gates=2)
+    default_registry().counter(
+        "shortconv_calls", "gated short-convolution mixers traced").inc()
+    bcx = _mm(x, p["Win"])
+    with jax.named_scope("shortconv.conv"):
+        u = bcx[..., :d] * bcx[..., 2 * d:]
+        v = causal_conv(u, p["conv"])
+        y = bcx[..., d:2 * d] * v
+    return _mm(y, p["Wo"])
+
+
 def _l2(x: Array) -> Array:
     xf = x.astype(F32)
     return xf * lax.rsqrt(jnp.sum(jnp.square(xf), -1, keepdims=True) + 1e-6)
@@ -455,7 +495,8 @@ def route(x: Array, router: Array, top_k: int, scoring: str = "softmax",
 
 def moe_topk(x: Array, p: Dict[str, Array], cfg, first: int = 0) -> Array:
     """x [B, T, D]: the held experts' part of the routed sum, dropless,
-    plus the shared expert, gated or (`cfg.shared_gate` false) not."""
+    plus the shared expert where there is one (`cfg.shared_d_ff`), gated or
+    (`cfg.shared_gate` false) not."""
     from deeplearning4j_tpu.observability.metrics import default_registry
     from deeplearning4j_tpu.observability.tracing import mark
     from deeplearning4j_tpu.ops import grouped_matmul as gm
@@ -477,6 +518,8 @@ def moe_topk(x: Array, p: Dict[str, Array], cfg, first: int = 0) -> Array:
                        p.get("router_bias"), cfg.routed_scale)
         plan = gm.plan_groups(idx, first, held, cfg.n_experts)
     y = gm.dropless_experts(xf, w, p["We_gu"], p["We_down"], plan)
+    if not cfg.shared_d_ff:
+        return y.reshape(b, t, d)
     with jax.named_scope("moe.shared"):
         shared = _swiglu(xf, p["Ws_gu"], p["Ws_down"])
         if cfg.shared_gate:
@@ -491,7 +534,8 @@ MIXERS = {"deltanet": ("deltanet", gated_deltanet),
           "full": ("attn", grouped_query_attention),
           "attention": ("attn", grouped_query_attention),
           "mamba2": ("mamba", mamba2),
-          "mla": ("mla", latent_attention)}
+          "mla": ("mla", latent_attention),
+          "shortconv": ("shortconv", short_conv)}
 
 
 def _joins(y: Array, cfg) -> Array:
@@ -522,9 +566,8 @@ def lead_forward(h: Array, lead: Dict[str, Dict[str, Array]], cfg) -> Array:
     """The `cfg.lead_dense_layers` layers before the first period, each
     rematerialised alone like a period's."""
     with jax.named_scope("lead"):
-        for i in range(cfg.lead_dense_layers):
-            h = _one_layer(cfg, cfg.layer_types[0], "swiglu")(
-                h, lead[f"l{i}"])
+        for i, kind in enumerate(lead_kinds(cfg)):
+            h = _one_layer(cfg, kind, "swiglu")(h, lead[f"l{i}"])
     return h
 
 

@@ -97,10 +97,11 @@ class TransformerConfig:
     cache_dtype: Optional[str] = None
     # --- a period of unlike layers (models/layer_kinds.py) -------------
     # the kinds of one period's layers, in order ("deltanet", "full",
-    # "mamba2", "attention", "mla"); () is the one GPT-2 block above. With
-    # layer_types the model has RMSNorm (1 + w), no learned positions,
-    # and after every mixer the MLP that `mlp_kind` names. Training path
-    # only: the serving engine refuses a config that sets them.
+    # "mamba2", "attention", "mla", "shortconv"); () is the one GPT-2
+    # block above. With layer_types the model has RMSNorm (1 + w), no
+    # learned positions, and after every mixer the MLP that `mlp_kind`
+    # names. Training path only: the serving engine refuses a config that
+    # sets them.
     layer_types: Tuple[str, ...] = ()
     # True: a period's runs of like layers are stacked (`blocks.r<j>`
     # leaves `[P, n, ...]`) and scanned, one traced body a run; False:
@@ -122,8 +123,12 @@ class TransformerConfig:
     ssm_state: int = 0
     ssm_groups: int = 1
     ssm_conv_width: int = 4
-    # a typed layer's second half: "moe" (top-k experts and a gated shared
-    # expert) or "swiglu" (one dense gated MLP of width dense_d_ff)
+    # the gated short convolution ("shortconv"): its depthwise causal
+    # convolution's taps (no bias)
+    shortconv_width: int = 3
+    # a typed layer's second half: "moe" (top-k experts and, where
+    # shared_d_ff is set, a shared expert) or "swiglu" (one dense gated
+    # MLP of width dense_d_ff)
     mlp_kind: str = "moe"
     dense_d_ff: int = 0
     # multipliers: the embedding's rows by embed_scale, a mixer's and an
@@ -141,7 +146,8 @@ class TransformerConfig:
     gdn_value_dim: int = 0
     gdn_conv_width: int = 4
     # top-k MoE of the typed layers: n_experts is the router's width,
-    # experts_held how many of them ([0, experts_held)) live here
+    # experts_held how many of them ([0, experts_held)) live here;
+    # shared_d_ff the shared expert's width, 0 for none (no Ws_* leaves)
     experts_held: int = 0
     moe_top_k: int = 1
     moe_d_ff: int = 0
@@ -168,8 +174,10 @@ class TransformerConfig:
     qk_rope_dim: int = 0
     v_head_dim: int = 0
     # layers before the first period, counted in n_layers, outside the
-    # scan: the period's first mixer and a dense SwiGLU of dense_d_ff
+    # scan: a mixer and a dense SwiGLU of dense_d_ff; lead_layer_types
+    # names each one's mixer, () the period's first for all
     lead_dense_layers: int = 0
+    lead_layer_types: Tuple[str, ...] = ()
     # multi-token prediction: one further layer fed by the final hidden
     # state and the next token's embedding; its loss, through the same
     # embedding and head, joins the main one times mtp_loss_weight

@@ -476,8 +476,9 @@ def make_parallel_train_step(cfg: TransformerConfig, mesh: Mesh, *,
             raise ValueError(
                 f"TransformerConfig.layer_types={cfg.layer_types}: the "
                 "typed layers (Gated DeltaNet, Mamba-2, grouped-query "
-                "attention, top-k MoE, dense SwiGLU) are not divided over "
-                "the 'model' or 'seq' axes; use data and pipe")
+                "attention, the gated short convolution, top-k MoE, dense "
+                "SwiGLU) are not divided over the 'model' or 'seq' axes; "
+                "use data and pipe")
         if pipeline_schedule == "1f1b" and s > 1:
             raise ValueError("TransformerConfig.layer_types: the 1f1b "
                              "schedule is not there for typed layers")

@@ -423,6 +423,11 @@ def _refuse_unserved(cfg: TransformerConfig) -> None:
             also += (f"; latent attention (q_lora_rank={cfg.q_lora_rank}, "
                      f"kv_lora_rank={cfg.kv_lora_rank}) has no latent K/V "
                      "cache here")
+        if "shortconv" in cfg.layer_types + cfg.lead_layer_types:
+            also += (f"; the gated short convolution (shortconv_width="
+                     f"{cfg.shortconv_width}) decodes from a window of the "
+                     f"last {cfg.shortconv_width - 1} positions a channel, "
+                     "which has no cache here")
         if cfg.lead_dense_layers or cfg.mtp_layers:
             also += (f"; lead_dense_layers={cfg.lead_dense_layers} and "
                      f"mtp_layers={cfg.mtp_layers} lie outside the stack "

@@ -60,6 +60,9 @@ STEPS = {
     "qwen3_next": lambda: _family_step("test_qwen3_next"),
     "granite_hybrid": lambda: _family_step("test_granite_hybrid"),
     "glm4_moe_lite": lambda: _family_step("test_glm4_moe_lite"),
+    # the gated short convolution's own names are outside the vocabulary:
+    # its operations resolve to `lead` or `layers`
+    "lfm2_moe": lambda: _family_step("test_lfm2_moe"),
 }
 
 
