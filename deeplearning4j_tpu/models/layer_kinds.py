@@ -75,6 +75,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from deeplearning4j_tpu.models.remat import remat_layer
+from deeplearning4j_tpu.ops.causal_conv import causal_conv, lanes
 
 Array = jax.Array
 F32 = jnp.float32
@@ -362,18 +363,6 @@ def latent_attention(x: Array, p: Dict[str, Array], cfg) -> Array:
     return _mm(a[..., :dv].reshape(b, t, h * dv), p["Wo"])
 
 
-def causal_conv(x: Array, w: Array, bias: Array = None) -> Array:
-    """Depthwise causal convolution: x [B, T, C], w [W, C], bias [C] or
-    none. The taps are summed in float32 (a tap's gradient is a sum over
-    every position of the batch), the result is in x's dtype."""
-    width, t = w.shape[0], x.shape[1]
-    xp = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0))).astype(F32)
-    y = sum(xp[:, i:i + t] * w[i] for i in range(width))
-    if bias is not None:
-        y = y + bias
-    return y.astype(x.dtype)
-
-
 def short_conv(x: Array, p: Dict[str, Array], cfg) -> Array:
     """The gated short convolution: ``[B | C | x~] = x Win``, ``B * x~``
     through the depthwise causal convolution (`causal_conv`: the first tap
@@ -387,9 +376,8 @@ def short_conv(x: Array, p: Dict[str, Array], cfg) -> Array:
         "shortconv_calls", "gated short-convolution mixers traced").inc()
     bcx = _mm(x, p["Win"])
     with jax.named_scope("shortconv.conv"):
-        u = bcx[..., :d] * bcx[..., 2 * d:]
-        v = causal_conv(u, p["conv"])
-        y = bcx[..., d:2 * d] * v
+        y = causal_conv(lanes(bcx, (2 * d, 3 * d)), p["conv"],
+                        pre=lanes(bcx, (0, d)), post=lanes(bcx, (d, 2 * d)))
     return _mm(y, p["Wo"])
 
 
@@ -409,11 +397,16 @@ def gated_deltanet(x: Array, p: Dict[str, Array], cfg) -> Array:
     ba = jnp.matmul(x.astype(F32), p["Wba"],
                     precision=_HI).reshape(b, t, hk, 2 * r)
     z = qkvz[..., 2 * dk + r * dv:].reshape(b, t, hv, dv)
-    mixed = jnp.concatenate(
-        [qkvz[..., :dk].reshape(b, t, hk * dk),
-         qkvz[..., dk:2 * dk].reshape(b, t, hk * dk),
-         qkvz[..., 2 * dk:2 * dk + r * dv].reshape(b, t, hv * dv)], -1)
-    mixed = jax.nn.silu(causal_conv(mixed, p["conv"]))
+    # q of every key head, then k, then v, read where the projection
+    # left them (a key head's row is q | k | v | z)
+    row = 2 * dk + 2 * r * dv
+    heads = range(0, hk * row, row)
+    mixed = causal_conv(
+        lanes(qkvz.reshape(b, t, hk * row),
+              *[(h, h + dk) for h in heads],
+              *[(h + dk, h + 2 * dk) for h in heads],
+              *[(h + 2 * dk, h + 2 * dk + r * dv) for h in heads]),
+        p["conv"], act="silu")
     q = mixed[..., :hk * dk].reshape(b, t, hk, dk)
     k = mixed[..., hk * dk:2 * hk * dk].reshape(b, t, hk, dk)
     v = mixed[..., 2 * hk * dk:].reshape(b, t, hv, dv)
@@ -441,8 +434,8 @@ def mamba2(x: Array, p: Dict[str, Array], cfg) -> Array:
     dt = jnp.matmul(x.astype(F32), p["Wdt"], precision=_HI)
     z = zxbc[..., :di]
     with jax.named_scope("mamba.conv"):
-        xbc = jax.nn.silu(causal_conv(zxbc[..., di:], p["conv"],
-                                      p["conv_b"]))
+        xbc = causal_conv(lanes(zxbc, (di, zxbc.shape[-1])), p["conv"],
+                          p["conv_b"], act="silu")
     xs = xbc[..., :di].reshape(b, t, h, hd)
     step = jax.nn.softplus(dt + p["dt_bias"])
     with jax.named_scope("mamba.ssd"):

@@ -150,7 +150,8 @@ def _kernel_names(text):
              if 'custom_call_target="tpu_custom_call"' in line]
     return {name for name in ("flash_fwd", "flash_bwd", "gdn_fwd", "gdn_bwd",
                               "moe_gmm_fwd", "moe_gmm_dw", "moe_combine",
-                              "ssd_fwd", "ssd_bwd")
+                              "ssd_fwd", "ssd_bwd", "causal_conv_fwd",
+                              "causal_conv_bwd")
             if any(name in c for c in calls)}
 
 
@@ -378,3 +379,64 @@ def test_rematerialised_step_holds_two_kernels_a_layer_pair_for_v5e(
     assert kernels() == 2
     monkeypatch.setattr(remat, "RESIDUAL_NAMES", ())
     assert kernels() == 3
+
+
+def _deltanet_lanes(src, hk=16, dk=128, vd=256):
+    import deeplearning4j_tpu.ops.causal_conv as cc
+    row = 2 * dk + 2 * vd
+    heads = range(0, hk * row, row)
+    return cc.lanes(src, *[(h, h + dk) for h in heads],
+                    *[(h + dk, h + 2 * dk) for h in heads],
+                    *[(h + 2 * dk, h + 2 * dk + vd) for h in heads])
+
+
+@pytest.mark.parametrize("cell", ["granite", "lfm2", "qwen"])
+def test_causal_conv_kernels_compile_for_v5e(one_chip, monkeypatch, cell):
+    """The causal convolution at each cell's size, forward and backward,
+    on the bfloat16 projection it reads in place: Granite's x B C (4,352
+    lanes after z's 4,096, bias and SiLU, 4 taps, 1 row of 8192), LFM2's
+    B, C and x~ (the thirds of 6,144 lanes, 3 taps, 4 rows) and Qwen's q,
+    k and v of 16 key heads (a head's 768 lanes are q | k | v | z; SiLU, 4
+    taps, 3 rows). The kernels take the whole projection: no slice of it
+    is copied out in front of them. `causal_conv_roofline.train` finds
+    them by their names."""
+    import deeplearning4j_tpu.ops.causal_conv as cc
+    from deeplearning4j_tpu.observability.tracing import default_spans
+    from deeplearning4j_tpu.ops import pallas_util
+    monkeypatch.setattr(pallas_util, "off_chip", lambda: False)
+    b, width, taps, channels, form = {
+        "granite": (1, 8448, 4, 4352, "bias"),
+        "lfm2": (4, 6144, 3, 2048, "gates"),
+        "qwen": (3, 12288, 4, 8192, "heads")}[cell]
+    t = 8192
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(src, w, bias):
+        if form == "gates":
+            y = cc.causal_conv(cc.lanes(src, (4096, 6144)), w,
+                               pre=cc.lanes(src, (0, 2048)),
+                               post=cc.lanes(src, (2048, 4096)))
+        elif form == "bias":
+            y = cc.causal_conv(cc.lanes(src, (4096, 8448)), w, bias,
+                               act="silu")
+        else:
+            y = cc.causal_conv(_deltanet_lanes(src), w, act="silu")
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    with jax.enable_x64(False):
+        text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+            sd((b, t, width), jnp.bfloat16), sd((taps, channels), jnp.float32),
+            sd((channels,), jnp.float32)).compile().as_text()
+    assert _kernel_names(text) == {"causal_conv_fwd", "causal_conv_bwd"}
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    operands = _kernel_operands(text)
+    assert (b, t, width) in operands and (b, t, channels) in operands
+    assert not [dims for dims in operands
+                if dims[:2] == (b, t) and dims[2] not in (width, channels)]
+    last = [sp for sp in default_spans().snapshot().spans
+            if sp.name == "causal_conv.layout"][-1]
+    lanes = {"granite": 256, "lfm2": 512, "qwen": 128}[cell]
+    assert last.args == {"channels": channels, "taps": taps,
+                         "lane_block": lanes, "t_block": cc.BLOCK // lanes}
